@@ -12,6 +12,10 @@ reference) so users of the reference can switch with an import change.
 """
 from __future__ import annotations
 
+from .framework.init import configure_compile_cache as _configure_compile_cache
+
+_configure_compile_cache()
+
 # framework primitives
 from .framework import (  # noqa: F401
     CPUPlace,

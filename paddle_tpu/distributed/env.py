@@ -64,6 +64,21 @@ def get_world_size() -> int:
         return 1
 
 
+def chip_binding_env(local_rank: int) -> dict:
+    """Environment that binds a child process to ONE chip of the host:
+    ``FLAGS_selected_tpus`` (what ``ParallelEnv.device_id`` reads) plus
+    the libtpu variables that make the selection real — the child's jax
+    sees chip ``local_rank`` as its single device, so siblings never
+    contend for a chip.  Inert on a host without a TPU."""
+    return {
+        "FLAGS_selected_tpus": str(local_rank),
+        "TPU_VISIBLE_CHIPS": str(local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+
+
 def device_count() -> int:
     return len(jax.devices())
 

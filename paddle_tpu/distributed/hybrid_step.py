@@ -37,7 +37,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from .mesh import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .pipeline import pipeline_apply

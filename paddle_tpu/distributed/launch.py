@@ -5,6 +5,13 @@ Reference analog: fleet/launch.py:334 launch() + launch_utils.py
 watch_local_trainers :526).  Sets the PADDLE_TRAINER_* env contract per child
 and watches them: any abnormal exit terminates the pod (same watchdog
 semantics; no restart — §5.3).
+
+One process per chip: a TPU chip belongs to ONE process, and a process
+that has touched jax holds every chip it can see.  So this parent never
+touches jax (``import paddle_tpu`` initialises no backend), and with
+``--nproc_per_node > 1`` each child is BOUND to the chip
+``FLAGS_selected_tpus`` names through libtpu's own process-bounds
+variables (``chip_binding_env``) — it sees that chip as its only device.
 """
 from __future__ import annotations
 
@@ -14,6 +21,8 @@ import signal
 import subprocess
 import sys
 import time
+
+from .env import chip_binding_env
 
 
 def _parse_args(argv=None):
@@ -60,6 +69,8 @@ def start_local_trainers(args):
             "PADDLE_GLOO_ENDPOINT": gloo_ep,
             "FLAGS_selected_tpus": str(local_rank),
         })
+        if nproc > 1:
+            env.update(chip_binding_env(local_rank))
         log = (open(os.path.join(args.log_dir, f"workerlog.{local_rank}"), "w")
                if args.log_dir else None)
         cmd = [sys.executable, "-u", args.training_script] + args.training_script_args

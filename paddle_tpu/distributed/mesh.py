@@ -18,38 +18,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:                     # jax>=0.5 exports shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:      # jax<0.5 keeps it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _shard_map_accepts(param: str) -> bool:
-    import inspect
-
-    try:
-        return param in inspect.signature(_shard_map_impl).parameters
-    except (TypeError, ValueError):
-        return True      # unknown signature: pass through untouched
-
-
-_HAS_CHECK_VMA = _shard_map_accepts("check_vma")
-
-
-def shard_map(f, *args, **kwargs):
-    """jax.shard_map with the check_rep<->check_vma kwarg rename papered
-    over in BOTH directions, so framework call sites can use the modern
-    name on any jax.  On legacy jax the check defaults OFF: its
-    replication checker has no rule for pallas_call and rejects cond
-    branches with differing replication — it is a static check only, and
-    the modern default is off."""
-    if not _HAS_CHECK_VMA:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if _shard_map_accepts("check_rep"):
-            kwargs.setdefault("check_rep", False)
-    return _shard_map_impl(f, *args, **kwargs)
-
 _GLOBAL_MESH: Optional[Mesh] = None
 
 

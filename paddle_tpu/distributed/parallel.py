@@ -65,7 +65,7 @@ def make_localsgd_train_step(layer: Layer, loss_fn: Callable, optimizer,
     Returns (step_fn, state); step_fn(state, x, y) -> (state, mean_loss).
     x/y are global batches sharded over ``axis``.
     """
-    from .mesh import shard_map
+    from jax import shard_map
 
     mesh = mesh or get_mesh()
     n = mesh.shape[axis]

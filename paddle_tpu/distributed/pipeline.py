@@ -152,7 +152,7 @@ def pipeline_forward(mesh, stage_fn, params_by_stage, x, micro_batch_size,
     leading stage dimension (sharded over 'pp'); x is the global batch
     (replicated); head/tail params are replicated.  Returns final-stage
     outputs for the full batch (head/tail may change shape+dtype)."""
-    from .mesh import shard_map
+    from jax import shard_map
 
     B = x.shape[0]
     M = B // micro_batch_size
@@ -309,17 +309,10 @@ def pipeline_train_1f1b(stage_fn, stage_params, x_microbatches,
 
     def _to_varying(v):
         """pcast to device-varying over the pipeline axis (no-op if
-        already varying; jax<0.5 has neither typeof nor vma tracking —
-        with check_rep off there is nothing to cast)."""
-        typeof = getattr(jax, "typeof", None)
-        if typeof is None:
+        already varying)."""
+        if axis_name in jax.typeof(v).vma:
             return v
-        vma = getattr(typeof(v), "vma", frozenset())
-        if axis_name in vma:
-            return v
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(v, (axis_name,), to="varying")
-        return jax.lax.pvary(v, (axis_name,))
+        return jax.lax.pcast(v, (axis_name,), to="varying")
 
     def ingest(mb):
         feed = jax.lax.dynamic_index_in_dim(
@@ -431,7 +424,7 @@ def pipeline_train_step(mesh, stage_fn, params_by_stage, x, y,
     runs the 1F1B schedule, and returns (mean_loss, stage_grads_by_stage,
     head_grads) — grads stacked/replicated to match the inputs.
     """
-    from .mesh import shard_map
+    from jax import shard_map
 
     B = x.shape[0]
     M = B // micro_batch_size

@@ -260,7 +260,7 @@ def sequence_parallel_attention(q, k, v, mesh=None, axis_name: str = "sp",
     """Whole-array entry: q/k/v are global [B, S, H, D]; runs ring attention
     with S sharded over `axis_name` of the (global) mesh."""
     from .mesh import get_mesh
-    from .mesh import shard_map
+    from jax import shard_map
 
     mesh = mesh or get_mesh()
     spec = PartitionSpec(None, axis_name, None, None)

@@ -28,6 +28,30 @@ _lock = threading.Lock()
 _sigterm_hooks: List[Callable[[], None]] = []
 
 
+def configure_compile_cache() -> Optional[str]:
+    """Place jax's persistent compilation cache; called once from
+    ``import paddle_tpu``.  Where ``JAX_COMPILATION_CACHE_DIR`` is set the
+    operator has placed it and nothing is set in code.  Otherwise it
+    goes to ``<checkout>/.jax_cache``, derived from this package's own
+    path: the directory is part of the cache key, so it must not move
+    between processes (no tempfile, pid or time).  A process held to the
+    CPU (``JAX_PLATFORMS=cpu`` — the test suite) gets none: its compiles
+    take seconds, and XLA:CPU logs a machine-feature warning on every
+    cache read.  Returns the directory set here, or None.  Touches only
+    ``jax.config`` — no backend is initialised."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+
+    if jax.config.jax_platforms == "cpu":
+        return None
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    cache_dir = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
 def init_devices(force: bool = False) -> list:
     """Enumerate accelerator devices once (init.cc:InitDevices analog).
     Returns the device list; safe to call from anywhere."""

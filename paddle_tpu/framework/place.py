@@ -22,11 +22,17 @@ class Place:
 
     @property
     def jax_device(self):
-        devs = [d for d in jax.devices() if d.platform == self.device_type]
-        if not devs:
-            # fall back to default backend (e.g. asking for TPUPlace on a CPU host)
-            devs = jax.devices()
-        return devs[self._device_id % len(devs)]
+        platform = self._platform()
+        devs = [d for d in jax.devices() if d.platform == platform]
+        if self._device_id >= len(devs):
+            raise ValueError(
+                f"{self!r}: jax reports {len(devs)} {platform!r} "
+                f"device(s) (default backend "
+                f"{jax.default_backend()!r}) — no device with that id")
+        return devs[self._device_id]
+
+    def _platform(self) -> str:
+        return self.device_type
 
     def __eq__(self, other):
         return (
@@ -56,6 +62,9 @@ class CUDAPlace(Place):
     exposes (on a TPU host this is the TPU chip)."""
 
     device_type = "gpu"
+
+    def _platform(self) -> str:
+        return _accelerator_platform()
 
 
 class CUDAPinnedPlace(Place):

@@ -22,25 +22,36 @@ import numpy as np
 
 
 class Generator:
+    """The key is built from the seed on FIRST USE, not at construction:
+    ``jax.random.key`` initialises the backend, and ``import paddle_tpu``
+    constructs the module-level ``default_generator`` — an import must
+    not take the chip (a launcher parent that imports the package would
+    otherwise hold it before any child starts)."""
+
     def __init__(self, seed: int = 0):
         self._seed = seed
-        self._key = jax.random.key(seed)
+        self._key = None
         self._lock = threading.Lock()
+
+    def _live_key(self):
+        if self._key is None:
+            self._key = jax.random.key(self._seed)
+        return self._key
 
     def manual_seed(self, seed: int):
         self._seed = seed
-        self._key = jax.random.key(seed)
+        self._key = None
         return self
 
     def get_state(self):
-        return self._key
+        return self._live_key()
 
     def set_state(self, key):
         self._key = key
 
     def split_key(self):
         with self._lock:
-            self._key, sub = jax.random.split(self._key)
+            self._key, sub = jax.random.split(self._live_key())
             return sub
 
     def state_dict(self):
@@ -49,7 +60,8 @@ class Generator:
         run splits the SAME subkey sequence the killed run would have)."""
         with self._lock:
             return {"seed": int(self._seed),
-                    "key_data": np.asarray(jax.random.key_data(self._key))}
+                    "key_data": np.asarray(
+                        jax.random.key_data(self._live_key()))}
 
     def set_state_dict(self, state):
         with self._lock:

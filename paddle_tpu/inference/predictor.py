@@ -25,7 +25,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from ..framework.export_compat import jax_export
+import jax.export
 from .config import Config
 
 
@@ -61,7 +61,7 @@ class Predictor:
     def __init__(self, config: Config):
         self._config = config
         with open(config.prog_file(), "rb") as f:
-            self._exported = jax_export().deserialize(f.read())
+            self._exported = jax.export.deserialize(f.read())
         try:
             with open(config.params_file(), "rb") as f:
                 self._state = pickle.load(f)

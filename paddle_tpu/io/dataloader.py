@@ -190,7 +190,19 @@ class _WorkerPool:
     epochs (persistent_workers) with a BOUNDED in-flight window — workers
     cannot race ahead and materialize the epoch in shared memory
     (reference _DataLoaderIterMultiProcess outstanding-capacity logic,
-    dataloader_iter.py:230)."""
+    dataloader_iter.py:230).
+
+    Fork and the accelerator: the pool usually forks AFTER the parent's
+    jax runtime is up (the first epoch starts after the model was
+    built).  That is safe for exactly what the workers do here — index
+    the dataset, collate with numpy, write shared memory — because a
+    forked child inherits the parent's runtime handles but none of its
+    threads, and a chip belongs to the one process that opened it: a
+    worker that calls into jax (a ``jnp`` op in ``dataset[i]``,
+    ``collate_fn`` or ``worker_init_fn``, a Tensor built in the worker)
+    fails or hangs on the parent's chip.  Keep datasets and collate
+    functions host-only (numpy in, numpy out); the parent turns batches
+    into device arrays."""
 
     def __init__(self, loader):
         from multiprocessing import shared_memory  # noqa: F401 (probe)

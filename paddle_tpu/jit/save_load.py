@@ -18,7 +18,7 @@ from typing import List, Optional
 import jax
 import numpy as np
 
-from ..framework.export_compat import jax_export
+import jax.export
 from ..nn.layer import Layer
 from ..tensor import Tensor
 from .functional import functional_call, get_state
@@ -53,7 +53,7 @@ def save(layer, path, input_spec=None, **configs):
         out, _ = functional_call(layer, params, buffers, arr_args, training=False)
         return out
 
-    exported = jax_export().export(jax.jit(infer_fn))(*args)
+    exported = jax.export.export(jax.jit(infer_fn))(*args)
     blob = exported.serialize()
     d = os.path.dirname(path)
     if d:
@@ -107,7 +107,7 @@ class TranslatedLayer(Layer):
 def load(path, **configs):
     with open(path + _PDMODEL_SUFFIX, "rb") as f:
         blob = f.read()
-    exported = jax_export().deserialize(blob)
+    exported = jax.export.deserialize(blob)
     with open(path + _PDPARAMS_SUFFIX, "rb") as f:
         state = pickle.load(f)
     indices = None

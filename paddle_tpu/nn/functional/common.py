@@ -42,12 +42,8 @@ def _mask_key(k):
     still provides the SEED (one tiny fold), so framework seeding
     semantics are unchanged; only the per-element bit generator differs.
     """
-    try:
-        seed = jax.random.key_data(k).reshape(-1)[:2].astype(jnp.uint32)
-        return jax.random.wrap_key_data(
-            jnp.tile(seed, 2)[:4], impl="rbg")
-    except Exception:  # older jax without key-data plumbing
-        return k
+    seed = jax.random.key_data(k).reshape(-1)[:2].astype(jnp.uint32)
+    return jax.random.wrap_key_data(jnp.tile(seed, 2)[:4], impl="rbg")
 
 
 def dropout(x, p=0.5, axis=None, training=True, mode="upscale_in_train", name=None):

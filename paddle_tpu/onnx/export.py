@@ -17,9 +17,6 @@ def export(layer, path, input_spec=None, opset_version=None, **configs):
     Returns the artifact prefix.
     """
     from .. import jit
-    from ..framework.export_compat import jax_export
-
-    jax_export()  # fail fast with a clear error before writing artifacts
 
     if path.endswith(".onnx"):
         path = path[: -len(".onnx")]

@@ -87,38 +87,11 @@ def _check_nan_inf(name, flat_outs):
 _non_linearizable: set = set()
 
 
-def _is_ad_linearize_assert(e) -> bool:
-    """jax 0.4.x's ad.linearize trips its bare ``assert
-    out_primal_pval.is_known()`` when partial-eval cannot produce known
-    primal outputs — reached by linearizing a function that itself calls
-    ``jax.vjp`` on a custom_vjp whose backward holds a primitive with no
-    JVP rule (a raw Pallas kernel): the exact static-replay /
-    double-grad recording shape ``apply_vjp`` builds.  Identify it by
-    provenance (an AssertionError raised FROM jax's ad.py
-    linearize/vjp frames), not by message — the assert carries none."""
-    if not isinstance(e, AssertionError):
-        return False
-    tb = e.__traceback__
-    if tb is None:
-        return False
-    while tb.tb_next is not None:     # innermost frame = the raise site
-        tb = tb.tb_next
-    code = tb.tb_frame.f_code
-    # only jax's OWN assert counts: a user assert inside a custom-VJP
-    # backward also propagates THROUGH ad.py frames, but its raise site
-    # is user code — that one must keep raising loudly
-    return (code.co_name in ("linearize", "vjp")
-            and code.co_filename.replace("\\", "/").endswith(
-                "jax/_src/interpreters/ad.py"))
-
-
 def _is_non_linearizable_error(e) -> bool:
     """True only for jax's structural can't-differentiate errors — e.g.
     forward-mode over a custom_vjp (raw Pallas backward being re-recorded
     for double grad / static replay). Shape bugs, dtype errors, or failures
     inside a user VJP must keep raising loudly."""
-    if _is_ad_linearize_assert(e):
-        return True
     msg = str(e)
     if ("does not support reverse-mode autodiff" in msg
             or "Linearization failed" in msg

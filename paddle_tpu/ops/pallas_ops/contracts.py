@@ -291,96 +291,22 @@ FLASH_BWD_DQ = KernelContract(
 )
 
 # ===========================================================================
-# paged_attention.py — ragged paged decode attention.  One block = one
-# physical KV page; the wrapper pads heads to the f32 sublane floor and
-# head_dim to the lane width, so the contract dims ARE the padding
-# constants the wrapper reads.
-# ===========================================================================
-PAGED_DECODE = KernelContract(
-    name="paged_attention_decode",
-    module="paddle_tpu/ops/pallas_ops/paged_attention.py",
-    grid=("batch", "pages_per_seq"),
-    dims={"page_size": 16, "heads": 8, "head_dim": 128, "lane": 128,
-          "head_align": 8},
-    blocks=(
-        BlockDecl("page_tables", "in", ("batch", "pages_per_seq"),
-                  "int32", memory="smem"),
-        BlockDecl("seq_lens", "in", ("batch",), "int32", memory="smem"),
-        BlockDecl("q", "in", (1, "heads", "head_dim"), "float32"),
-        BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "float32"),
-        BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "float32"),
-        BlockDecl("o", "out", (1, "heads", "head_dim"), "float32"),
-        BlockDecl("acc", "scratch", ("heads", "head_dim"), "float32"),
-        BlockDecl("m", "scratch", ("heads", "lane"), "float32"),
-        BlockDecl("l", "scratch", ("heads", "lane"), "float32"),
-    ),
-    shape_buckets={"head_dim": (128, 256), "heads": (8, 16, 32)},
-    # the head padding floor is a legal relayout knob: any multiple of
-    # the f32 sublane floor tiles, padded rows are sliced off — exactly
-    # parity-preserving
-    sweep={"head_align": (8, 16)},
-)
-
-PAGED_DECODE_INT8 = KernelContract(
-    name="paged_attention_decode_int8",
-    module="paddle_tpu/ops/pallas_ops/paged_attention.py",
-    grid=("batch", "pages_per_seq"),
-    # fused_dequant=1 is the historical epilogue: the [H] scale rows
-    # multiply the LOGITS (K) and the accumulated context (V) after the
-    # dots; 0 dequantizes the page in-register BEFORE the dots.  Both
-    # stream 1 byte/element from HBM — the choice moves the multiply
-    # between the VPU epilogue and the MXU operand path, which is
-    # exactly the kind of platform-dependent tie the sweep measures.
-    dims={"page_size": 16, "heads": 8, "head_dim": 128, "lane": 128,
-          "head_align": 8, "fused_dequant": 1},
-    blocks=(
-        BlockDecl("page_tables", "in", ("batch", "pages_per_seq"),
-                  "int32", memory="smem"),
-        BlockDecl("seq_lens", "in", ("batch",), "int32", memory="smem"),
-        BlockDecl("q", "in", (1, "heads", "head_dim"), "float32"),
-        BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "int8",
-                  waivers=("sublane: int8 pages keep the f32 page "
-                           "layout (heads padded to 8, not the int8 "
-                           "floor 32) — padding H 4x just for storage "
-                           "tiling would quadruple page bytes and "
-                           "defeat the int8 win; interpret-validated, "
-                           "real-TPU relayout cost accepted until the "
-                           "autotuner revisits",)),
-        BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "int8",
-                  waivers=("sublane: same trade as k_page — see its "
-                           "waiver",)),
-        BlockDecl("k_scales", "in", (1, "heads"), "float32",
-                  lanes_full=True,
-                  waivers=("sublane: one [H] fp32 scale row rides each "
-                           "page DMA — a sub-tile row block by design "
-                           "(padding it to 8 rows would 8x the scale "
-                           "traffic for zeros)",)),
-        BlockDecl("v_scales", "in", (1, "heads"), "float32",
-                  lanes_full=True,
-                  waivers=("sublane: same trade as k_scales",)),
-        BlockDecl("o", "out", (1, "heads", "head_dim"), "float32"),
-        BlockDecl("acc", "scratch", ("heads", "head_dim"), "float32"),
-        BlockDecl("m", "scratch", ("heads", "lane"), "float32"),
-        BlockDecl("l", "scratch", ("heads", "lane"), "float32"),
-    ),
-    shape_buckets={"head_dim": (128, 256), "heads": (8, 16, 32)},
-    # fused_dequant moves the scale multiply across the dot — NOT
-    # bit-exact (rounding points differ), so the non-default choice only
-    # survives a sweep run with an explicit tolerance (docs/TUNING.md)
-    sweep={"head_align": (8, 16), "fused_dequant": (0, 1)},
-)
-
-# ===========================================================================
-# paged_attention.py — UNIFIED ragged-QUERY paged attention (ISSUE 18).
-# One grid group = one lane: a block of up to ``q_align`` query rows
-# (decode lane = 1 row, chunked-prefill lane = chunk rows, spec-verify
-# lane = K rows) sharing ONE page-table row, so the page DMA is paid
-# once per lane instead of once per query row.  Same online-softmax
-# scratch as the decode contract, widened by the query-row dim.
+# paged_attention.py — ragged-QUERY paged attention (ISSUE 18): ONE
+# kernel body for every paged form.  One grid group = one lane: a block
+# of up to ``q_align`` query rows (decode lane = 1 row — the decode
+# entry point IS this kernel at Q = 1 — chunked-prefill lane = chunk
+# rows, spec-verify lane = K rows) sharing ONE page-table row, so the
+# page DMA is paid once per lane instead of once per query row.  One
+# block = one physical KV page; the wrapper pads heads to the f32
+# sublane floor and head_dim to the lane width, so the contract dims
+# ARE the padding constants the wrapper reads.
+#
+# Every VMEM block below obeys the rule the TPU lowering ENFORCES — the
+# trailing two block dims are (8k, 128k) or span the whole array extent
+# (``lanes_full``/``sublane_full``) — with no waiver: q/o/lse and the
+# online-softmax scratch are head-major ([heads, q_align, .]) so the
+# body indexes whole tiles per head; row_lens rides as [G, q_align, 1]
+# and the int8 scale rows as [N, 1, heads].
 # ===========================================================================
 PAGED_RAGGED = KernelContract(
     name="paged_attention_ragged",
@@ -393,19 +319,15 @@ PAGED_RAGGED = KernelContract(
                   "int32", memory="smem"),
         BlockDecl("group_lens", "in", ("groups",), "int32",
                   memory="smem"),
-        BlockDecl("row_lens", "in", (1, "q_align"), "int32",
-                  lanes_full=True,
-                  waivers=("sublane: one [Qp] int32 per-row length "
-                           "vector rides each group — a sub-tile row "
-                           "block by design (padding it to 8 rows "
-                           "would 8x the length traffic for zeros)",)),
-        BlockDecl("q", "in", (1, "q_align", "heads", "head_dim"),
+        BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
+                  lanes_full=True),
+        BlockDecl("q", "in", (1, "heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
                   "float32"),
         BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
                   "float32"),
-        BlockDecl("o", "out", (1, "q_align", "heads", "head_dim"),
+        BlockDecl("o", "out", (1, "heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("acc", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
@@ -415,9 +337,11 @@ PAGED_RAGGED = KernelContract(
                   "float32"),
     ),
     shape_buckets={"head_dim": (128, 256), "heads": (8, 16, 32)},
-    # head_align as in the decode contract; q_align is the padding floor
-    # for the per-lane query-row dim — padded rows carry row_len 0 and
-    # are sliced off, so both axes are exactly parity-preserving
+    # the head padding floor is a legal relayout knob: any multiple of
+    # the f32 sublane floor tiles, padded heads are never visited;
+    # q_align is the padding floor for the per-lane query-row dim —
+    # padded rows carry row_len 0 and are sliced off, so both axes are
+    # exactly parity-preserving
     sweep={"head_align": (8, 16), "q_align": (8, 16)},
 )
 
@@ -425,9 +349,12 @@ PAGED_RAGGED_INT8 = KernelContract(
     name="paged_attention_ragged_int8",
     module="paddle_tpu/ops/pallas_ops/paged_attention.py",
     grid=("groups", "pages_per_seq"),
-    # fused_dequant as in the decode int8 contract: 1 folds the [H]
-    # scale rows into the logits/context epilogues, 0 dequantizes the
-    # page in-register before the dots
+    # fused_dequant=1 is the historical epilogue: the per-head scales
+    # multiply the LOGITS (K) and the accumulated context (V) after the
+    # dots; 0 dequantizes the head's page slab BEFORE the dots.  Both
+    # stream 1 byte/element from HBM — the choice moves the multiply
+    # between the VPU epilogue and the MXU operand path, which is
+    # exactly the kind of platform-dependent tie the sweep measures.
     dims={"page_size": 16, "heads": 8, "head_dim": 128, "lane": 128,
           "head_align": 8, "q_align": 8, "fused_dequant": 1},
     blocks=(
@@ -435,33 +362,24 @@ PAGED_RAGGED_INT8 = KernelContract(
                   "int32", memory="smem"),
         BlockDecl("group_lens", "in", ("groups",), "int32",
                   memory="smem"),
-        BlockDecl("row_lens", "in", (1, "q_align"), "int32",
-                  lanes_full=True,
-                  waivers=("sublane: same trade as the ragged f32 "
-                           "contract's row_lens — one sub-tile int32 "
-                           "row per group by design",)),
-        BlockDecl("q", "in", (1, "q_align", "heads", "head_dim"),
+        BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
+                  lanes_full=True),
+        BlockDecl("q", "in", (1, "heads", "q_align", "head_dim"),
                   "float32"),
+        # int8 pages keep the f32 page layout (heads padded to 8, not
+        # the int8 floor 32 — padding H 4x for storage tiling would
+        # quadruple page bytes and defeat the int8 win); the block spans
+        # the pool's whole (padded) head extent, which the lowering
+        # accepts at any extent
         BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "int8",
-                  waivers=("sublane: int8 pages keep the f32 page "
-                           "layout (heads padded to 8, not the int8 "
-                           "floor 32) — same storage-vs-tiling trade "
-                           "as paged_attention_decode_int8's k_page",)),
+                  "int8", sublane_full=True),
         BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "int8",
-                  waivers=("sublane: same trade as k_page — see its "
-                           "waiver",)),
-        BlockDecl("k_scales", "in", (1, "heads"), "float32",
-                  lanes_full=True,
-                  waivers=("sublane: one [H] fp32 scale row rides each "
-                           "page DMA — a sub-tile row block by design "
-                           "(padding it to 8 rows would 8x the scale "
-                           "traffic for zeros)",)),
-        BlockDecl("v_scales", "in", (1, "heads"), "float32",
-                  lanes_full=True,
-                  waivers=("sublane: same trade as k_scales",)),
-        BlockDecl("o", "out", (1, "q_align", "heads", "head_dim"),
+                  "int8", sublane_full=True),
+        BlockDecl("k_scales", "in", (1, 1, "heads"), "float32",
+                  lanes_full=True, sublane_full=True),
+        BlockDecl("v_scales", "in", (1, 1, "heads"), "float32",
+                  lanes_full=True, sublane_full=True),
+        BlockDecl("o", "out", (1, "heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("acc", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
@@ -469,18 +387,26 @@ PAGED_RAGGED_INT8 = KernelContract(
                   "float32"),
         BlockDecl("l", "scratch", ("heads", "q_align", "lane"),
                   "float32"),
+        # Mosaic's sublane-strided load (head h's [P, D] slab of the
+        # page) exists for 32-bit data only: the int8 page is converted
+        # once into an f32 stage and the slabs are loaded from there
+        BlockDecl("k_stage", "scratch",
+                  ("page_size", "heads", "head_dim"), "float32"),
+        BlockDecl("v_stage", "scratch",
+                  ("page_size", "heads", "head_dim"), "float32"),
     ),
     shape_buckets={"head_dim": (128, 256), "heads": (8, 16, 32)},
     # fused_dequant moves the scale multiply across the dot — NOT
-    # bit-exact, non-default choices need an explicit sweep tolerance
-    # (docs/TUNING.md); head_align/q_align are exactly parity-preserving
+    # bit-exact (rounding points differ), so the non-default choice only
+    # survives a sweep run with an explicit tolerance (docs/TUNING.md);
+    # head_align/q_align are exactly parity-preserving
     sweep={"head_align": (8, 16), "q_align": (8, 16),
            "fused_dequant": (0, 1)},
 )
 
 # ===========================================================================
 # paged_attention.py — mesh-aware head-shard STATS form (ISSUE 19).
-# Same grid/scratch as the unified ragged contract, but the kernel runs
+# Same body/grid/scratch as the ragged contract, but the kernel runs
 # on ONE mesh shard: its page pool holds the shard's 1/sp of the pages
 # (and its H/tp head-shard of each), a third scalar-prefetch operand
 # masks page-table entries by OWNERSHIP, and alongside the locally-
@@ -502,26 +428,20 @@ PAGED_RAGGED_STATS = KernelContract(
                   memory="smem"),
         BlockDecl("page_ok", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
-        BlockDecl("row_lens", "in", (1, "q_align"), "int32",
-                  lanes_full=True,
-                  waivers=("sublane: same trade as the ragged f32 "
-                           "contract's row_lens — one sub-tile int32 "
-                           "row per group by design",)),
-        BlockDecl("q", "in", (1, "q_align", "heads", "head_dim"),
+        BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
+                  lanes_full=True),
+        BlockDecl("q", "in", (1, "heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
                   "float32"),
         BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
                   "float32"),
-        BlockDecl("o", "out", (1, "q_align", "heads", "head_dim"),
+        BlockDecl("o", "out", (1, "heads", "q_align", "head_dim"),
                   "float32"),
-        BlockDecl("lse", "out", (1, "q_align", "heads"), "float32",
-                  lanes_full=True,
-                  waivers=("lane: the [Qp, H] lse stats row spans the "
-                           "full head extent (H/tp local heads, not a "
-                           "128-lane tile) — one sub-lane stats block "
-                           "per group by design, like the flash "
-                           "kernels' lse",)),
+        # one lse value per (head, row), carried [.., q_align, 1] like
+        # the flash kernels' lse
+        BlockDecl("lse", "out", (1, "heads", "q_align", 1), "float32",
+                  lanes_full=True),
         BlockDecl("acc", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("m", "scratch", ("heads", "q_align", "lane"),
@@ -568,7 +488,6 @@ QUANTIZED_MATMUL = KernelContract(
 # autotuner iterate
 CONTRACTS: Dict[str, KernelContract] = {
     c.name: c for c in (FLASH_FWD, FLASH_BWD_DKV, FLASH_BWD_DQ,
-                        PAGED_DECODE, PAGED_DECODE_INT8,
                         PAGED_RAGGED, PAGED_RAGGED_INT8,
                         PAGED_RAGGED_STATS, QUANTIZED_MATMUL)
 }

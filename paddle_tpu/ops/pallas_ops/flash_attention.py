@@ -266,24 +266,14 @@ def _interpret_mode() -> bool:
 
 
 def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # param name drift across jax versions
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _sds(shape, dtype, ref):
     """ShapeDtypeStruct inheriting `ref`'s shard_map varying axes (vma) —
     required when the kernel runs inside shard_map (ring attention)."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(ref), "vma", None) if typeof is not None else None
-    if vma:
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-        except TypeError:  # older jax without vma kwarg
-            pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(ref).vma)
 
 
 def _flash_fwd_bhsd(q, k, v, mask, seed, scale, causal, dropout_p,

@@ -7,8 +7,10 @@ sequence owns a *page table* — a row of page ids into a global pool of
 fixed-size KV pages — and attention streams exactly the pages a sequence
 owns, masked to its true (ragged) length.  Kept deliberately small and
 composable (Tensor Processing Primitives style) next to
-``flash_attention.py``: one decode query per sequence, online-softmax
-accumulation page by page.
+``flash_attention.py``: ONE kernel body (``_ragged_body``) serves every
+form — a lane of Q query rows against its page-table row, online-softmax
+accumulation page by page; decode is Q = 1, the int8 and the mesh
+(stats) forms are flags of the same body.
 
 TPU mechanics: ``pltpu.PrefetchScalarGridSpec`` prefetches the page
 tables + sequence lengths into SMEM so the BlockSpec ``index_map`` can
@@ -50,19 +52,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .contracts import (PAGED_DECODE, PAGED_DECODE_INT8, PAGED_RAGGED,
-                        PAGED_RAGGED_INT8, PAGED_RAGGED_STATS)
+from .contracts import (PAGED_RAGGED, PAGED_RAGGED_INT8,
+                        PAGED_RAGGED_STATS)
 
 NEG_INF = -1e30
 
 # padding constants from the declared KernelContract (contracts.py):
-# heads pad to the f32 sublane floor, head_dim to the lane width — the
-# pallas-contract lint checks the same values the kernel runs with
-_HEAD_ALIGN = PAGED_DECODE.dim("head_align")
-_LANE = PAGED_DECODE.dim("lane")
-_FUSED_DEQUANT = PAGED_DECODE_INT8.dim("fused_dequant")
-# ragged-query variants (ISSUE 18): the per-lane query-row dim pads to
-# its own contract floor
+# heads pad to the f32 sublane floor, head_dim to the lane width, the
+# per-lane query-row dim to its own floor — the pallas-contract lint
+# checks the same values the kernel runs with
+_LANE = PAGED_RAGGED.dim("lane")
 _RAGGED_HEAD_ALIGN = PAGED_RAGGED.dim("head_align")
 _RAGGED_Q_ALIGN = PAGED_RAGGED.dim("q_align")
 _RAGGED_FUSED_DEQUANT = PAGED_RAGGED_INT8.dim("fused_dequant")
@@ -71,26 +70,11 @@ _STATS_HEAD_ALIGN = PAGED_RAGGED_STATS.dim("head_align")
 _STATS_Q_ALIGN = PAGED_RAGGED_STATS.dim("q_align")
 
 
-def _resolved_dims(H, D, quantized):
-    """(head_align, fused_dequant) for this call: tuning-table hit
-    (validate()-gated at the (heads, head_dim) shape bucket) ->
-    contract default.  With no table installed this is a single None
-    check — the historical padding/epilogue run unchanged."""
-    from ...tune.runtime import lookup_dims
-
-    contract = PAGED_DECODE_INT8 if quantized else PAGED_DECODE
-    tuned = lookup_dims(contract, {"heads": H, "head_dim": D},
-                        dtype="int8" if quantized else "float32")
-    if tuned is None:
-        return _HEAD_ALIGN, bool(_FUSED_DEQUANT)
-    return (tuned.get("head_align", _HEAD_ALIGN),
-            bool(tuned.get("fused_dequant", _FUSED_DEQUANT)))
-
-
 def _ragged_resolved_dims(H, D, quantized):
-    """(head_align, q_align, fused_dequant) for a ragged-query call —
-    same explicit-arg > table-hit > contract-default chain as
-    :func:`_resolved_dims`, against the ragged contracts."""
+    """(head_align, q_align, fused_dequant) for a ragged-query call:
+    tuning-table hit (validate()-gated at the (heads, head_dim) shape
+    bucket) -> contract default.  With no table installed this is a
+    single None check."""
     from ...tune.runtime import lookup_dims
 
     contract = PAGED_RAGGED_INT8 if quantized else PAGED_RAGGED
@@ -112,129 +96,16 @@ def _interpret_mode() -> bool:
 
 
 def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    except Exception:  # param name drift across jax versions
-        return None
-
-
-def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_sc, m_sc, l_sc, *, scale, page_size, num_pages_grid):
-    """Grid (B, max_pages_per_seq), pages innermost: per sequence b the
-    kernel visits its pages in order, keeping flash-style running
-    max/denominator in VMEM scratch; the page to DMA was chosen by the
-    index_map from the prefetched page table."""
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-
-    seq_len = sl_ref[b]
-
-    # ragged early-out: pages entirely past the sequence length do no work
-    @pl.when(i * page_size < seq_len)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale          # [H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [P, H, D]
-        v = v_ref[0].astype(jnp.float32)
-        # per-head q·k over the page: batch H, contract D -> [H, P]
-        s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                                preferred_element_type=jnp.float32)
-        H = q.shape[0]
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, page_size), 1)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
-        m_prev = m_sc[:, :1]                              # [H, 1]
-        l_prev = l_sc[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p [H, P] @ v [P, H, D]: batch H, contract P -> [H, D]
-        acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
-
-    @pl.when(i == num_pages_grid - 1)
-    def _write():
-        # empty sequences (seq_len == 0, e.g. padded batch lanes) have
-        # l == 0 and write exact zeros — the engine masks those lanes
-        l_safe = jnp.maximum(l_sc[:, :1], 1e-30)
-        o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
-
-
-def _decode_kernel_quant(pt_ref, sl_ref, q_ref, k_ref, v_ref, ks_ref,
-                         vs_ref, o_ref, acc_sc, m_sc, l_sc, *, scale,
-                         page_size, num_pages_grid, fused_dequant=True):
-    """Int8-KV variant of ``_decode_kernel``: the DMA'd page blocks are
-    int8 and ride with their [H] fp32 scale rows.  ``fused_dequant``
-    (a sweepable contract axis, ISSUE 14) picks WHERE the per-head
-    dequant multiply lands: True (the historical epilogue) folds it
-    into the logits (K) and the accumulated context contribution (V)
-    after the dots; False dequantizes the page in-register BEFORE the
-    dots.  Either way HBM streams 1 byte/element and everything after
-    is the same f32 online softmax — the two differ only in rounding
-    points and in which unit does the multiply."""
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-
-    seq_len = sl_ref[b]
-
-    @pl.when(i * page_size < seq_len)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale          # [H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [P, H, D] s8→f32
-        v = v_ref[0].astype(jnp.float32)
-        ks = ks_ref[0].astype(jnp.float32)                # [H] page K scale
-        vs = vs_ref[0].astype(jnp.float32)                # [H] page V scale
-        if not fused_dequant:
-            k = k * ks[None, :, None]                     # dequant K pre-dot
-            v = v * vs[None, :, None]                     # dequant V pre-dot
-        s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                                preferred_element_type=jnp.float32)
-        if fused_dequant:
-            s = s * ks[:, None]                           # dequant K
-        H = q.shape[0]
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, page_size), 1)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
-        m_prev = m_sc[:, :1]
-        l_prev = l_sc[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        ctx = jax.lax.dot_general(p, v, (((1,), (0,)), ((0,), (1,))),
-                                  preferred_element_type=jnp.float32)
-        if fused_dequant:
-            ctx = ctx * vs[:, None]                       # dequant V
-        acc_sc[:] = acc_sc[:] * alpha + ctx
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
-
-    @pl.when(i == num_pages_grid - 1)
-    def _write():
-        l_safe = jnp.maximum(l_sc[:, :1], 1e-30)
-        o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
 
 def paged_attention_kernel(q, k_pages, v_pages, page_tables, seq_lens,
                            k_scales=None, v_scales=None, *, interpret=None,
                            head_align=None, fused_dequant=None):
-    """The Pallas kernel proper (interpret mode off-TPU unless forced).
+    """One decode query per sequence — the ragged-query kernel at Q = 1
+    (the same computation: one query row per lane against the lane's
+    page-table row), so there is one kernel body to compile.
 
     q           [B, H, D]   one decode query per sequence
     k_pages     [N, P, H, D] global K page pool (page_size = P)
@@ -246,91 +117,11 @@ def paged_attention_kernel(q, k_pages, v_pages, page_tables, seq_lens,
     v_scales    [N, H] fp32  per-page-per-head V dequant scales
 
     Returns [B, H, D]; softmax scale 1/sqrt(D) is applied internally.
-
-    ``head_align`` (padding floor for H) and ``fused_dequant`` (where
-    the int8 scale multiply lands) resolve explicit argument >
-    tuning-table hit > contract default (``None`` selects the lookup).
     """
-    B, H, D = q.shape
-    page_size = k_pages.shape[1]
-    max_pages = page_tables.shape[1]
-    quantized = k_pages.dtype == jnp.int8
-    if quantized and (k_scales is None or v_scales is None):
-        raise ValueError("int8 KV pages require k_scales/v_scales")
-    if head_align is None or (quantized and fused_dequant is None):
-        t_align, t_fused = _resolved_dims(H, D, quantized)
-        head_align = t_align if head_align is None else head_align
-        fused_dequant = t_fused if fused_dequant is None else fused_dequant
-    # the softmax temperature comes from the REAL head_dim — computed
-    # before any tile padding so the padded kernel is numerically
-    # identical to the unpadded one (zero-padded D lanes add 0 to q·k)
-    scale = 1.0 / math.sqrt(D)
-    page_tables = page_tables.astype(jnp.int32)
-    seq_lens = seq_lens.astype(jnp.int32)
-
-    # mosaic wants the trailing block dims (H, D) tile-aligned on real
-    # TPU; pad unconditionally (cheap — decode arrays are small) so the
-    # CPU interpret tests exercise the exact same padded path as TPU
-    Hp = -(-H // head_align) * head_align
-    Dp = _LANE if D <= _LANE else -(-D // _LANE) * _LANE
-    if Hp != H or Dp != D:
-        q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, Dp - D)))
-        k_pages = jnp.pad(k_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        v_pages = jnp.pad(v_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        if quantized:
-            # padded heads multiply garbage rows that are sliced off; 1.0
-            # keeps the arithmetic finite
-            k_scales = jnp.pad(k_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-            v_scales = jnp.pad(v_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-    Bq, Hq, Dq = q.shape
-
-    in_specs = [
-        pl.BlockSpec((1, Hq, Dq), lambda b, i, pt, sl: (b, 0, 0)),
-        pl.BlockSpec((1, page_size, Hq, Dq),
-                     lambda b, i, pt, sl: (pt[b, i], 0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hq, Dq),
-                     lambda b, i, pt, sl: (pt[b, i], 0, 0, 0)),
-    ]
-    operands = [q, k_pages, v_pages]
-    kern = _decode_kernel
-    if quantized:
-        # the scale rows ride the same page-table index_map as the pages
-        in_specs += [
-            pl.BlockSpec((1, Hq), lambda b, i, pt, sl: (pt[b, i], 0)),
-            pl.BlockSpec((1, Hq), lambda b, i, pt, sl: (pt[b, i], 0)),
-        ]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
-        kern = functools.partial(_decode_kernel_quant,
-                                 fused_dequant=bool(fused_dequant))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,        # page_tables, seq_lens
-        grid=(B, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, Dq), lambda b, i, pt, sl: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, Dq), jnp.float32),
-            pltpu.VMEM((Hq, _LANE), jnp.float32),
-            pltpu.VMEM((Hq, _LANE), jnp.float32),
-        ],
-    )
-    out_dtype = q.dtype
-    out = pl.pallas_call(
-        functools.partial(kern, scale=scale, page_size=page_size,
-                          num_pages_grid=max_pages),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Dq), out_dtype),
-        compiler_params=_compiler_params(),
-        interpret=_interpret_mode() if interpret is None else interpret,
-    )(page_tables, seq_lens, *operands)
-    if Hq != H or Dq != D:
-        out = out[:, :H, :D]
-    return out
+    return ragged_paged_attention_kernel(
+        q[:, None], k_pages, v_pages, page_tables, seq_lens[:, None],
+        k_scales, v_scales, interpret=interpret, head_align=head_align,
+        fused_dequant=fused_dequant)[:, 0]
 
 
 def paged_attention_xla(q, k_pages, v_pages, page_tables, seq_lens,
@@ -403,15 +194,44 @@ def paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
 # ===========================================================================
 
 
-def _ragged_kernel(pt_ref, gl_ref, rl_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_sc, m_sc, l_sc, *, scale, page_size,
-                   num_pages_grid):
-    """Grid (G, max_pages_per_seq), pages innermost — the decode kernel's
-    online softmax widened by the query-row dim.  The group early-out
-    keys on the LANE's max horizon (``gl_ref``); rows shorter than the
-    lane mask the tail pages per row.  A row fully masked on an active
-    page keeps m == NEG_INF, so probabilities are re-masked AFTER the
-    exp (exp(NEG_INF - NEG_INF) == 1 would otherwise corrupt l)."""
+def _ragged_body(*refs, scale, page_size, num_pages_grid, heads,
+                 quantized, stats, fused_dequant, staged):
+    """Grid (G, max_pages_per_seq), pages innermost: per lane g the body
+    visits the lane's pages in order, keeping flash-style running
+    max/denominator per (head, query row) in VMEM scratch; the page to
+    DMA was chosen by the index_map from the prefetched page table.
+
+    One body serves every form (``quantized``: int8 pages + scale rows;
+    ``stats``: page-ownership mask + lse output) because each is the same
+    page step, written the way Mosaic accepts it:
+
+    - heads are a STATIC loop of 2-D matmuls ([Qp, D] x [D, P] and
+      [Qp, P] x [P, D]) — Mosaic has no dot with a batch dim in the
+      middle of an operand, and q/o/lse are head-major ([H, Qp, .]) so
+      ``ref[0, h]`` is a whole tile and nothing is transposed in-kernel;
+    - head h's [P, D] slab of the [P, H, D] page is a sublane-strided
+      load from the page viewed as [P*H, D] (a free view: H is padded to
+      the f32 sublane tile);
+    - strided loads exist for 32-bit data only, so bf16/int8 pages are
+      converted once per page into an f32 VMEM stage (``staged``) and
+      the slabs are loaded from there.
+
+    The lane early-out keys on the lane's LONGEST row (``gl_ref``); rows
+    shorter than that mask the tail per row.  A row fully masked on an
+    active page keeps m == NEG_INF, so probabilities are re-masked AFTER
+    the exp (exp(NEG_INF - NEG_INF) == 1 would otherwise corrupt l)."""
+    # refs arrive as: scalar prefetch, inputs, outputs, scratch — the
+    # optional ones present only in the form that uses them
+    it = iter(refs)
+    _pt_ref, gl_ref = next(it), next(it)     # page table: index_maps only
+    ok_ref = next(it) if stats else None
+    rl_ref, q_ref, k_ref, v_ref = (next(it) for _ in range(4))
+    ks_ref, vs_ref = (next(it), next(it)) if quantized else (None, None)
+    o_ref = next(it)
+    lse_ref = next(it) if stats else None
+    acc_sc, m_sc, l_sc = (next(it) for _ in range(3))
+    k_stage, v_stage = (next(it), next(it)) if staged else (None, None)
+
     g = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -421,107 +241,188 @@ def _ragged_kernel(pt_ref, gl_ref, rl_ref, q_ref, k_ref, v_ref, o_ref,
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    group_len = gl_ref[g]
+    # ragged early-out: pages entirely past the lane's longest row (and,
+    # in the stats form, pages this shard does not own) do no work
+    live = i * page_size < gl_ref[g]
+    if stats:
+        live = live & (ok_ref[g, i] != 0)
 
-    @pl.when(i * page_size < group_len)
+    @pl.when(live)
     def _step():
-        q = q_ref[0].astype(jnp.float32) * scale          # [Qp, H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [P, H, D]
-        v = v_ref[0].astype(jnp.float32)
-        rl = rl_ref[0]                                    # [Qp] int32
-        # per-head q·k over the page: batch H, contract D -> [H, Qp, P]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((1,), (1,))),
-                                preferred_element_type=jnp.float32)
-        H, Qp, P = s.shape
+        rl = rl_ref[0]                                    # [Qp, 1] int32
+        Qp = rl.shape[0]
+        _, P, Hq, Dq = k_ref.shape
         pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, Qp, P), 2)
-        valid = pos < rl[None, :, None]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_sc[:, :, :1]                           # [H, Qp, 1]
-        l_prev = l_sc[:, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p [H, Qp, P] @ v [P, H, D]: batch H, contract P -> [H, Qp, D]
-        acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+            jnp.int32, (Qp, P), 1)
+        valid = pos < rl                                  # [Qp, P]
+        if staged:
+            k_stage[:] = k_ref[0].astype(jnp.float32)
+            v_stage[:] = v_ref[0].astype(jnp.float32)
+            k2 = k_stage.reshape(P * Hq, Dq)
+            v2 = v_stage.reshape(P * Hq, Dq)
+        else:
+            k2 = k_ref.at[0].reshape(P * Hq, Dq)
+            v2 = v_ref.at[0].reshape(P * Hq, Dq)
+        if quantized:
+            ks_row = ks_ref[0]                            # [1, Hq] f32
+            vs_row = vs_ref[0]
+        for h in range(heads):
+            q = q_ref[0, h].astype(jnp.float32) * scale   # [Qp, D]
+            k = k2[pl.ds(h, P, stride=Hq), :]             # [P, D] f32
+            v = v2[pl.ds(h, P, stride=Hq), :]
+            if quantized:
+                ks = ks_row[:, h:h + 1]                   # [1, 1]
+                vs = vs_row[:, h:h + 1]
+                if not fused_dequant:
+                    k = k * ks                            # dequant pre-dot
+                    v = v * vs
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if quantized and fused_dequant:
+                s = s * ks                                # dequant K
+            s = jnp.where(valid, s, NEG_INF)              # [Qp, P]
+            m_prev = m_sc[h][:, :1]                       # [Qp, 1]
+            l_prev = l_sc[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            ctx = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            if quantized and fused_dequant:
+                ctx = ctx * vs                            # dequant V
+            acc_sc[h] = acc_sc[h] * alpha + ctx
+            m_sc[h] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+            l_sc[h] = jnp.broadcast_to(l_new, l_sc.shape[1:])
 
     @pl.when(i == num_pages_grid - 1)
     def _write():
-        # rows with row_len == 0 (padding) have l == 0 -> exact zeros
-        l_safe = jnp.maximum(l_sc[:, :, :1], 1e-30)
-        o_ref[0] = jnp.transpose(acc_sc[:] / l_safe,
-                                 (1, 0, 2)).astype(o_ref.dtype)
+        for h in range(heads):
+            # rows with row_len == 0 (padding) have l == 0 -> exact zeros
+            l_cur = l_sc[h][:, :1]
+            l_safe = jnp.maximum(l_cur, 1e-30)
+            o_ref[0, h] = (acc_sc[h] / l_safe).astype(o_ref.dtype)
+            if stats:
+                # a row with NO owned/visible positions keeps l == 0: lse
+                # is NEG_INF so the merge weight exp(lse - M) underflows
+                lse_ref[0, h] = jnp.where(
+                    l_cur > 0, m_sc[h][:, :1] + jnp.log(l_safe), NEG_INF)
 
 
-def _ragged_kernel_quant(pt_ref, gl_ref, rl_ref, q_ref, k_ref, v_ref,
-                         ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc, *,
-                         scale, page_size, num_pages_grid,
-                         fused_dequant=True):
-    """Int8-KV variant of ``_ragged_kernel`` — the scale rows ride the
-    page DMA exactly as in ``_decode_kernel_quant``, paid once per lane
-    per page for all of the lane's query rows."""
-    g = pl.program_id(0)
-    i = pl.program_id(1)
+def _ragged_call(q, k_pages, v_pages, page_tables, row_lens, page_ok,
+                 k_scales, v_scales, *, interpret, head_align, q_align,
+                 fused_dequant):
+    """Pad, lay out and launch ``_ragged_body``; returns ``(out, lse)``
+    with ``lse`` None unless ``page_ok`` selects the stats form."""
+    G, Qb, H, D = q.shape
+    page_size = k_pages.shape[1]
+    max_pages = page_tables.shape[1]
+    quantized = k_pages.dtype == jnp.int8
+    stats = page_ok is not None
+    if quantized and (k_scales is None or v_scales is None):
+        raise ValueError("int8 KV pages require k_scales/v_scales")
+    # the softmax temperature comes from the REAL head_dim — computed
+    # before any tile padding so the padded kernel is numerically
+    # identical to the unpadded one (zero-padded D lanes add 0 to q·k)
+    scale = 1.0 / math.sqrt(D)
+    row_lens = row_lens.astype(jnp.int32)
 
-    @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+    # pad the query-row dim to the contract floor (padded rows carry
+    # row_len 0 and are sliced off); mosaic wants the pages' trailing
+    # (H, D) tile-aligned — pad unconditionally so the CPU interpret
+    # tests exercise the exact same padded path as TPU
+    Qp = -(-Qb // q_align) * q_align
+    Hp = -(-H // head_align) * head_align
+    Dp = _LANE if D <= _LANE else -(-D // _LANE) * _LANE
+    if Qp != Qb:
+        q = jnp.pad(q, ((0, 0), (0, Qp - Qb), (0, 0), (0, 0)))
+        row_lens = jnp.pad(row_lens, ((0, 0), (0, Qp - Qb)))
+    if Hp != H or Dp != D:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
+        k_pages = jnp.pad(k_pages,
+                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
+        v_pages = jnp.pad(v_pages,
+                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
+        if quantized:
+            # padded heads are never visited (the body loops the REAL
+            # head count); 1.0 keeps the rows finite all the same
+            k_scales = jnp.pad(k_scales, ((0, 0), (0, Hp - H)),
+                               constant_values=1.0)
+            v_scales = jnp.pad(v_scales, ((0, 0), (0, Hp - H)),
+                               constant_values=1.0)
+    # head-major query/output: ``ref[0, h]`` is a whole [Qp, D] tile
+    q = jnp.swapaxes(q, 1, 2)                             # [G, Hp, Qp, Dp]
+    # the lane's page early-out keys on its longest row
+    group_lens = jnp.max(row_lens, axis=1)
+    # strided slab loads need 32-bit data: other pools are staged as f32
+    staged = k_pages.dtype != jnp.float32
 
-    group_len = gl_ref[g]
+    def lane(*tail):
+        return lambda g, i, *prefetch: (g,) + tail
 
-    @pl.when(i * page_size < group_len)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale          # [Qp, H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [P, H, D]
-        v = v_ref[0].astype(jnp.float32)
-        ks = ks_ref[0].astype(jnp.float32)                # [H] page K scale
-        vs = vs_ref[0].astype(jnp.float32)                # [H] page V scale
-        rl = rl_ref[0]                                    # [Qp] int32
-        if not fused_dequant:
-            k = k * ks[None, :, None]                     # dequant K pre-dot
-            v = v * vs[None, :, None]                     # dequant V pre-dot
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((1,), (1,))),
-                                preferred_element_type=jnp.float32)
-        if fused_dequant:
-            s = s * ks[:, None, None]                     # dequant K
-        H, Qp, P = s.shape
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, Qp, P), 2)
-        valid = pos < rl[None, :, None]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_sc[:, :, :1]
-        l_prev = l_sc[:, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        ctx = jax.lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))),
-                                  preferred_element_type=jnp.float32)
-        if fused_dequant:
-            ctx = ctx * vs[:, None, None]                 # dequant V
-        acc_sc[:] = acc_sc[:] * alpha + ctx
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+    def page(*tail):
+        return lambda g, i, pt, *prefetch: (pt[g, i],) + tail
 
-    @pl.when(i == num_pages_grid - 1)
-    def _write():
-        l_safe = jnp.maximum(l_sc[:, :, :1], 1e-30)
-        o_ref[0] = jnp.transpose(acc_sc[:] / l_safe,
-                                 (1, 0, 2)).astype(o_ref.dtype)
+    # every trailing-dims pair below is (8k, 128k) or the whole array
+    # extent, the rule the TPU lowering enforces: row_lens rides as
+    # [G, Qp, 1], the scale rows as [N, 1, Hp]
+    in_specs = [
+        pl.BlockSpec((1, Qp, 1), lane(0, 0)),
+        pl.BlockSpec((1, Hp, Qp, Dp), lane(0, 0, 0)),
+        pl.BlockSpec((1, page_size, Hp, Dp), page(0, 0, 0)),
+        pl.BlockSpec((1, page_size, Hp, Dp), page(0, 0, 0)),
+    ]
+    operands = [row_lens[:, :, None], q, k_pages, v_pages]
+    if quantized:
+        # the scale rows ride the same page-table index_map as the pages
+        in_specs += [pl.BlockSpec((1, 1, Hp), page(0, 0)),
+                     pl.BlockSpec((1, 1, Hp), page(0, 0))]
+        operands += [k_scales.astype(jnp.float32)[:, None, :],
+                     v_scales.astype(jnp.float32)[:, None, :]]
+    out_specs = [pl.BlockSpec((1, Hp, Qp, Dp), lane(0, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((G, Hp, Qp, Dp), q.dtype)]
+    if stats:
+        out_specs.append(pl.BlockSpec((1, Hp, Qp, 1), lane(0, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((G, Hp, Qp, 1), jnp.float32))
+    scratch_shapes = [
+        pltpu.VMEM((Hp, Qp, Dp), jnp.float32),
+        pltpu.VMEM((Hp, Qp, _LANE), jnp.float32),
+        pltpu.VMEM((Hp, Qp, _LANE), jnp.float32),
+    ]
+    if staged:
+        scratch_shapes += [pltpu.VMEM((page_size, Hp, Dp), jnp.float32),
+                           pltpu.VMEM((page_size, Hp, Dp), jnp.float32)]
+    prefetch = [page_tables.astype(jnp.int32), group_lens]
+    if stats:
+        prefetch.append(page_ok.astype(jnp.int32))
+
+    outs = pl.pallas_call(
+        functools.partial(_ragged_body, scale=scale, page_size=page_size,
+                          num_pages_grid=max_pages, heads=H,
+                          quantized=quantized, stats=stats,
+                          fused_dequant=bool(fused_dequant), staged=staged),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(G, max_pages),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=_compiler_params(),
+        interpret=_interpret_mode() if interpret is None else interpret,
+    )(*prefetch, *operands)
+    out = jnp.swapaxes(outs[0], 1, 2)[:, :Qb, :H, :D]
+    if not stats:
+        return out, None
+    return out, jnp.swapaxes(outs[1][..., 0], 1, 2)[:, :Qb, :H]
 
 
 def ragged_paged_attention_kernel(q, k_pages, v_pages, page_tables,
                                   row_lens, k_scales=None, v_scales=None,
                                   *, interpret=None, head_align=None,
                                   q_align=None, fused_dequant=None):
-    """The ragged-query Pallas kernel proper.
+    """The ragged-query Pallas kernel proper (interpret mode off-TPU
+    unless forced).
 
     q           [G, Qb, H, D]  Qb query rows per lane (decode lane: row 0
                                real, rest padded; prefill lane: chunk
@@ -538,88 +439,18 @@ def ragged_paged_attention_kernel(q, k_pages, v_pages, page_tables,
     ``head_align``/``q_align``/``fused_dequant`` resolve explicit
     argument > tuning-table hit > contract default.
     """
-    G, Qb, H, D = q.shape
-    page_size = k_pages.shape[1]
-    max_pages = page_tables.shape[1]
+    H, D = q.shape[2:]
     quantized = k_pages.dtype == jnp.int8
-    if quantized and (k_scales is None or v_scales is None):
-        raise ValueError("int8 KV pages require k_scales/v_scales")
     if head_align is None or q_align is None \
             or (quantized and fused_dequant is None):
         t_align, t_q, t_fused = _ragged_resolved_dims(H, D, quantized)
         head_align = t_align if head_align is None else head_align
         q_align = t_q if q_align is None else q_align
         fused_dequant = t_fused if fused_dequant is None else fused_dequant
-    scale = 1.0 / math.sqrt(D)
-    page_tables = page_tables.astype(jnp.int32)
-    row_lens = row_lens.astype(jnp.int32)
-
-    # pad the query-row dim to the contract floor (padded rows carry
-    # row_len 0 and are sliced off) and H/D exactly as the decode kernel
-    Qp = -(-Qb // q_align) * q_align
-    Hp = -(-H // head_align) * head_align
-    Dp = _LANE if D <= _LANE else -(-D // _LANE) * _LANE
-    if Qp != Qb:
-        q = jnp.pad(q, ((0, 0), (0, Qp - Qb), (0, 0), (0, 0)))
-        row_lens = jnp.pad(row_lens, ((0, 0), (0, Qp - Qb)))
-    if Hp != H or Dp != D:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        k_pages = jnp.pad(k_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        v_pages = jnp.pad(v_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        if quantized:
-            k_scales = jnp.pad(k_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-            v_scales = jnp.pad(v_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-    Gq, Qq, Hq, Dq = q.shape
-    # the lane's page early-out keys on its longest row
-    group_lens = jnp.max(row_lens, axis=1).astype(jnp.int32)
-
-    in_specs = [
-        pl.BlockSpec((1, Qq), lambda g, i, pt, gl: (g, 0)),
-        pl.BlockSpec((1, Qq, Hq, Dq), lambda g, i, pt, gl: (g, 0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hq, Dq),
-                     lambda g, i, pt, gl: (pt[g, i], 0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hq, Dq),
-                     lambda g, i, pt, gl: (pt[g, i], 0, 0, 0)),
-    ]
-    operands = [row_lens, q, k_pages, v_pages]
-    kern = _ragged_kernel
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, Hq), lambda g, i, pt, gl: (pt[g, i], 0)),
-            pl.BlockSpec((1, Hq), lambda g, i, pt, gl: (pt[g, i], 0)),
-        ]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
-        kern = functools.partial(_ragged_kernel_quant,
-                                 fused_dequant=bool(fused_dequant))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,        # page_tables, group_lens
-        grid=(G, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Qq, Hq, Dq),
-                               lambda g, i, pt, gl: (g, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, Qq, Dq), jnp.float32),
-            pltpu.VMEM((Hq, Qq, _LANE), jnp.float32),
-            pltpu.VMEM((Hq, Qq, _LANE), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(kern, scale=scale, page_size=page_size,
-                          num_pages_grid=max_pages),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Gq, Qq, Hq, Dq), q.dtype),
-        compiler_params=_compiler_params(),
-        interpret=_interpret_mode() if interpret is None else interpret,
-    )(page_tables, group_lens, *operands)
-    if Qq != Qb or Hq != H or Dq != D:
-        out = out[:, :Qb, :H, :D]
-    return out
+    return _ragged_call(q, k_pages, v_pages, page_tables, row_lens, None,
+                        k_scales, v_scales, interpret=interpret,
+                        head_align=head_align, q_align=q_align,
+                        fused_dequant=fused_dequant)[0]
 
 
 def ragged_paged_attention_xla(q, k_pages, v_pages, page_tables,
@@ -678,126 +509,6 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, row_lens,
 # ===========================================================================
 
 
-def _ragged_stats_kernel(pt_ref, gl_ref, ok_ref, rl_ref, q_ref, k_ref,
-                         v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc, *,
-                         scale, page_size, num_pages_grid):
-    """``_ragged_kernel`` widened with a page-ownership mask (third
-    scalar-prefetch operand) and an lse output: grid cell (g, i) skips
-    non-owned pages' contributions entirely, and the final write emits
-    the running stats alongside the locally-normalized context."""
-    g = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-
-    group_len = gl_ref[g]
-
-    @pl.when((i * page_size < group_len) & (ok_ref[g, i] != 0))
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale          # [Qp, H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [P, H, D]
-        v = v_ref[0].astype(jnp.float32)
-        rl = rl_ref[0]                                    # [Qp] int32
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((1,), (1,))),
-                                preferred_element_type=jnp.float32)
-        H, Qp, P = s.shape
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, Qp, P), 2)
-        valid = pos < rl[None, :, None]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_sc[:, :, :1]
-        l_prev = l_sc[:, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
-
-    @pl.when(i == num_pages_grid - 1)
-    def _write():
-        l_cur = l_sc[:, :, :1]
-        l_safe = jnp.maximum(l_cur, 1e-30)
-        o_ref[0] = jnp.transpose(acc_sc[:] / l_safe,
-                                 (1, 0, 2)).astype(o_ref.dtype)
-        # a row with NO owned/visible positions keeps l == 0: lse is
-        # NEG_INF so the merge weight exp(lse - M) underflows to 0
-        lse = jnp.where(l_cur > 0, m_sc[:, :, :1] + jnp.log(l_safe),
-                        NEG_INF)
-        lse_ref[0] = jnp.transpose(lse[:, :, 0], (1, 0))
-
-
-def _ragged_stats_kernel_quant(pt_ref, gl_ref, ok_ref, rl_ref, q_ref,
-                               k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                               lse_ref, acc_sc, m_sc, l_sc, *, scale,
-                               page_size, num_pages_grid,
-                               fused_dequant=True):
-    """Int8-KV variant of ``_ragged_stats_kernel`` — in-register dequant
-    exactly as ``_ragged_kernel_quant``; the K scale lands before the
-    running max so lse is the dequantized logits' log-sum-exp."""
-    g = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-
-    group_len = gl_ref[g]
-
-    @pl.when((i * page_size < group_len) & (ok_ref[g, i] != 0))
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale          # [Qp, H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [P, H, D]
-        v = v_ref[0].astype(jnp.float32)
-        ks = ks_ref[0].astype(jnp.float32)                # [H] page K scale
-        vs = vs_ref[0].astype(jnp.float32)                # [H] page V scale
-        rl = rl_ref[0]                                    # [Qp] int32
-        if not fused_dequant:
-            k = k * ks[None, :, None]
-            v = v * vs[None, :, None]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((1,), (1,))),
-                                preferred_element_type=jnp.float32)
-        if fused_dequant:
-            s = s * ks[:, None, None]
-        H, Qp, P = s.shape
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, Qp, P), 2)
-        valid = pos < rl[None, :, None]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_sc[:, :, :1]
-        l_prev = l_sc[:, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        ctx = jax.lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))),
-                                  preferred_element_type=jnp.float32)
-        if fused_dequant:
-            ctx = ctx * vs[:, None, None]
-        acc_sc[:] = acc_sc[:] * alpha + ctx
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
-
-    @pl.when(i == num_pages_grid - 1)
-    def _write():
-        l_cur = l_sc[:, :, :1]
-        l_safe = jnp.maximum(l_cur, 1e-30)
-        o_ref[0] = jnp.transpose(acc_sc[:] / l_safe,
-                                 (1, 0, 2)).astype(o_ref.dtype)
-        lse = jnp.where(l_cur > 0, m_sc[:, :, :1] + jnp.log(l_safe),
-                        NEG_INF)
-        lse_ref[0] = jnp.transpose(lse[:, :, 0], (1, 0))
-
-
 def ragged_paged_attention_stats_kernel(q, k_pages, v_pages, page_tables,
                                         row_lens, page_ok, k_scales=None,
                                         v_scales=None, *, interpret=None,
@@ -806,92 +517,16 @@ def ragged_paged_attention_stats_kernel(q, k_pages, v_pages, page_tables,
     """The stats-form Pallas kernel proper — ``ragged_paged_attention_kernel``
     plus a ``page_ok [G, M]`` ownership mask (third scalar prefetch) and
     an lse output.  Returns ``(o [G, Qb, H, D], lse [G, Qb, H] f32)``."""
-    G, Qb, H, D = q.shape
-    page_size = k_pages.shape[1]
-    max_pages = page_tables.shape[1]
-    quantized = k_pages.dtype == jnp.int8
-    if quantized and (k_scales is None or v_scales is None):
-        raise ValueError("int8 KV pages require k_scales/v_scales")
     if head_align is None:
         head_align = _STATS_HEAD_ALIGN
     if q_align is None:
         q_align = _STATS_Q_ALIGN
-    if quantized and fused_dequant is None:
+    if fused_dequant is None:
         fused_dequant = bool(_RAGGED_FUSED_DEQUANT)
-    scale = 1.0 / math.sqrt(D)
-    page_tables = page_tables.astype(jnp.int32)
-    row_lens = row_lens.astype(jnp.int32)
-    page_ok = page_ok.astype(jnp.int32)
-
-    Qp = -(-Qb // q_align) * q_align
-    Hp = -(-H // head_align) * head_align
-    Dp = _LANE if D <= _LANE else -(-D // _LANE) * _LANE
-    if Qp != Qb:
-        q = jnp.pad(q, ((0, 0), (0, Qp - Qb), (0, 0), (0, 0)))
-        row_lens = jnp.pad(row_lens, ((0, 0), (0, Qp - Qb)))
-    if Hp != H or Dp != D:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        k_pages = jnp.pad(k_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        v_pages = jnp.pad(v_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        if quantized:
-            k_scales = jnp.pad(k_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-            v_scales = jnp.pad(v_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-    Gq, Qq, Hq, Dq = q.shape
-    group_lens = jnp.max(row_lens, axis=1).astype(jnp.int32)
-
-    in_specs = [
-        pl.BlockSpec((1, Qq), lambda g, i, pt, gl, ok: (g, 0)),
-        pl.BlockSpec((1, Qq, Hq, Dq),
-                     lambda g, i, pt, gl, ok: (g, 0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hq, Dq),
-                     lambda g, i, pt, gl, ok: (pt[g, i], 0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hq, Dq),
-                     lambda g, i, pt, gl, ok: (pt[g, i], 0, 0, 0)),
-    ]
-    operands = [row_lens, q, k_pages, v_pages]
-    kern = _ragged_stats_kernel
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, Hq), lambda g, i, pt, gl, ok: (pt[g, i], 0)),
-            pl.BlockSpec((1, Hq), lambda g, i, pt, gl, ok: (pt[g, i], 0)),
-        ]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
-        kern = functools.partial(_ragged_stats_kernel_quant,
-                                 fused_dequant=bool(fused_dequant))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,        # page_tables, group_lens, page_ok
-        grid=(G, max_pages),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, Qq, Hq, Dq),
-                         lambda g, i, pt, gl, ok: (g, 0, 0, 0)),
-            pl.BlockSpec((1, Qq, Hq), lambda g, i, pt, gl, ok: (g, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((Hq, Qq, Dq), jnp.float32),
-            pltpu.VMEM((Hq, Qq, _LANE), jnp.float32),
-            pltpu.VMEM((Hq, Qq, _LANE), jnp.float32),
-        ],
-    )
-    out, lse = pl.pallas_call(
-        functools.partial(kern, scale=scale, page_size=page_size,
-                          num_pages_grid=max_pages),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Gq, Qq, Hq, Dq), q.dtype),
-                   jax.ShapeDtypeStruct((Gq, Qq, Hq), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=_interpret_mode() if interpret is None else interpret,
-    )(page_tables, group_lens, page_ok, *operands)
-    if Qq != Qb or Hq != H or Dq != D:
-        out = out[:, :Qb, :H, :D]
-        lse = lse[:, :Qb, :H]
-    return out, lse
+    return _ragged_call(q, k_pages, v_pages, page_tables, row_lens,
+                        page_ok, k_scales, v_scales, interpret=interpret,
+                        head_align=head_align, q_align=q_align,
+                        fused_dequant=fused_dequant)
 
 
 def ragged_paged_attention_stats_xla(q, k_pages, v_pages, page_tables,

@@ -74,11 +74,8 @@ def _interpret_mode() -> bool:
 
 
 def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # param name drift across jax versions
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_sc, *, k_steps):

@@ -344,21 +344,15 @@ class ProfiledJit:
             with self._lock:
                 compiled = self._compiled.get(sig)
                 if compiled is None:
-                    try:
-                        compiled = self._compile_for(sig, args, kwargs)
-                    except Exception:  # noqa: BLE001 — AOT unsupported
-                        compiled = False    # remembered: don't retry
-                        # the plain-jit fallback still traces+compiles
-                        # this signature exactly once — the ledger's
-                        # compile accounting must not lose it
-                        compile_ledger.on_compile(
-                            self.name, self._sig_str(sig),
-                            fallback=True)
+                    # a compile error surfaces as itself: a retry through
+                    # plain jit would re-raise it later under another
+                    # name, or hide a kernel the compiler refused
+                    compiled = self._compile_for(sig, args, kwargs)
                     self._compiled[sig] = compiled
         # timer starts AFTER compilation: compile time is attributed
         # separately (record_compile) and must not pollute call latency
         t0 = time.perf_counter()
-        if compiled:
+        if compiled is not None:
             # no fallback on failure here: the signature key pins the
             # avals, and re-running through plain jit after a failed
             # call could touch already-donated buffers (the engine

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import dtype as _dt
-from ..framework.export_compat import jax_export
+import jax.export
 from ..tensor import Parameter, Tensor
 
 
@@ -278,7 +278,7 @@ class Program:
         param_vals = [p._value for _, p in param_items]
         param_specs = [jax.ShapeDtypeStruct(p.shape, p.dtype)
                        for p in param_vals]
-        exported = jax_export().export(jax.jit(infer))(feed_specs, param_specs)
+        exported = jax.export.export(jax.jit(infer))(feed_specs, param_specs)
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         with open(path + ".program", "wb") as f:
             f.write(exported.serialize())
@@ -323,7 +323,7 @@ class Program:
         param_vals = [p._value for _, p in param_items]
         state_vals = [raw_state(t, sp)
                       for (_, (t, *_r)), sp in zip(state_items, specs)]
-        exported = jax_export().export(jax.jit(train_step))(
+        exported = jax.export.export(jax.jit(train_step))(
             feed_specs,
             [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in param_vals],
             [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in state_vals])
@@ -359,7 +359,7 @@ class LoadedTrainProgram:
 
     def __init__(self, path):
         with open(path + ".trainprogram", "rb") as f:
-            self._exported = jax_export().deserialize(f.read())
+            self._exported = jax.export.deserialize(f.read())
         with open(path + ".trainstate", "rb") as f:
             meta = pickle.load(f)
         self.params = [jnp.asarray(p) for p in meta["params"]]
@@ -411,7 +411,7 @@ class LoadedProgram:
 
     def __init__(self, path):
         with open(path + ".program", "rb") as f:
-            self._exported = jax_export().deserialize(f.read())
+            self._exported = jax.export.deserialize(f.read())
         with open(path + ".params", "rb") as f:
             meta = pickle.load(f)
         self._params = [jnp.asarray(p) for p in meta["params"]]

@@ -445,8 +445,11 @@ def _make_gpt_paged_sharded_core(model, page_size: int, pages_per_seq: int,
         kvs = layout.kv_spec(kv)
         in_specs = (cspecs, P(), P(), P(), P(), kvs)
         out_specs = (P(), kvs) if with_head else kvs
-        f = mesh_lib.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+        # check_vma=False: the logits are replicated in VALUE (every
+        # shard all-gathers the same context) but jax types an
+        # all_gather result as varying, and pallas_call carries no vma
+        f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
         vlen = valid_len if has_vl else jnp.zeros((), jnp.int32)
         out = f(consts, tokens, pos, page_tables, vlen, kv)
         return out if with_head else (None, out)

@@ -50,12 +50,6 @@ DEFAULT_EXTENTS: Dict[str, List[Dict[str, int]]] = {
     "flash_attention_bwd_dq": [
         {"block_q": 2048, "block_k": 2048},
     ],
-    "paged_attention_decode": [
-        {"heads": 8, "head_dim": 128},
-    ],
-    "paged_attention_decode_int8": [
-        {"heads": 8, "head_dim": 128},
-    ],
     "paged_attention_ragged": [
         {"heads": 8, "head_dim": 128},
     ],
@@ -63,8 +57,7 @@ DEFAULT_EXTENTS: Dict[str, List[Dict[str, int]]] = {
         {"heads": 8, "head_dim": 128},
     ],
 }
-_KERNEL_DTYPE = {"paged_attention_decode_int8": "int8",
-                 "paged_attention_ragged_int8": "int8",
+_KERNEL_DTYPE = {"paged_attention_ragged_int8": "int8",
                  "quantized_matmul": "int8_weights"}
 
 

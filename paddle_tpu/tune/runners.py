@@ -13,11 +13,9 @@ Runners exist for the kernels with a runtime-swappable config:
 ``flash_attention_bwd_dkv`` / ``..._bwd_dq`` (the grad-path pair:
 forward stats are precomputed ONCE at the default blocks, each
 candidate re-tiles only the backward kernel under the sweep's parity
-gate — ISSUE 18), ``paged_attention_decode`` / ``..._int8`` (head
-padding floor, and the int8 fused-dequant epilogue choice),
-``paged_attention_ragged`` / ``..._int8`` (query-row and head padding
-floors for the unified serving dispatch) and ``quantized_matmul``
-(block_m/n/k).
+gate — ISSUE 18), ``paged_attention_ragged`` / ``..._int8`` (query-row
+and head padding floors for the unified serving dispatch, and the int8
+fused-dequant epilogue choice) and ``quantized_matmul`` (block_m/n/k).
 
 Kernel modules are imported lazily inside each runner so this package
 never participates in an import cycle with ``ops.pallas_ops``.
@@ -193,73 +191,6 @@ def _flash_dq_runner(contract: KernelContract,
 
     def run(choice):
         return jit_for(choice)(q, k, v, g, lse, delta, mask, seed)
-
-    return run
-
-
-def _paged_inputs(bucket: Mapping[str, int], page_size: int,
-                  int8: bool):
-    import jax.numpy as jnp
-
-    H, D = bucket["heads"], bucket["head_dim"]
-    N, B, M = 9, 2, 4
-    rng = np.random.RandomState(2)
-    q = jnp.asarray(rng.randn(B, H, D).astype(np.float32) * 0.3)
-    kf = rng.randn(N, page_size, H, D).astype(np.float32)
-    vf = rng.randn(N, page_size, H, D).astype(np.float32)
-    pt = np.zeros((B, M), np.int32)
-    pt[0, :3] = [1, 2, 3]
-    pt[1, :4] = [4, 5, 6, 7]
-    sl = jnp.asarray(np.array([page_size * 2 + 3, page_size * 4],
-                              np.int32))
-    pt = jnp.asarray(pt)
-    if not int8:
-        return q, jnp.asarray(kf), jnp.asarray(vf), pt, sl, None, None
-    ks = (np.abs(kf).max(axis=(1, 3)) / 127 + 1e-9).astype(np.float32)
-    vs = (np.abs(vf).max(axis=(1, 3)) / 127 + 1e-9).astype(np.float32)
-    kq = np.clip(np.round(kf / ks[:, None, :, None]), -127,
-                 127).astype(np.int8)
-    vq = np.clip(np.round(vf / vs[:, None, :, None]), -127,
-                 127).astype(np.int8)
-    return (q, jnp.asarray(kq), jnp.asarray(vq), pt, sl,
-            jnp.asarray(ks), jnp.asarray(vs))
-
-
-@register_runner("paged_attention_decode")
-def _paged_runner(contract: KernelContract, bucket: Mapping[str, int],
-                  dtype: str):
-    from ..ops.pallas_ops.paged_attention import paged_attention_kernel
-
-    q, kp, vp, pt, sl, _, _ = _paged_inputs(
-        bucket, contract.dim("page_size"), int8=False)
-
-    jit_for = _per_choice(
-        contract.name,
-        lambda c: lambda a, b, d, e, f: paged_attention_kernel(
-            a, b, d, e, f, head_align=c["head_align"]))
-
-    def run(choice):
-        return jit_for(choice)(q, kp, vp, pt, sl)
-
-    return run
-
-
-@register_runner("paged_attention_decode_int8")
-def _paged_int8_runner(contract: KernelContract,
-                       bucket: Mapping[str, int], dtype: str):
-    from ..ops.pallas_ops.paged_attention import paged_attention_kernel
-
-    q, kp, vp, pt, sl, ks, vs = _paged_inputs(
-        bucket, contract.dim("page_size"), int8=True)
-
-    jit_for = _per_choice(
-        contract.name,
-        lambda c: lambda a, b, d, e, f, g, h: paged_attention_kernel(
-            a, b, d, e, f, g, h, head_align=c["head_align"],
-            fused_dequant=bool(c["fused_dequant"])))
-
-    def run(choice):
-        return jit_for(choice)(q, kp, vp, pt, sl, ks, vs)
 
     return run
 

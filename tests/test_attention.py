@@ -115,7 +115,7 @@ class TestRingAttention:
         assert np.isfinite(np.asarray(g)).all()
 
     def test_ulysses_matches(self):
-        from paddle_tpu.distributed.mesh import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.distributed import init_mesh
@@ -402,7 +402,7 @@ class TestRingFlash:
         """Peak temp memory must stay (near-)flat in S_local per ring
         step: the compiled HLO may not allocate an S_local×S_local f32
         score matrix (the kernel streams KV blocks instead)."""
-        from paddle_tpu.distributed.mesh import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.distributed import init_mesh

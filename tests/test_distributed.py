@@ -9,10 +9,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:     # jax<0.5 keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle

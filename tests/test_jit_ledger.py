@@ -96,20 +96,25 @@ class TestLedgerUnits:
             f(jnp.zeros((8,)))            # new signature: recompile
         assert cb.compiles() == {"ledger.unit_add": 2}
 
-    def test_aot_fallback_still_counted(self, monkeypatch):
+    def test_compile_error_surfaces(self, monkeypatch):
+        """A compile failure is raised as itself — never swallowed and
+        retried through plain jit (which would hide a kernel the
+        compiler refused), and never remembered as a dead signature."""
         from paddle_tpu.profiler import jit_cost
 
+        real = jit_cost.ProfiledJit._compile_for
         monkeypatch.setattr(
             jit_cost.ProfiledJit, "_compile_for",
             lambda self, sig, a, k: (_ for _ in ()).throw(
-                RuntimeError("AOT unsupported")))
+                RuntimeError("Mosaic refused the kernel")))
         f = profiled_jit("ledger.unit_fb", lambda x: x * 2)
         with compile_budget(None, prefix="ledger.") as cb:
-            out = f(jnp.ones((3,)))
-        np.testing.assert_array_equal(np.asarray(out), [2, 2, 2])
-        assert cb.compiles() == {"ledger.unit_fb": 1}
-        name, _, fallback = compile_ledger.events()[-1]
-        assert name == "ledger.unit_fb" and fallback
+            with pytest.raises(RuntimeError, match="Mosaic refused"):
+                f(jnp.ones((3,)))
+        assert cb.compiles() == {}
+        monkeypatch.setattr(jit_cost.ProfiledJit, "_compile_for", real)
+        np.testing.assert_array_equal(np.asarray(f(jnp.ones((3,)))),
+                                      [2, 2, 2])
 
 
 # =============================================================================

@@ -38,12 +38,12 @@ class TestContractRegistry:
         qmm = CONTRACTS["quantized_matmul"]
         assert (qmm.dim("block_m"), qmm.dim("block_n"),
                 qmm.dim("block_k")) == (128, 128, 128)
-        paged = CONTRACTS["paged_attention_decode"]
+        paged = CONTRACTS["paged_attention_ragged"]
         assert paged.dim("head_align") == 8
         assert paged.dim("lane") == 128
         # the int8 epilogue axis (ISSUE 14) defaults to the historical
         # fused form — scale multiplies folded AFTER the dots
-        assert CONTRACTS["paged_attention_decode_int8"].dim(
+        assert CONTRACTS["paged_attention_ragged_int8"].dim(
             "fused_dequant") == 1
 
     def test_sweep_axes_bind_dims_and_default_is_a_member(self):
@@ -58,8 +58,6 @@ class TestContractRegistry:
         assert swept == {"flash_attention_fwd",
                          "flash_attention_bwd_dkv",
                          "flash_attention_bwd_dq",
-                         "paged_attention_decode",
-                         "paged_attention_decode_int8",
                          "paged_attention_ragged",
                          "paged_attention_ragged_int8",
                          "quantized_matmul"}
@@ -78,38 +76,27 @@ class TestContractRegistry:
             == CONTRACTS["flash_attention_fwd"].dim("block_q")
         assert flash_attention.DEFAULT_BLOCK_K \
             == CONTRACTS["flash_attention_fwd"].dim("block_k")
-        assert paged_attention._HEAD_ALIGN \
-            == CONTRACTS["paged_attention_decode"].dim("head_align")
+        assert paged_attention._RAGGED_HEAD_ALIGN \
+            == CONTRACTS["paged_attention_ragged"].dim("head_align")
         assert quantized_matmul._BLOCK_K \
             == CONTRACTS["quantized_matmul"].dim("block_k")
 
-    def test_int8_waivers_are_reasoned_and_scoped(self):
-        """Sublane waivers stay scoped to the paged contracts that
-        genuinely trade layout for DMA shape — the int8 page/scale
-        blocks and the ragged family's per-row length vectors — and
-        each carries a reason.  The one lane waiver in the repo is the
-        stats form's [Q, H] lse block (a per-head scalar row, not a
-        128-lane tile)."""
-        waived = [(c.name, b.name, w)
-                  for c in CONTRACTS.values() for b in c.blocks
-                  for w in b.waivers]
-        assert waived and {cn for cn, _, _ in waived} == {
-            "paged_attention_decode_int8",
-            "paged_attention_ragged",
-            "paged_attention_ragged_int8",
-            "paged_attention_ragged_stats"}
-        for cn, bn, w in waived:
-            rule, _, reason = w.partition(":")
-            assert rule.strip() in ("sublane", "lane") \
-                and len(reason.strip()) > 10
-            if rule.strip() == "lane":
-                assert (cn, bn) == ("paged_attention_ragged_stats",
-                                    "lse")
-        # waived() matches the rule key, not the prose
-        b = next(b for b in
-                 CONTRACTS["paged_attention_decode_int8"].blocks
-                 if b.name == "k_page")
-        assert b.waived("sublane") and not b.waived("lane")
+    def test_paged_contracts_need_no_waiver(self):
+        """The paged contracts say what the TPU lowering ENFORCES
+        (ISSUE 21): every VMEM block's trailing dims are tile-aligned
+        or span the whole array extent — no contract waives a rule the
+        compiler does not waive (tests/test_pallas_tpu_lowering.py
+        lowers the kernels these blocks describe)."""
+        assert [(c.name, b.name) for c in CONTRACTS.values()
+                for b in c.blocks if b.waivers] == []
+        rl = next(b for b in CONTRACTS["paged_attention_ragged"].blocks
+                  if b.name == "row_lens")
+        assert rl.shape == (1, "q_align", 1) and rl.lanes_full
+        ks = next(b for b in
+                  CONTRACTS["paged_attention_ragged_int8"].blocks
+                  if b.name == "k_scales")
+        assert ks.shape == (1, 1, "heads") \
+            and ks.lanes_full and ks.sublane_full
 
 
 class TestValidateRules:

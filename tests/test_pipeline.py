@@ -88,7 +88,7 @@ class TestPipeline:
         stacked = stack_stage_params(per_stage)
         x = np.random.RandomState(1).randn(8, d).astype(np.float32)
 
-        from paddle_tpu.distributed.mesh import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from paddle_tpu.distributed.pipeline import pipeline_apply
 

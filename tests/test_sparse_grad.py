@@ -126,7 +126,7 @@ class TestShardedEmbeddingParity:
         """Row-sharded (mp) embedding under shard_map == gather from the
         full table (reference split semantics, collective.py:811 parallel
         embedding: row-split + allreduce)."""
-        from paddle_tpu.distributed.mesh import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         import paddle_tpu.distributed as dist

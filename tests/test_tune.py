@@ -400,8 +400,10 @@ class TestRuntimeResolution:
         assert quantized_matmul._resolved_blocks(8, 256, 256) \
             == (128, 128, 128)
         assert flash_attention._resolved_blocks(1024) == (512, 1024)
-        assert paged_attention._resolved_dims(2, 16, False) == (8, True)
-        assert paged_attention._resolved_dims(2, 16, True) == (8, True)
+        assert paged_attention._ragged_resolved_dims(2, 16, False) \
+            == (8, 8, True)
+        assert paged_attention._ragged_resolved_dims(2, 16, True) \
+            == (8, 8, True)
 
     def test_hit_miss_and_counter_accounting(self):
         from paddle_tpu.ops.pallas_ops import quantized_matmul as qmm
