@@ -12,6 +12,7 @@ DataParallel wrapper is kept for API parity: eagerly it is transparent
 """
 from __future__ import annotations
 
+import warnings
 from functools import partial
 from typing import Callable, Dict, Optional
 
@@ -141,25 +142,40 @@ def make_sharded_train_step(layer: Layer, loss_fn: Callable, optimizer,
     over 'mp' etc. if parameters carry partition_spec), batch sharded over
     data_axes, gradients reduced by XLA.
 
+    A mesh axis shards a parameter dim only where it divides it; a dim it
+    does not divide (GPT-2's published vocabulary, 50257, over mp=2) stays
+    whole on every device, with a warning naming the parameter.  The Pallas
+    flash kernel, which XLA cannot partition by itself, is split over the
+    same mesh — batch over data_axes, heads over 'mp' — under shard_map
+    (`flash_attention.partitioned_over`).
+
     Returns (step_fn, state) where state = {'params','buffers','opt','step'};
     step_fn(state, batch_x, batch_y, key) -> (state, loss).
     """
+    from ..ops.pallas_ops.flash_attention import partitioned_over
+
     mesh = mesh or get_mesh()
     params, buffers = get_state(layer)
     param_objs = dict(layer.named_parameters())
 
     def param_sharding(name, v):
-        spec = getattr(param_objs[name], "partition_spec", None)
-        if spec is None:
-            return NamedSharding(mesh, PartitionSpec())
-        return NamedSharding(mesh, PartitionSpec(*spec))
+        spec = getattr(param_objs[name], "partition_spec", None) or ()
+        fitted = tuple(
+            None if a in mesh.shape and v.shape[i] % mesh.shape[a] else a
+            for i, a in enumerate(spec))
+        if fitted != tuple(spec):
+            warnings.warn(
+                f"make_sharded_train_step: {name} {tuple(v.shape)} is not "
+                f"divisible by mesh {dict(mesh.shape)} along {tuple(spec)}; "
+                f"placed as {fitted}", stacklevel=3)
+        return NamedSharding(mesh, PartitionSpec(*fitted))
 
+    replicated = NamedSharding(mesh, PartitionSpec())
     params = {n: jax.device_put(v, param_sharding(n, v)) for n, v in params.items()}
-    buffers = {n: jax.device_put(v, NamedSharding(mesh, PartitionSpec()))
-               for n, v in buffers.items()}
-    opt_state = optimizer.init_opt_state(params)
+    buffers = {n: jax.device_put(v, replicated) for n, v in buffers.items()}
     opt_state = jax.tree_util.tree_map(
-        lambda v: jax.device_put(v, NamedSharding(mesh, PartitionSpec())), opt_state)
+        lambda v: jax.device_put(v, replicated),
+        optimizer.init_opt_state(params))
 
     data_sharding = NamedSharding(mesh, PartitionSpec(data_axes[0] if data_axes else None))
 
@@ -176,17 +192,23 @@ def make_sharded_train_step(layer: Layer, loss_fn: Callable, optimizer,
     def step_fn(state, x, y, key):
         params_, buffers_, opt_, count = (state["params"], state["buffers"],
                                           state["opt"], state["step"])
-        (loss, new_bufs), grads = jax.value_and_grad(loss_of, has_aux=True)(
-            params_, buffers_, x, y, key)
+        with partitioned_over(mesh, data_axes):
+            (loss, new_bufs), grads = jax.value_and_grad(
+                loss_of, has_aux=True)(params_, buffers_, x, y, key)
         new_params, new_opt = optimizer.fused_step(params_, grads, opt_,
                                                    count + 1)
         return ({"params": new_params, "buffers": new_bufs, "opt": new_opt,
                  "step": count + 1}, loss)
 
-    jit_step = jax.jit(step_fn, donate_argnums=(0,) if donate else ())
-
     state = {"params": params, "buffers": buffers, "opt": opt_state,
-             "step": jnp.zeros((), jnp.int32)}
+             "step": jax.device_put(jnp.zeros((), jnp.int32), replicated)}
+    # the new state keeps the placement of the old: left to the
+    # partitioner it may come back laid out otherwise, which un-shards
+    # the weights and compiles the step a second time on the next call
+    jit_step = jax.jit(
+        step_fn, donate_argnums=(0,) if donate else (),
+        out_shardings=(jax.tree_util.tree_map(lambda v: v.sharding, state),
+                       replicated))
 
     def run(state, x, y, key=None):
         from ..framework.random import default_generator

@@ -23,12 +23,18 @@ class Place:
     @property
     def jax_device(self):
         platform = self._platform()
-        devs = [d for d in jax.devices() if d.platform == platform]
+        # by platform, not from jax.devices(): that lists the DEFAULT
+        # backend only, and a TPU host still has its CPU device
+        try:
+            devs = jax.devices(platform)
+        except RuntimeError as e:
+            raise ValueError(
+                f"{self!r}: jax has no {platform!r} backend here (default "
+                f"backend {jax.default_backend()!r}): {e}") from None
         if self._device_id >= len(devs):
             raise ValueError(
                 f"{self!r}: jax reports {len(devs)} {platform!r} "
-                f"device(s) (default backend "
-                f"{jax.default_backend()!r}) — no device with that id")
+                f"device(s) — no device with that id")
         return devs[self._device_id]
 
     def _platform(self) -> str:

@@ -1,0 +1,191 @@
+"""Every kernel form ``CONTRACTS`` governs, with inputs and its XLA twin —
+the ONE table of them.
+
+``chip_smoke.py``'s kernels phase runs it on the chip,
+``tests/test_pallas_tpu_lowering.py`` compiles it for a v5e from the CPU,
+and the tune runners (``tune/runners.py``) draw their inputs from the
+same builders at their own bucket sizes.  A new kernel form is added
+here, once; ``kernel_cases`` refuses to return while a contract has no
+case.
+"""
+from __future__ import annotations
+
+from typing import Callable, NamedTuple, Tuple
+
+import numpy as np
+
+from .contracts import CONTRACTS
+
+__all__ = ["KernelCase", "kernel_cases", "flash_inputs", "paged_inputs",
+           "qmm_inputs"]
+
+
+PAGE_SIZE = 16                      # the serving default
+FLASH_SEQ, FLASH_BLOCK = 512, 256   # 2 x 2 blocks: the causal skip runs
+
+
+class KernelCase(NamedTuple):
+    contract: str       # the CONTRACTS entry that governs this form
+    label: str
+    kernel: Callable    # the Pallas form, never interpreted
+    twin: Callable      # its XLA twin, same arguments
+    args: Tuple
+
+
+def flash_inputs(B, H, S, D):
+    """(q, k, v, g) f32 [B, H, S, D], the all-valid kv mask [B, 1, S] and
+    the zero dropout seed — the operands of the three flash kernels."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(7)
+    qkvg = tuple(jnp.asarray(rng.standard_normal((B, H, S, D))
+                             .astype(np.float32)) for _ in range(4))
+    return qkvg, jnp.ones((B, 1, S), jnp.float32), jnp.zeros((1,), jnp.int32)
+
+
+def paged_inputs(H, D, page_size, *, int8, pages=40, rows=16, table=8):
+    """A MIXED three-lane batch for the ragged-query kernels, ragged as
+    the engine dispatches it: a steady-decode lane (one live row), a
+    prefill-chunk lane (every row live, ascending positions) and a
+    spec-verify-shaped lane (a few rows).  Returns ``(q [3, rows, H, D],
+    k_pool, v_pool [pages, page_size, H, D], page_tables [3, table],
+    row_lens [3, rows], page_ok [3, table], k_scales, v_scales)``; the
+    pools are int8 with per-page-per-head scales when ``int8``, else f32
+    and the scales are None."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(7)
+    cap = table * page_size
+    q = rng.standard_normal((3, rows, H, D)).astype(np.float32) * 0.5
+    kf = rng.standard_normal((pages, page_size, H, D)).astype(np.float32)
+    vf = rng.standard_normal((pages, page_size, H, D)).astype(np.float32)
+    pt = rng.randint(1, pages, (3, table)).astype(np.int32)
+    few = max(1, rows // 4)
+    rl = np.zeros((3, rows), np.int32)
+    rl[0, 0] = cap * 5 // 8 + 3
+    rl[1, :] = np.arange(cap // 4, cap // 4 + rows)
+    rl[2, :few] = np.arange(cap * 3 // 4, cap * 3 // 4 + few)
+    ok = rng.randint(0, 2, (3, table)).astype(np.int32)
+    ks = vs = None
+    if int8:
+        ks = (np.abs(kf).max(axis=(1, 3)) / 127 + 1e-9).astype(np.float32)
+        vs = (np.abs(vf).max(axis=(1, 3)) / 127 + 1e-9).astype(np.float32)
+        kf = np.clip(np.round(kf / ks[:, None, :, None]), -127,
+                     127).astype(np.int8)
+        vf = np.clip(np.round(vf / vs[:, None, :, None]), -127,
+                     127).astype(np.int8)
+        ks, vs = jnp.asarray(ks), jnp.asarray(vs)
+    return (jnp.asarray(q), jnp.asarray(kf), jnp.asarray(vf),
+            jnp.asarray(pt), jnp.asarray(rl), jnp.asarray(ok), ks, vs)
+
+
+def qmm_inputs(M, K, N):
+    """(x [M, K] f32, w_q [K, N] int8, w_scale [N] f32) for the
+    weight-only int8 matmul."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(7)
+    return (jnp.asarray(rng.standard_normal((M, K)).astype(np.float32)),
+            jnp.asarray(rng.randint(-127, 128, (K, N)).astype(np.int8)),
+            jnp.asarray(rng.uniform(0.5, 1.5, (N,)).astype(np.float32)
+                        / 127.0))
+
+
+def kernel_cases(heads, head_dim):
+    """The cases at (heads, head_dim): f32 inputs of unit scale; the paged
+    kernels take ``interpret=False``, the flash wrappers decide from
+    ``flash_attention._interpret_mode()`` (the chip, or a test's patch)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..attention import _sdpa_core
+    from . import flash_attention as fa
+    from . import paged_attention as pa
+    from . import quantized_matmul as qm
+
+    H, D = heads, head_dim
+    cases = []
+
+    # --- flash: fwd, then the two backward kernels on the fwd's stats ----
+    B, S = 1, FLASH_SEQ
+    qkvg, mask, seed = flash_inputs(B, H, S, D)
+    tail = (1.0 / float(np.sqrt(D)), True, 0.0, FLASH_BLOCK, FLASH_BLOCK)
+
+    def xla_attn(q, k, v):
+        return _sdpa_core(q, k, v, None, 0.0, True, None)
+
+    def xla_grads(q, k, v, g):
+        return jax.vjp(xla_attn, q, k, v)[1](g)
+
+    def flash_fwd(q, k, v, g):
+        return fa._flash_fwd_bhsd(q, k, v, mask, seed, *tail)[0]
+
+    def flash_stats(q, k, v, g):
+        o, lse = fa._flash_fwd_bhsd(q, k, v, mask, seed, *tail)
+        return lse, jnp.sum(g * o, axis=-1).reshape(B * H, S, 1)
+
+    def flash_dkv(q, k, v, g):
+        return fa._flash_dkv_bhsd(q, k, v, g, *flash_stats(q, k, v, g),
+                                  mask, seed, *tail)
+
+    def flash_dq(q, k, v, g):
+        return fa._flash_dq_bhsd(q, k, v, g, *flash_stats(q, k, v, g),
+                                 mask, seed, *tail)
+
+    cases += [
+        KernelCase("flash_attention_fwd", "flash fwd", flash_fwd,
+                   lambda q, k, v, g: xla_attn(q, k, v), qkvg),
+        KernelCase("flash_attention_bwd_dkv", "flash bwd dk/dv", flash_dkv,
+                   lambda *a: xla_grads(*a)[1:], qkvg),
+        KernelCase("flash_attention_bwd_dq", "flash bwd dq", flash_dq,
+                   lambda *a: xla_grads(*a)[0], qkvg),
+    ]
+
+    # --- paged: ragged, decode (ragged at Q = 1) and stats, x native/int8 --
+    def paged(int8):
+        q, kp, vp, pt, rl, ok, ks, vs = paged_inputs(H, D, PAGE_SIZE,
+                                                     int8=int8)
+        pools = (kp, vp) + ((ks, vs) if int8 else ())
+        kind = "int8" if int8 else "native"
+        suffix = "_int8" if int8 else ""
+
+        def ragged(kp, vp, *sc):
+            return pa.ragged_paged_attention_kernel(q, kp, vp, pt, rl, *sc,
+                                                    interpret=False)
+
+        def decode(kp, vp, *sc):
+            return pa.paged_attention_kernel(q[:, 0], kp, vp, pt, rl[:, 0],
+                                             *sc, interpret=False)
+
+        def stats(kp, vp, *sc):
+            return pa.ragged_paged_attention_stats_kernel(
+                q, kp, vp, pt, rl, ok, *sc, interpret=False)
+
+        return [
+            KernelCase("paged_attention_ragged" + suffix, f"ragged {kind}",
+                       ragged,
+                       lambda kp, vp, *sc: pa.ragged_paged_attention_xla(
+                           q, kp, vp, pt, rl, *sc), pools),
+            KernelCase("paged_attention_ragged" + suffix,
+                       f"decode {kind} (ragged at Q=1)", decode,
+                       lambda kp, vp, *sc: pa.paged_attention_xla(
+                           q[:, 0], kp, vp, pt, rl[:, 0], *sc), pools),
+            KernelCase("paged_attention_ragged_stats",
+                       f"ragged-stats {kind}", stats,
+                       lambda kp, vp, *sc:
+                       pa.ragged_paged_attention_stats_xla(
+                           q, kp, vp, pt, rl, ok, *sc), pools),
+        ]
+
+    cases += paged(False) + paged(True)
+
+    # --- weight-only int8 matmul at the model's own projection shape -----
+    cases.append(KernelCase(
+        "quantized_matmul", "int8 weight-only matmul",
+        lambda *a: qm.quantized_matmul_kernel(*a, interpret=False),
+        qm.quantized_matmul_xla, qmm_inputs(64, H * D, 3 * H * D)))
+
+    missing = set(CONTRACTS) - {c.contract for c in cases}
+    if missing:
+        raise AssertionError(f"contracts without a kernel case: {missing}")
+    return cases

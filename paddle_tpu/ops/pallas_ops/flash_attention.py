@@ -23,13 +23,16 @@ Round-2 upgrades (VERDICT r1 #2):
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
 
 from .contracts import FLASH_FWD
 
@@ -437,6 +440,58 @@ def _core_bwd(scale, causal, dropout_p, block_q, block_k, res, g):
 _flash_attention_core.defvjp(_core_fwd, _core_bwd)
 
 
+# XLA's SPMD partitioner cannot split a Mosaic call ("Mosaic kernels
+# cannot be automatically partitioned"), so a GSPMD-jitted step over a
+# real TPU mesh has to say how: while it traces under `partitioned_over`
+# the kernel runs inside shard_map, split along the two dims attention
+# is independent across — batch and heads.
+_partition = threading.local()
+
+
+# the mesh axis the models' partition_specs split the heads over
+_HEAD_AXIS = "mp"
+
+
+@contextlib.contextmanager
+def partitioned_over(mesh, batch_axes):
+    """While tracing under this context, `flash_attention_bshd` shards its
+    kernel over `mesh`: batch over `batch_axes`, heads over 'mp'
+    (`distributed.make_sharded_train_step` traces its step here)."""
+    prev = getattr(_partition, "spec", None)
+    _partition.spec = (mesh, tuple(batch_axes))
+    try:
+        yield
+    finally:
+        _partition.spec = prev
+
+
+def _shard_over(core, spec, B, H, per_shard_seed):
+    """`core(q, k, v, mask, seed)` on [B, H, S, D] under shard_map.  An
+    axis splits a dim only where it divides it; otherwise every shard
+    along that axis computes the dim whole (the operands are replicated
+    over it)."""
+    mesh, batch_axes = spec
+    b = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)
+    if not b or B % math.prod(mesh.shape[a] for a in b):
+        b = None
+    h = _HEAD_AXIS if mesh.shape.get(_HEAD_AXIS, 1) > 1 else None
+    if h is not None and H % mesh.shape[h]:
+        h = None
+    split = (b or ()) + ((h,) if h else ())
+
+    def body(q, k, v, mask, seed):
+        if per_shard_seed and split:
+            # the kernel hashes (seed, LOCAL batch*head index, row, col):
+            # without this every shard would drop the same positions
+            seed = seed + jax.lax.axis_index(split).astype(seed.dtype)
+        return core(q, k, v, mask, seed)
+
+    P = PartitionSpec
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(b, h), P(b, h), P(b, h), P(b), P()),
+                         out_specs=P(b, h))
+
+
 def _pad_head_dim(d):
     """MXU-friendly head width: 64 stays, otherwise next multiple of 128."""
     if d <= _LANE // 2:
@@ -489,9 +544,15 @@ def flash_attention_bshd(q, k, v, causal=False, kv_mask=None, dropout_p=0.0,
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    out = _flash_attention_core(qt, kt, vt, mask, seed, scale, causal,
-                                float(dropout_p), bq, bk)
-    out = jnp.swapaxes(out, 1, 2)
+
+    def core(q, k, v, mask, seed):
+        return _flash_attention_core(q, k, v, mask, seed, scale, causal,
+                                     float(dropout_p), bq, bk)
+
+    spec = getattr(_partition, "spec", None)
+    if spec is not None:
+        core = _shard_over(core, spec, B, H, per_shard_seed=dropout_p > 0.0)
+    out = jnp.swapaxes(core(qt, kt, vt, mask, seed), 1, 2)
     if Sp != S or Dp != D:
         out = out[:, :S, :, :D]
     return out

@@ -445,9 +445,13 @@ def _make_gpt_paged_sharded_core(model, page_size: int, pages_per_seq: int,
         kvs = layout.kv_spec(kv)
         in_specs = (cspecs, P(), P(), P(), P(), kvs)
         out_specs = (P(), kvs) if with_head else kvs
-        # check_vma=False: the logits are replicated in VALUE (every
-        # shard all-gathers the same context) but jax types an
-        # all_gather result as varying, and pallas_call carries no vma
+        # check_vma=False for the whole core, because the one typing
+        # problem cannot be opted out of alone: the logits are replicated
+        # in VALUE (every shard all-gathers the same context), but jax
+        # types an all_gather result as varying, jax 0.9 has no public
+        # invariant all_gather (`all_gather_invariant` lives in jax._src)
+        # and pcast only goes invariant -> varying.  The value assumption
+        # is pinned by the byte-identity tests in test_serving_mesh.py.
         f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False)
         vlen = valid_len if has_vl else jnp.zeros((), jnp.int32)

@@ -76,15 +76,11 @@ def _per_choice(name: str, build):
 @register_runner("quantized_matmul")
 def _qmm_runner(contract: KernelContract, bucket: Mapping[str, int],
                 dtype: str):
-    import jax.numpy as jnp
-
+    from ..ops.pallas_ops.cases import qmm_inputs
     from ..ops.pallas_ops.quantized_matmul import quantized_matmul_kernel
 
-    M, K, N = (bucket["block_m"], bucket["block_k"], bucket["block_n"])
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(M, K).astype(np.float32))
-    w_q = jnp.asarray(rng.randint(-127, 128, (K, N)).astype(np.int8))
-    w_s = jnp.asarray((rng.rand(N).astype(np.float32) * 0.1 + 1e-3))
+    x, w_q, w_s = qmm_inputs(bucket["block_m"], bucket["block_k"],
+                             bucket["block_n"])
 
     jit_for = _per_choice(
         contract.name,
@@ -103,15 +99,13 @@ def _flash_runner(contract: KernelContract, bucket: Mapping[str, int],
                   dtype: str):
     import jax.numpy as jnp
 
+    from ..ops.pallas_ops.cases import flash_inputs
     from ..ops.pallas_ops.flash_attention import flash_attention_bshd
 
     # both sweep axes tile the same sequence extent — run at the larger
     S = max(bucket["block_q"], bucket["block_k"])
-    B, H, D = 1, 2, 64
-    rng = np.random.RandomState(1)
-    q = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32) * 0.2)
-    k = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32) * 0.2)
-    v = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32) * 0.2)
+    (q, k, v, _), _, _ = flash_inputs(1, 2, S, 64)
+    q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))   # wrapper: BSHD
 
     jit_for = _per_choice(
         contract.name,
@@ -133,18 +127,13 @@ def _flash_bwd_inputs(bucket: Mapping[str, int]):
     attributable to the candidate blocks alone."""
     import jax.numpy as jnp
 
+    from ..ops.pallas_ops.cases import flash_inputs
     from ..ops.pallas_ops.contracts import FLASH_FWD
     from ..ops.pallas_ops.flash_attention import _flash_fwd_bhsd
 
     S = max(bucket["block_q"], bucket["block_k"])
     B, H, D = 1, 2, 64
-    rng = np.random.RandomState(4)
-    q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32) * 0.2)
-    k = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32) * 0.2)
-    v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32) * 0.2)
-    g = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32) * 0.2)
-    mask = jnp.ones((B, 1, S), jnp.float32)
-    seed = jnp.zeros((1,), jnp.int32)
+    (q, k, v, g), mask, seed = flash_inputs(B, H, S, D)
     scale = 1.0 / float(np.sqrt(D))
     bq = min(FLASH_FWD.dim("block_q"), S)
     bk = min(FLASH_FWD.dim("block_k"), S)
@@ -197,38 +186,14 @@ def _flash_dq_runner(contract: KernelContract,
 
 def _ragged_inputs(bucket: Mapping[str, int], page_size: int,
                    int8: bool):
-    """A representative MIXED group batch for the unified-dispatch
-    kernel: a steady-decode lane (1 live row), a prefill-chunk lane
-    (5 rows at ascending positions) and a spec-verify-shaped lane
-    (3 rows) — ragged exactly as the engine dispatches them."""
-    import jax.numpy as jnp
+    """The case table's mixed three-lane batch (decode lane, prefill
+    chunk, spec-verify-shaped lane) at the sweep's small extents."""
+    from ..ops.pallas_ops.cases import paged_inputs
 
-    H, D = bucket["heads"], bucket["head_dim"]
-    N, G, Qb, M = 9, 3, 5, 4
-    rng = np.random.RandomState(6)
-    q = jnp.asarray(rng.randn(G, Qb, H, D).astype(np.float32) * 0.3)
-    kf = rng.randn(N, page_size, H, D).astype(np.float32)
-    vf = rng.randn(N, page_size, H, D).astype(np.float32)
-    pt = np.zeros((G, M), np.int32)
-    pt[0, :3] = [1, 2, 3]
-    pt[1, :4] = [4, 5, 6, 7]
-    pt[2, :2] = [8, 1]
-    rl = np.zeros((G, Qb), np.int32)
-    rl[0, 0] = page_size * 2 + 3                    # decode row
-    rl[1, :] = np.arange(8, 8 + Qb)                 # prefill chunk
-    rl[2, :3] = np.arange(3, 6)                     # spec-verify rows
-    rl_j = jnp.asarray(rl)
-    pt_j = jnp.asarray(pt)
-    if not int8:
-        return q, jnp.asarray(kf), jnp.asarray(vf), pt_j, rl_j, None, None
-    ks = (np.abs(kf).max(axis=(1, 3)) / 127 + 1e-9).astype(np.float32)
-    vs = (np.abs(vf).max(axis=(1, 3)) / 127 + 1e-9).astype(np.float32)
-    kq = np.clip(np.round(kf / ks[:, None, :, None]), -127,
-                 127).astype(np.int8)
-    vq = np.clip(np.round(vf / vs[:, None, :, None]), -127,
-                 127).astype(np.int8)
-    return (q, jnp.asarray(kq), jnp.asarray(vq), pt_j, rl_j,
-            jnp.asarray(ks), jnp.asarray(vs))
+    q, kp, vp, pt, rl, _, ks, vs = paged_inputs(
+        bucket["heads"], bucket["head_dim"], page_size, int8=int8,
+        pages=9, rows=5, table=4)
+    return q, kp, vp, pt, rl, ks, vs
 
 
 @register_runner("paged_attention_ragged")

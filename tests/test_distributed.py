@@ -175,6 +175,53 @@ class TestDataParallelStep:
         np.testing.assert_allclose(w8, w1, rtol=1e-5, atol=1e-6)
 
 
+    def test_sharded_step_carries_the_flash_kernel(self, monkeypatch):
+        """dp2 x mp2 GPT step at S >= 128: the attention rides the Pallas
+        flash kernel (interpreted here), split over the mesh under
+        shard_map, and the run equals the one-device run; a vocabulary
+        mp does not divide leaves the embedding whole, with a warning."""
+        import paddle_tpu.nn.functional as F
+        from paddle_tpu.distributed.parallel import make_sharded_train_step
+        from paddle_tpu.ops import attention as attn_mod
+        from paddle_tpu.text.models import GPTModel
+
+        monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH", "1")
+        V = 51
+        toks = np.random.RandomState(0).randint(0, V, (4, 129)).astype(np.int32)
+
+        def run(axes):
+            paddle.seed(3)
+            mesh = init_mesh(axes)
+            net = GPTModel(vocab_size=V, hidden_size=32, num_layers=1,
+                           num_heads=2, ffn_size=64, max_seq_len=128)
+            net.train()
+            opt = optimizer.SGD(0.5, parameters=net.parameters())
+            step, state = make_sharded_train_step(
+                net, lambda o, y: F.cross_entropy(o.reshape([-1, V]),
+                                                  y.reshape([-1])),
+                opt, mesh=mesh)
+            losses = []
+            for _ in range(2):
+                state, loss = step(state, toks[:, :-1], toks[:, 1:])
+                losses.append(float(loss))
+            return losses, state["params"]
+
+        routes0 = dict(attn_mod.ROUTE_STATS)
+        with pytest.warns(UserWarning, match=r"wte\.weight \(51, 32\)"):
+            sharded, p4 = run({"dp": 2, "mp": 2})
+        single, p1 = run({"dp": 1, "mp": 1})
+        assert attn_mod.ROUTE_STATS["pallas"] > routes0["pallas"]
+        assert attn_mod.ROUTE_STATS["xla"] == routes0["xla"]
+        np.testing.assert_allclose(sharded, single, rtol=1e-5)
+        assert sharded[1] < sharded[0]
+        assert p4["wte.weight"].sharding.is_fully_replicated
+        assert p4["layers.0.fc1.weight"].sharding.spec == P(None, "mp")
+        for name in p1:
+            np.testing.assert_allclose(np.asarray(p4[name]),
+                                       np.asarray(p1[name]), rtol=1e-4,
+                                       atol=1e-5, err_msg=name)
+
+
 class TestTensorParallel:
     # slow-marked (ISSUE 6 suite health): a ~19 s full-BERT dp×mp train
     # step soak; the TP layer semantics stay pinned in tier-1 by the

@@ -102,6 +102,41 @@ print("ok")
             == env["TPU_PROCESS_BOUNDS"]
 
 
+class TestPlaceLookup:
+    """A Place names a platform, not a position in the default backend's
+    device list: on a TPU host `jax.devices()` lists TPUs only, and
+    CPUPlace must still find the host's CPU device."""
+
+    def test_cpu_place_when_default_backend_is_not_cpu(self, monkeypatch):
+        import jax
+
+        import paddle_tpu as paddle
+        from paddle_tpu.framework.place import (CPUPlace, CUDAPinnedPlace,
+                                                TPUPlace)
+
+        real = jax.devices
+        cpu = real("cpu")[0]
+
+        class FakeTpu:
+            platform = "tpu"
+
+        # what a TPU host reports: the default list holds no CPU device
+        monkeypatch.setattr(
+            jax, "devices",
+            lambda backend=None: real(backend) if backend else [FakeTpu()])
+        assert CPUPlace().jax_device == cpu
+        assert CUDAPinnedPlace().jax_device == cpu
+        t = paddle.to_tensor([1.0, 2.0], place=CPUPlace())
+        assert t._value.devices() == {cpu}
+        # an absent backend is a ValueError naming the place, and an id
+        # past the end is not wrapped
+        with pytest.raises(ValueError, match=r"TPUPlace\(0\).*no 'tpu'"):
+            TPUPlace(0).jax_device
+        monkeypatch.setattr(jax, "devices", real)
+        with pytest.raises(ValueError, match="no device with that id"):
+            paddle.CUDAPlace(len(real()) + 1).jax_device
+
+
 class TestLazyGeneratorKey:
     """The default generator builds its key on first use; seeding
     semantics are what they were when it was built eagerly."""
