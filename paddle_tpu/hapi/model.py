@@ -272,14 +272,22 @@ class Model:
 
     # --- single-batch API ----------------------------------------------------
     def train_batch(self, inputs, labels=None, update=True):
-        inputs = _to_list(inputs)
-        labels = _to_list(labels)
+        # one span per step whoever drives it (fit's loop or a caller of
+        # its own); the children name the host's phases of a jitted step
+        with RecordEvent("hapi/train_batch"):
+            return self._train_batch(_to_list(inputs), _to_list(labels),
+                                     update)
+
+    def _train_batch(self, inputs, labels, update):
         if self._adapter is not None:
             return self._adapter.train_batch(inputs, labels, update)
         x = inputs[0]
         y = labels[0] if labels else None
-        xv = x._value if isinstance(x, Tensor) else jnp.asarray(np.asarray(x))
-        yv = y._value if isinstance(y, Tensor) else jnp.asarray(np.asarray(y))
+        with RecordEvent("hapi/train_batch/inputs"):
+            xv = x._value if isinstance(x, Tensor) \
+                else jnp.asarray(np.asarray(x))
+            yv = y._value if isinstance(y, Tensor) \
+                else jnp.asarray(np.asarray(y))
 
         if self._accelerate:
             self._ensure_state()
@@ -291,23 +299,30 @@ class Model:
             key = default_generator.split_key()
             if self._anomaly_guard:
                 prev = self._state
-                (self._state, loss, out,
-                 gn, ok) = self._train_step(self._state, key, xv, yv)
-                lossf = float(np.asarray(loss))
-                okb = bool(np.asarray(ok))
-                self._last_guard = {"ok": okb, "loss": lossf,
-                                    "grad_norm": float(np.asarray(gn))}
+                with RecordEvent("hapi/train_batch/dispatch"):
+                    (self._state, loss, out,
+                     gn, ok) = self._train_step(self._state, key, xv, yv)
+                with RecordEvent("hapi/train_batch/fetch_loss"):
+                    lossf = float(np.asarray(loss))
+                    okb = bool(np.asarray(ok))
+                    self._last_guard = {"ok": okb, "loss": lossf,
+                                        "grad_norm": float(np.asarray(gn))}
                 self._prev_state = prev
                 if not okb:
                     # poisoned step: never feed NaN outputs into the
                     # metrics; the anomaly runtime decides skip/rollback
                     return [lossf]
-                metrics_out = self._update_metrics(out, yv)
+                with RecordEvent("hapi/train_batch/metrics"):
+                    metrics_out = self._update_metrics(out, yv)
                 return [lossf] + metrics_out
             self._last_guard = None
-            self._state, loss, out = self._train_step(self._state, key, xv, yv)
-            metrics_out = self._update_metrics(out, yv)
-            return [float(np.asarray(loss))] + metrics_out
+            with RecordEvent("hapi/train_batch/dispatch"):
+                self._state, loss, out = self._train_step(
+                    self._state, key, xv, yv)
+            with RecordEvent("hapi/train_batch/metrics"):
+                metrics_out = self._update_metrics(out, yv)
+            with RecordEvent("hapi/train_batch/fetch_loss"):
+                return [float(np.asarray(loss))] + metrics_out
 
         # eager path
         self.network.train()
@@ -619,13 +634,12 @@ class Model:
                             x = batch[0]
                             y = batch[1] if len(batch) > 1 else None
                             t0 = _time.perf_counter()
-                            with RecordEvent("hapi/train_batch"):
-                                outs = self._step_with_retry(
-                                    x, y, step_retries,
-                                    step_retry_backoff_s, chaos_site,
-                                    stat_add, KILL, FatalError,
-                                    runtime=anomaly_rt, epoch=epoch,
-                                    batch=step, global_step=global_step)
+                            outs = self._step_with_retry(
+                                x, y, step_retries,
+                                step_retry_backoff_s, chaos_site,
+                                stat_add, KILL, FatalError,
+                                runtime=anomaly_rt, epoch=epoch,
+                                batch=step, global_step=global_step)
                             histogram_observe(
                                 "hapi.train_batch_ms",
                                 (_time.perf_counter() - t0) * 1e3)

@@ -823,7 +823,8 @@ class ServingEngine:
     def add_request(self, prompt, max_new_tokens: int = 32,
                     request_id: Optional[str] = None,
                     deadline: Optional[float] = None,
-                    prefix_cache: bool = True) -> str:
+                    prefix_cache: bool = True,
+                    arrival_time: Optional[float] = None) -> str:
         """Enqueue a generation request; returns its id.  Non-blocking —
         admission happens inside step() when a slot and pages are free.
         ``deadline`` is an ABSOLUTE ``time.monotonic()`` instant: once
@@ -832,14 +833,19 @@ class ServingEngine:
         surfaces through ``take_expired()``.  ``prefix_cache=False``
         opts this request out of the engine's prefix cache (no index
         lookup, its pages are never sealed for other requests); a no-op
-        when the engine has none."""
+        when the engine has none.  ``arrival_time`` is the
+        ``time.monotonic()`` instant the request reached the system (the
+        frontend passes its submit time) — ``serving.queue_wait_ms`` and
+        ``serving.ttft_ms`` start there; default: now."""
         prompt = self.check_request(prompt, max_new_tokens)
         if not isinstance(prefix_cache, bool):
             raise InvalidArgumentError(
                 f"prefix_cache must be a bool, got {prefix_cache!r}")
         req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
                       request_id=request_id or "", deadline=deadline,
-                      use_prefix_cache=prefix_cache)
+                      use_prefix_cache=prefix_cache,
+                      arrival_time=(time.monotonic() if arrival_time is None
+                                    else float(arrival_time)))
         self._check_not_live(req.request_id)
         self.scheduler.add(req)
         return req.request_id
@@ -1521,89 +1527,109 @@ class ServingEngine:
         (no plans) reuses per-bucket cached input arrays and is
         bit-identical to the split decode program."""
         B = self._state_bucket
-        chunks: Dict[int, Tuple[np.ndarray, np.ndarray, int]] = {}
-        idle: set = set()
-        done_plans: List[Tuple[str, dict]] = []
-        # barrier snapshot BEFORE this dispatch issues anything: a lane
-        # may only read a shared page once the chunk writing it was
-        # issued by an EARLIER dispatch (device program order then
-        # commits the payload ahead of the read)
-        pc = self.prefix_cache
-        pending_before = set(pc.unwritten) if pc is not None \
-            and pc.unwritten else ()
-        for lane, seq in active:
-            plan = self._prefill_plans.get(seq.seq_id)
-            if plan is None:
-                continue
-            aw = plan["await"]
-            if aw:
-                aw.intersection_update(pending_before)
-            if aw:
-                idle.add(lane)           # barrier holds: no chunk, no
-                continue                 # decode, device state frozen
-            if plan["cow"]:
-                # deferred copy-on-write: the source page's payload is
-                # committed now — duplicate it before this dispatch
-                self._apply_cow(seq)
-                plan["cow"] = False
-            if plan["chunks"]:
-                ctok, cpos, n = plan["chunks"].popleft()
-                chunks[lane] = (ctok, cpos, n)
-                pend = plan["pending"]
-                if pend:
-                    # sealed pages this chunk writes through are now
-                    # issued — readers may pass their barrier next step
-                    through = min(int(cpos[-1]), n - 1)
-                    while pend and pend[0][1] <= through:
-                        pc.unwritten.discard(pend.pop(0)[0])
-            if not plan["chunks"]:
-                done_plans.append(
-                    (seq.seq_id,
-                     self._prefill_plans.pop(seq.seq_id)))
-        self._sync_rows(active)
-        t = time.perf_counter()
-        if self._last_dispatch is not None:
-            self.metrics.on_dispatch_gap(t - self._last_dispatch)
-        self._last_dispatch = t
-        prefill_rows = 0
-        if not chunks and not idle:
-            Q = 1
-            rows_tok, rows_pos, row_valid, advance = self._steady_rows(B)
-        else:
-            # mixed step: fresh host rows for this step's chunk mix —
-            # pow2 row bucket (chunk sizes already are), junk padding
-            # rows carry row_valid 0 (trash-page scatter, zero
-            # attention span)
-            Q = max((c[0].size for c in chunks.values()), default=1)
-            rt = np.zeros((B, Q), np.int32)
-            rp = np.zeros((B, Q), np.int32)
-            rv = np.zeros((B, Q), np.int32)
-            adv = np.ones((B,), np.int32)
-            rv[:, 0] = self._ragged_no_limit
-            for lane, (ctok, cpos, n) in chunks.items():
-                sz = ctok.size
-                rt[lane, :sz] = ctok
-                rp[lane, :sz] = cpos
-                rv[lane, :] = 0
-                rv[lane, :sz] = n
-                adv[lane] = 0
-                prefill_rows += sz
-            for lane in idle:
-                # barrier-held lane: every row junk, no advance — the
-                # device state is untouched until the awaited pages'
-                # writes have been issued
-                rv[lane, :] = 0
-                adv[lane] = 0
+        with RecordEvent("serving/plan_rows") as ev:
+            chunks: Dict[int, Tuple[np.ndarray, np.ndarray, int]] = {}
+            idle: set = set()
+            done_plans: List[Tuple[str, dict]] = []
+            # barrier snapshot BEFORE this dispatch issues anything: a lane
+            # may only read a shared page once the chunk writing it was
+            # issued by an EARLIER dispatch (device program order then
+            # commits the payload ahead of the read)
+            pc = self.prefix_cache
+            pending_before = set(pc.unwritten) if pc is not None \
+                and pc.unwritten else ()
             for lane, seq in active:
-                if lane in chunks:
-                    flight.request_event(
-                        seq.seq_id, EV_PREFILL_CHUNK,
-                        replica=self.chaos_key,
-                        size=int(chunks[lane][0].size))
-            rows_tok = self._dput(rt)
-            rows_pos = self._dput(rp)
-            row_valid = self._dput(rv)
-            advance = self._dput(adv)
+                plan = self._prefill_plans.get(seq.seq_id)
+                if plan is None:
+                    continue
+                aw = plan["await"]
+                if aw:
+                    aw.intersection_update(pending_before)
+                if aw:
+                    idle.add(lane)           # barrier holds: no chunk, no
+                    continue                 # decode, device state frozen
+                if plan["cow"]:
+                    # deferred copy-on-write: the source page's payload is
+                    # committed now — duplicate it before this dispatch
+                    self._apply_cow(seq)
+                    plan["cow"] = False
+                if plan["chunks"]:
+                    ctok, cpos, n = plan["chunks"].popleft()
+                    chunks[lane] = (ctok, cpos, n)
+                    pend = plan["pending"]
+                    if pend:
+                        # sealed pages this chunk writes through are now
+                        # issued — readers may pass their barrier next step
+                        through = min(int(cpos[-1]), n - 1)
+                        while pend and pend[0][1] <= through:
+                            pc.unwritten.discard(pend.pop(0)[0])
+                if not plan["chunks"]:
+                    done_plans.append(
+                        (seq.seq_id,
+                         self._prefill_plans.pop(seq.seq_id)))
+            self._sync_rows(active)
+            t = time.perf_counter()
+            if self._last_dispatch is not None:
+                self.metrics.on_dispatch_gap(t - self._last_dispatch)
+            self._last_dispatch = t
+            prefill_rows = 0
+            if not chunks and not idle:
+                Q = 1
+                rows_tok, rows_pos, row_valid, advance = self._steady_rows(B)
+            else:
+                # mixed step: fresh host rows for this step's chunk mix —
+                # pow2 row bucket (chunk sizes already are), junk padding
+                # rows carry row_valid 0 (trash-page scatter, zero
+                # attention span)
+                Q = max((c[0].size for c in chunks.values()), default=1)
+                rt = np.zeros((B, Q), np.int32)
+                rp = np.zeros((B, Q), np.int32)
+                rv = np.zeros((B, Q), np.int32)
+                adv = np.ones((B,), np.int32)
+                rv[:, 0] = self._ragged_no_limit
+                for lane, (ctok, cpos, n) in chunks.items():
+                    sz = ctok.size
+                    rt[lane, :sz] = ctok
+                    rp[lane, :sz] = cpos
+                    rv[lane, :] = 0
+                    rv[lane, :sz] = n
+                    adv[lane] = 0
+                    prefill_rows += sz
+                for lane in idle:
+                    # barrier-held lane: every row junk, no advance — the
+                    # device state is untouched until the awaited pages'
+                    # writes have been issued
+                    rv[lane, :] = 0
+                    adv[lane] = 0
+                for lane, seq in active:
+                    if lane in chunks:
+                        flight.request_event(
+                            seq.seq_id, EV_PREFILL_CHUNK,
+                            replica=self.chaos_key,
+                            size=int(chunks[lane][0].size))
+                rows_tok = self._dput(rt)
+                rows_pos = self._dput(rp)
+                row_valid = self._dput(rv)
+                advance = self._dput(adv)
+            # the step's work, from host state alone (no device read):
+            # each decode lane reads its pos + 1 KV positions for one
+            # row; a chunk's rows that carry a prompt token (positions
+            # under the prompt's horizon n) each read their own
+            # position + 1, the lane as a whole the last of them
+            decode_rows = 0
+            ctx_tokens = 0
+            for lane, seq in active:
+                if lane not in chunks and lane not in idle:
+                    decode_rows += 1
+                    ctx_tokens += seq.pos + 1
+            attn_pairs = ctx_tokens
+            for ctok, cpos, n in chunks.values():
+                first = int(cpos[0])
+                last = min(first + ctok.size, n)
+                if last > first:
+                    ctx_tokens += last
+                    attn_pairs += (first + 1 + last) * (last - first) // 2
+            ev.set(chunks=len(chunks), idle=len(idle))
         if self._mesh_layout is not None:
             # chaos site ``serving.shard_sync``: the last host boundary
             # before the mesh-wide sharded dispatch — ``delay`` models a
@@ -1614,7 +1640,9 @@ class ServingEngine:
             # radius of a dead chip in a tp/sp group)
             chaos_site("serving.shard_sync", key=self.chaos_key)
             self.metrics.on_shard_step()
-        with RecordEvent("serving/ragged_step", bucket=B, rows=Q):
+        with RecordEvent("serving/ragged_step", bucket=B, rows=Q,
+                         decode_rows=decode_rows, prefill_rows=prefill_rows,
+                         ctx_tokens=ctx_tokens, attn_pairs=attn_pairs):
             (_out_rows, out_dec, self._tokens, self._pos,
              self._kv) = self._ragged_jit(
                 self._tokens, self._pos, self._tables, rows_tok,
@@ -1631,9 +1659,9 @@ class ServingEngine:
                 s.pos += 1
         self._pending.append(_Pending(out_dec, 1, snapshot))
         self.metrics.on_ragged(
-            decode_rows=sum(1 for lane, _ in active
-                            if lane not in chunks and lane not in idle),
-            prefill_rows=prefill_rows, q_bucket=Q)
+            decode_rows=decode_rows, prefill_rows=prefill_rows, q_bucket=Q,
+            rows_computed=B * Q, ctx_tokens=ctx_tokens,
+            attn_pairs=attn_pairs)
         for sid, plan in done_plans:
             if not plan["count"]:
                 # barrier-only plan (fully-covered prefix hit): the
@@ -1740,7 +1768,8 @@ class ServingEngine:
         returns tokens emitted."""
         ent = self._pending.popleft()
         t0 = time.perf_counter()
-        toks = np.asarray(jax.device_get(ent.tokens))
+        with RecordEvent("serving/fetch_tokens"):
+            toks = np.asarray(jax.device_get(ent.tokens))
         self.metrics.on_decode(time.perf_counter() - t0)
         rows = toks if ent.steps > 1 else toks[None, :]
         now = time.monotonic()
@@ -1989,8 +2018,13 @@ class ServingEngine:
                     self._dput(rows_val),
                     self._dput(np.zeros((bucket,), np.int32)),
                     self._kv)
-                self.metrics.on_ragged(spec_rows=K * len(active),
-                                       q_bucket=K)
+                # K rows per lane at pos .. pos + K - 1, each reading
+                # its own position + 1
+                ctx = sum(seq.pos + K for _, seq in active)
+                self.metrics.on_ragged(
+                    spec_rows=K * len(active), q_bucket=K,
+                    rows_computed=bucket * K, ctx_tokens=ctx,
+                    attn_pairs=K * ctx - len(active) * K * (K - 1) // 2)
                 t0 = time.perf_counter()
                 toks = np.ascontiguousarray(              # [K, bucket]
                     np.asarray(jax.device_get(out_rows)).T)
@@ -2052,6 +2086,92 @@ class ServingEngine:
         return {"emitted": emitted, "bucket": bucket,
                 "lanes": len(active)}
 
+    def _admit_waiting(self) -> List[Sequence]:
+        """Admit what fits from the waiting queue (pipeline already
+        collapsed) and run each admission's device half: page-scale
+        reset, snapshot upload or COW copy + prefill plan, lane bind."""
+        sched = self.scheduler
+        if self.kv_transport is not None:
+            # admission boundary (ISSUE 16): promote tier hits for
+            # the waiting prompts, and open the ONLY window where
+            # evictions demote (admission-pressure reclaims gather
+            # D2H here; decode-time pressure keeps discarding, so
+            # steady decode never pays a transfer)
+            self.kv_transport.chaos_key = self.chaos_key
+            self.kv_transport.demote_window = True
+            try:
+                for req in sched.waiting:
+                    if req.resume is None and req.use_prefix_cache:
+                        self.prefix_cache.promote_for(req.prompt)
+                admitted = sched.admit()
+            finally:
+                self.kv_transport.demote_window = False
+        else:
+            admitted = sched.admit()
+        now = time.monotonic()
+        self.metrics.on_admission(
+            len(admitted),
+            queue_waits=[now - s.request.arrival_time for s in admitted])
+        for seq in admitted:
+            flight.request_event(seq.seq_id, EV_ADMITTED,
+                                 replica=self.chaos_key,
+                                 resume=seq.request.resume is not None)
+            if seq.request.resume is None and seq.cached_tokens:
+                flight.request_event(seq.seq_id, EV_PREFIX_HIT,
+                                     replica=self.chaos_key,
+                                     tokens=int(seq.cached_tokens))
+            # freshly allocated pages must quantize from scratch
+            # (dynamic int8 mode; no-op otherwise — and dynamic
+            # mode bypasses the prefix cache, so no shared page can
+            # ever be scale-reset here)
+            self._reset_page_scales(self.cache.seq_page_ids(seq.seq_id))
+            if seq.request.resume is not None:
+                # warm-failover resume: upload checkpoint pages
+                # instead of prefilling — decode continues mid-stream
+                self._upload_snapshot(seq)
+            else:
+                # hit/miss accounting and the sealing of prompt
+                # pages happened inside Scheduler.admit (host-side,
+                # so intra-batch sharing works); the device halves
+                # — the COW page copy and the suffix prefill — run
+                # here in admission order
+                deps = ()
+                if self.ragged and seq.cached_tokens \
+                        and self.prefix_cache is not None:
+                    # shared pages this sequence READS whose writer
+                    # is itself still mid-plan: the lane must idle
+                    # until their writes are issued (and the COW
+                    # copy below must wait with it — it would
+                    # duplicate an empty page)
+                    ids = self.cache.seq_page_ids(seq.seq_id)
+                    unw = self.prefix_cache.unwritten
+                    deps = {int(p) for p in
+                            ids[:seq.cached_tokens // self.page_size]
+                            if int(p) in unw}
+                    if seq.cow_pair is not None \
+                            and int(seq.cow_pair[0]) in unw:
+                        # the COW SOURCE is no longer in this
+                        # sequence's table (the host already
+                        # swapped in the copy) but the copy's
+                        # payload comes from it
+                        deps.add(int(seq.cow_pair[0]))
+                if seq.cow_pair is not None and not deps:
+                    self._apply_cow(seq)
+                if self.ragged:
+                    # unified dispatch: plan now, chunks ride the
+                    # mixed ragged steps (no dedicated prefill
+                    # program, no serialization ahead of decode)
+                    self._plan_prefill(seq, awaits=deps)
+                else:
+                    self._prefill_seq(seq)
+            self._bind_lane(seq)
+            if self.spec is not None:
+                # seed the drafter with the lane's full history
+                # (prompt, plus generated for a snapshot resume —
+                # which also restores the drafter's adaptive state)
+                self.spec.on_admit(seq)
+        return admitted
+
     # --- one scheduler iteration -----------------------------------------
     def step(self) -> dict:
         """Admit + prefill waiting requests, then dispatch one decode
@@ -2094,83 +2214,11 @@ class ServingEngine:
         # pipeline first; a FULL batch skips the attempt entirely and
         # stays pipelined under queue pressure
         if sched.waiting and len(sched.running) < sched.max_batch_size:
-            emitted += self._sync_pending()
-            if self.kv_transport is not None:
-                # admission boundary (ISSUE 16): promote tier hits for
-                # the waiting prompts, and open the ONLY window where
-                # evictions demote (admission-pressure reclaims gather
-                # D2H here; decode-time pressure keeps discarding, so
-                # steady decode never pays a transfer)
-                self.kv_transport.chaos_key = self.chaos_key
-                self.kv_transport.demote_window = True
-                try:
-                    for req in sched.waiting:
-                        if req.resume is None and req.use_prefix_cache:
-                            self.prefix_cache.promote_for(req.prompt)
-                    admitted = sched.admit()
-                finally:
-                    self.kv_transport.demote_window = False
-            else:
-                admitted = sched.admit()
-            for seq in admitted:
-                flight.request_event(seq.seq_id, EV_ADMITTED,
-                                     replica=self.chaos_key,
-                                     resume=seq.request.resume is not None)
-                if seq.request.resume is None and seq.cached_tokens:
-                    flight.request_event(seq.seq_id, EV_PREFIX_HIT,
-                                         replica=self.chaos_key,
-                                         tokens=int(seq.cached_tokens))
-                # freshly allocated pages must quantize from scratch
-                # (dynamic int8 mode; no-op otherwise — and dynamic
-                # mode bypasses the prefix cache, so no shared page can
-                # ever be scale-reset here)
-                self._reset_page_scales(self.cache.seq_page_ids(seq.seq_id))
-                if seq.request.resume is not None:
-                    # warm-failover resume: upload checkpoint pages
-                    # instead of prefilling — decode continues mid-stream
-                    self._upload_snapshot(seq)
-                else:
-                    # hit/miss accounting and the sealing of prompt
-                    # pages happened inside Scheduler.admit (host-side,
-                    # so intra-batch sharing works); the device halves
-                    # — the COW page copy and the suffix prefill — run
-                    # here in admission order
-                    deps = ()
-                    if self.ragged and seq.cached_tokens \
-                            and self.prefix_cache is not None:
-                        # shared pages this sequence READS whose writer
-                        # is itself still mid-plan: the lane must idle
-                        # until their writes are issued (and the COW
-                        # copy below must wait with it — it would
-                        # duplicate an empty page)
-                        ids = self.cache.seq_page_ids(seq.seq_id)
-                        unw = self.prefix_cache.unwritten
-                        deps = {int(p) for p in
-                                ids[:seq.cached_tokens // self.page_size]
-                                if int(p) in unw}
-                        if seq.cow_pair is not None \
-                                and int(seq.cow_pair[0]) in unw:
-                            # the COW SOURCE is no longer in this
-                            # sequence's table (the host already
-                            # swapped in the copy) but the copy's
-                            # payload comes from it
-                            deps.add(int(seq.cow_pair[0]))
-                    if seq.cow_pair is not None and not deps:
-                        self._apply_cow(seq)
-                    if self.ragged:
-                        # unified dispatch: plan now, chunks ride the
-                        # mixed ragged steps (no dedicated prefill
-                        # program, no serialization ahead of decode)
-                        self._plan_prefill(seq, awaits=deps)
-                    else:
-                        self._prefill_seq(seq)
-                self._bind_lane(seq)
-                if self.spec is not None:
-                    # seed the drafter with the lane's full history
-                    # (prompt, plus generated for a snapshot resume —
-                    # which also restores the drafter's adaptive state)
-                    self.spec.on_admit(seq)
-            self.metrics.on_admission(len(admitted))
+            with RecordEvent("serving/admit") as ev:
+                collapsed = self._sync_pending()
+                admitted = self._admit_waiting()
+                ev.set(admitted=len(admitted), collapsed=collapsed)
+            emitted += collapsed
 
         bucket = 0
         dispatched_lanes = 0
@@ -2179,10 +2227,12 @@ class ServingEngine:
             # pages for the positions this dispatch writes; preemption
             # may strike lanes (including ones with results in flight —
             # their epochs are bumped, pending tokens become no-ops)
-            preempted = sched.ensure_decode_pages(
-                [s for _, s in active if self._remaining(s) > 0])
-            if preempted:
-                self.metrics.on_preemption(len(preempted))
+            with RecordEvent("serving/ensure_pages") as ev:
+                preempted = sched.ensure_decode_pages(
+                    [s for _, s in active if self._remaining(s) > 0])
+                ev.set(preempted=len(preempted))
+                if preempted:
+                    self.metrics.on_preemption(len(preempted))
                 for victim in preempted:
                     self._uploaded_pages.pop(victim.seq_id, None)
                     stale = self._drop_plan(victim.seq_id)
@@ -2225,13 +2275,18 @@ class ServingEngine:
         # when nothing was dispatched — then drain fully so retirements
         # and the final outputs land)
         target_depth = 0 if (self.sync_mode or not bucket) else 1
-        while len(self._pending) > target_depth:
-            emitted += self._consume_one()
-        # guard verdicts land here: a lane flagged by this step's
-        # consume is failed within this same step (pipeline collapsed
-        # first so pages are never freed under an in-flight dispatch)
-        if self._quarantine_pending:
-            self._process_quarantines()
+        with RecordEvent("serving/consume") as ev:
+            consumed = 0
+            while len(self._pending) > target_depth:
+                consumed += self._consume_one()
+            # guard verdicts land here: a lane flagged by this step's
+            # consume is failed within this same step (pipeline
+            # collapsed first so pages are never freed under an
+            # in-flight dispatch)
+            if self._quarantine_pending:
+                self._process_quarantines()
+            ev.set(emitted=consumed)
+        emitted += consumed
         self._maybe_shrink()
 
         step_seconds = time.perf_counter() - t_step

@@ -70,6 +70,7 @@ from ..profiler.flight_recorder import (EV_PLACED, EV_QUEUED,
 from ..profiler.flight_recorder import recorder as flight
 from ..profiler.slo import SLOPolicy, SLOTracker
 from ..testing.chaos import chaos_site
+from ..utils.profiler import RecordEvent
 from .engine import ServingEngine
 from .metrics import FleetMetrics, FrontendMetrics, ServingMetrics
 from .resilience import (BROWNOUT_CLAMP, BROWNOUT_REJECT, BROWNOUT_SHED,
@@ -1261,51 +1262,9 @@ class ServingFrontend:
                 self.slo.maybe_evaluate()
             if rep.state == DEAD:
                 break
-            now = time.monotonic()
-            for entry in work:
-                h = entry.handle
-                if entry.cancel_requested:
-                    self._resolve(entry, CANCELLED)
-                    continue
-                if h.deadline is not None and now >= h.deadline:  # analyze: allow[determinism] request deadline SLO is wall-clock by contract
-                    self._resolve(entry, DEADLINE_MISS,
-                                  "expired in frontend queue")
-                    continue
-                try:
-                    if entry.snapshot is not None:
-                        # warm failover: resume mid-stream from the
-                        # checkpoint.  The deadline is the handle's
-                        # ABSOLUTE submit-time SLO — a requeue after
-                        # replica death must never extend it
-                        entry.snapshot.deadline = h.deadline
-                        eng.restore(entry.snapshot)
-                    else:
-                        eng.add_request(
-                            entry.prompt, entry.max_new_tokens,
-                            request_id=h.request_id,
-                            deadline=h.deadline,
-                            prefix_cache=entry.use_prefix_cache)
-                    with self._lock:
-                        entry.in_engine = True
-                except ValueError as e:
-                    # a fresh request failing validation is the caller's
-                    # fault (400); a snapshot failing to restore is an
-                    # internal failover/configuration fault (500) — the
-                    # client's original request was valid
-                    self._resolve(entry, FAILED, str(e),
-                                  error_cls=(InternalError
-                                             if entry.snapshot is not None
-                                             else InvalidArgumentError))
-            for entry in cancels:
-                if eng.abort(entry.handle.request_id):
-                    self._resolve(entry, CANCELLED)
-                # else: it finished first — the outputs harvest owns it
-            for entry in sheds:
-                if eng.abort(entry.handle.request_id):
-                    self._resolve(entry, REJECTED,
-                                  "brownout shed (lowest deadline slack)",
-                                  error_cls=UnavailableError)
-                # else: it finished first — the outputs harvest owns it
+            if work or cancels or sheds:
+                with RecordEvent("serving/pump_intake", requests=len(work)):
+                    self._intake(eng, work, cancels, sheds)
             if eng.scheduler.has_work() or eng._pending:
                 rep.step_started = time.monotonic()
                 try:
@@ -1326,16 +1285,19 @@ class ServingFrontend:
                 rep.last_step_time = t_done
                 if self.watchdog is not None:
                     self.watchdog.observe_step(rep.id, step_s)
-                self._harvest(rep, eng)
-                self._maybe_snapshot(rep, eng)
-                if rep.role == "prefill":
-                    self._ship_ready(rep, eng)
-                # snapshot/ship calls SYNC a pipelined engine: a request
-                # whose final token was still in flight at the harvest
-                # above retires during that sync, and with no work left
-                # the pump would idle with its output stranded — sweep
-                # again so the iteration that retires also resolves
-                self._harvest(rep, eng)
+                with RecordEvent("serving/harvest",
+                                 requests=len(eng.outputs)):
+                    self._harvest(rep, eng)
+                    self._maybe_snapshot(rep, eng)
+                    if rep.role == "prefill":
+                        self._ship_ready(rep, eng)
+                    # snapshot/ship calls SYNC a pipelined engine: a
+                    # request whose final token was still in flight at
+                    # the harvest above retires during that sync, and
+                    # with no work left the pump would idle with its
+                    # output stranded — sweep again so the iteration
+                    # that retires also resolves
+                    self._harvest(rep, eng)
                 fault = chaos_site("replica.kill", key=rep.id)
                 if fault is not None and fault.action == "kill":
                     self._kill(rep, f"chaos kill at step {rep.steps}")
@@ -1351,6 +1313,57 @@ class ServingFrontend:
                 rep.wake.wait(self._poll_interval)
                 rep.wake.clear()
 
+    def _intake(self, eng: ServingEngine, work, cancels, sheds):
+        """The pump's intake: hand the inbox to the engine (add or
+        snapshot-restore), then apply cancellations and brownout
+        sheds."""
+        now = time.monotonic()
+        for entry in work:
+            h = entry.handle
+            if entry.cancel_requested:
+                self._resolve(entry, CANCELLED)
+                continue
+            if h.deadline is not None and now >= h.deadline:  # analyze: allow[determinism] request deadline SLO is wall-clock by contract
+                self._resolve(entry, DEADLINE_MISS,
+                              "expired in frontend queue")
+                continue
+            try:
+                if entry.snapshot is not None:
+                    # warm failover: resume mid-stream from the
+                    # checkpoint.  The deadline is the handle's
+                    # ABSOLUTE submit-time SLO — a requeue after
+                    # replica death must never extend it
+                    entry.snapshot.deadline = h.deadline
+                    eng.restore(entry.snapshot)
+                else:
+                    eng.add_request(
+                        entry.prompt, entry.max_new_tokens,
+                        request_id=h.request_id,
+                        deadline=h.deadline,
+                        prefix_cache=entry.use_prefix_cache,
+                        arrival_time=h.submit_time)
+                with self._lock:
+                    entry.in_engine = True
+            except ValueError as e:
+                # a fresh request failing validation is the caller's
+                # fault (400); a snapshot failing to restore is an
+                # internal failover/configuration fault (500) — the
+                # client's original request was valid
+                self._resolve(entry, FAILED, str(e),
+                              error_cls=(InternalError
+                                         if entry.snapshot is not None
+                                         else InvalidArgumentError))
+        for entry in cancels:
+            if eng.abort(entry.handle.request_id):
+                self._resolve(entry, CANCELLED)
+            # else: it finished first — the outputs harvest owns it
+        for entry in sheds:
+            if eng.abort(entry.handle.request_id):
+                self._resolve(entry, REJECTED,
+                              "brownout shed (lowest deadline slack)",
+                              error_cls=UnavailableError)
+            # else: it finished first — the outputs harvest owns it
+
     def _maybe_snapshot(self, rep: Replica, eng: ServingEngine):
         """Checkpoint every request on ``rep`` that consumed
         ``snapshot_interval`` tokens since its last snapshot — the warm
@@ -1364,7 +1377,10 @@ class ServingFrontend:
                    and not e.cancel_requested and not e.shed_requested
                    and e.handle.num_tokens - e.snap_tokens >= k]
         for entry in due:
-            snap = eng.snapshot(entry.handle.request_id)
+            # the request's KV pages come off the device here, on the
+            # pump thread: nothing is dispatched while they do
+            with RecordEvent("serving/snapshot"):
+                snap = eng.snapshot(entry.handle.request_id)
             if snap is None:
                 continue          # finished/preempted meanwhile — keep old
             updated = False

@@ -120,6 +120,13 @@ class ServingMetrics:
                 # positions per speculating lane)
                 "serving.ragged.steps", "serving.ragged.decode_rows",
                 "serving.ragged.prefill_rows", "serving.ragged.spec_rows",
+                # the same dispatches' work, counted where it is
+                # dispatched (ISSUE 25): rows the program computed
+                # (lane bucket x row bucket, padding included — the
+                # exact denominator of the useful-row share), KV
+                # positions the lanes read, and (row, key) pairs attended
+                "serving.ragged.rows_computed", "serving.ragged.ctx_tokens",
+                "serving.ragged.attn_pairs",
                 # mesh-sharded serving (ISSUE 19): ragged dispatches that
                 # ran as one mesh program (every step crosses the
                 # tp/sp collectives), and maintenance traffic that had to
@@ -131,6 +138,8 @@ class ServingMetrics:
     HISTOGRAMS = ("serving.step_latency_ms", "serving.prefill_latency_ms",
                   "serving.decode_latency_ms", "serving.ttft_ms",
                   "serving.dispatch_gap_ms",
+                  # arrival (the frontend's submit time) to admission
+                  "serving.queue_wait_ms",
                   "serving.failover_recovery_ms",
                   # disaggregation (ISSUE 16): one prefill→decode ship,
                   # snapshot-gather through re-admission on the decode
@@ -177,9 +186,14 @@ class ServingMetrics:
                 clock=self._clock)
 
     # --- event hooks (called by the engine) --------------------------------
-    def on_admission(self, n: int):
+    def on_admission(self, n: int, queue_waits=()):
+        """``n`` requests admitted; ``queue_waits`` holds each one's
+        seconds between its arrival and this admission."""
         if n:
             stat_registry.get("serving.requests_admitted").add(n)
+        for wait in queue_waits:
+            stat_registry.histogram("serving.queue_wait_ms").observe(
+                wait * 1e3)
 
     def on_first_token(self, arrival_time: float, now: float):
         ttft = now - arrival_time
@@ -309,14 +323,23 @@ class ServingMetrics:
 
     # --- unified ragged dispatch (ISSUE 18) --------------------------------
     def on_ragged(self, *, decode_rows: int = 0, prefill_rows: int = 0,
-                  spec_rows: int = 0, q_bucket: int = 0):
+                  spec_rows: int = 0, q_bucket: int = 0,
+                  rows_computed: int = 0, ctx_tokens: int = 0,
+                  attn_pairs: int = 0):
         """One ``serving.ragged_step`` dispatch's row mix: ``decode_rows``
         lanes advanced one position, ``prefill_rows`` prompt positions
         rode along as chunk rows (instead of serializing ahead of the
         decode ticks), ``spec_rows`` positions were teacher-forced for
         speculative verify.  ``q_bucket`` is the step's per-lane
-        query-row bucket Q (gauged — 1 in steady decode)."""
+        query-row bucket Q (gauged — 1 in steady decode).  The step's
+        work: ``rows_computed`` = lane bucket x Q, ``ctx_tokens`` = KV
+        positions read over the lanes with a row that carries a token,
+        ``attn_pairs`` = position + 1 over every such row."""
         stat_registry.get("serving.ragged.steps").add(1)
+        stat_registry.get("serving.ragged.rows_computed").add(
+            int(rows_computed))
+        stat_registry.get("serving.ragged.ctx_tokens").add(int(ctx_tokens))
+        stat_registry.get("serving.ragged.attn_pairs").add(int(attn_pairs))
         if decode_rows:
             stat_registry.get("serving.ragged.decode_rows").add(
                 int(decode_rows))
@@ -485,7 +508,8 @@ class ServingMetrics:
         snap["ragged"] = {
             short: stat_registry.get(f"serving.ragged.{short}").get()
             for short in ("steps", "decode_rows", "prefill_rows",
-                          "spec_rows", "row_bucket")}
+                          "spec_rows", "row_bucket", "rows_computed",
+                          "ctx_tokens", "attn_pairs")}
         snap["disagg"] = {"shipped_pages": stat_registry.get(
             "serving.disagg.shipped_pages").get()}
         snap["shard"] = {

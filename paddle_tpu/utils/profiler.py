@@ -2,13 +2,23 @@
 platform/profiler.h:127 RecordEvent, device_tracer.h CUPTI timeline).
 
 TPU-native: jax.profiler (XPlane/TensorBoard trace — libtpu's tracer
-subsumes DeviceTracer) + named_scope RecordEvent analog.  RecordEvent is
-rebased on ``paddle_tpu.profiler.tracer`` — every event is a span on the
-thread-local span stack (parent/child links, Chrome-trace exportable via
-``paddle_tpu.profiler.export_chrome_trace``) AND a jax.named_scope, so
-the same name shows up in the XPlane/device timeline.  The summary table
-reads the tracer's aggregate registry, which is lock-protected (the old
-module-level defaultdict dropped counts under concurrent ``__exit__``).
+subsumes DeviceTracer) + RecordEvent.  RecordEvent is the ONE way the
+program opens a span, and every event is three things at once:
+
+* a span on ``paddle_tpu.profiler.tracer``'s thread-local stack
+  (parent/child links, Chrome-trace exportable via
+  ``paddle_tpu.profiler.export_chrome_trace``; host clock);
+* a ``jax.profiler.TraceAnnotation`` — while a ``jax.profiler`` trace
+  runs it is a TraceMe event on the thread's line of the host plane,
+  its args as the event's stats, on the clock the device planes use
+  (so device idle time can be laid against it).  Nothing switches it:
+  a running trace is what turns it on, and without one it costs ~1 us;
+* a ``jax.named_scope``, which only names the HLO of whatever is being
+  traced inside it — it emits nothing when a compiled step runs.
+
+The summary table reads the tracer's aggregate registry, which is
+lock-protected (the old module-level defaultdict dropped counts under
+concurrent ``__exit__``).
 """
 from __future__ import annotations
 
@@ -22,9 +32,19 @@ from ..profiler.tracer import tracer as _tracer
 _active_trace_dir = None
 
 
+def _trace_args(args):
+    """What a TraceMe event can carry as stats: numbers and short
+    strings.  Anything else stays on the tracer span only."""
+    return {k: (int(v) if isinstance(v, bool) else v)
+            for k, v in args.items()
+            if isinstance(v, (int, float))
+            or (isinstance(v, str) and len(v) <= 64)}
+
+
 class RecordEvent:
     """RAII op-scope timer (platform/profiler.h:127): a hierarchical
-    tracer span + a jax.named_scope (device-timeline annotation)."""
+    tracer span, a TraceMe event in a running ``jax.profiler`` trace,
+    and a jax.named_scope (HLO names at trace time)."""
 
     def __init__(self, name, **args):
         self.name = name
@@ -33,11 +53,23 @@ class RecordEvent:
     def __enter__(self):
         self._scope = jax.named_scope(self.name)
         self._scope.__enter__()
+        self._mark = jax.profiler.TraceAnnotation(
+            self.name, **_trace_args(self._args or {}))
+        self._mark.__enter__()
         self._span = _tracer.begin(self.name, self._args)
         return self
 
+    def set(self, **args):
+        """Add args known only once the work is done (an admission's
+        count, a consume's tokens) — to the span and to the event."""
+        if self._span.args is None:
+            self._span.args = {}
+        self._span.args.update(args)
+        self._mark.set_metadata(**_trace_args(args))
+
     def __exit__(self, *exc):
         _tracer.end(self._span)
+        self._mark.__exit__(*exc)
         self._scope.__exit__(*exc)
         return False
 

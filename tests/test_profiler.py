@@ -403,3 +403,110 @@ class TestRecordEventOverhead:
                 pass
         per_call = (time.perf_counter() - t0) / n
         assert per_call < 150e-6, f"{per_call * 1e6:.1f}µs per event"
+
+
+class TestRecordEventInTheProfilerTrace:
+    """ISSUE 25: while a ``jax.profiler`` trace runs, a RecordEvent is a
+    TraceMe event on its thread's line of the host plane — its args the
+    event's stats, nested as the tracer's span stack says — on the clock
+    the device planes use.  Nothing switches it on but the trace."""
+
+    @staticmethod
+    def _trace(tmp_path, body):
+        import glob
+        import os
+
+        import jax
+        from jax.profiler import ProfileData
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                      "*", "*.xplane.pb"))[0]
+        out = []
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("probe/"):
+                        out.append((e.name, line.name, e.start_ns,
+                                    e.start_ns + e.duration_ns,
+                                    dict(e.stats)))
+        return out
+
+    def test_args_are_stats_and_nesting_matches_the_tracer(self, tmp_path):
+        def body():
+            with RecordEvent("probe/step", lanes=48, kind="mixed",
+                             ratio=0.5, flag=True,
+                             skipped=[1, 2], long="x" * 200):
+                with RecordEvent("probe/admit") as ev:
+                    jnp.ones(8).block_until_ready()
+                    ev.set(admitted=3, collapsed=7)
+                with RecordEvent("probe/dispatch", rows=64):
+                    pass
+
+        profiler.enable_tracing()
+        events = {e[0]: e for e in self._trace(tmp_path, body)}
+        profiler.disable_tracing()
+        assert set(events) == {"probe/step", "probe/admit",
+                               "probe/dispatch"}
+        # numbers and short strings travel; a list and a long string
+        # stay on the tracer span only
+        assert events["probe/step"][4] == {"lanes": 48, "kind": "mixed",
+                                           "ratio": 0.5, "flag": 1}
+        assert events["probe/admit"][4] == {"admitted": 3, "collapsed": 7}
+        assert events["probe/dispatch"][4] == {"rows": 64}
+        # one thread, one line; children inside the parent, in order
+        assert len({e[1] for e in events.values()}) == 1
+        _, _, s0, s1, _ = events["probe/step"]
+        _, _, a0, a1, _ = events["probe/admit"]
+        _, _, d0, d1, _ = events["probe/dispatch"]
+        assert s0 <= a0 < a1 <= d0 <= d1 <= s1
+        # ... which is what the tracer's own span stack recorded
+        spans = {s.name: s for s in profiler.get_spans()}
+        assert spans["probe/admit"].parent_id \
+            == spans["probe/dispatch"].parent_id \
+            == spans["probe/step"].span_id
+        assert spans["probe/admit"].args == {"admitted": 3, "collapsed": 7}
+        assert spans["probe/step"].args["skipped"] == [1, 2]
+
+    def test_without_a_trace_nothing_is_emitted_and_set_still_works(self):
+        profiler.enable_tracing()
+        with RecordEvent("probe/quiet") as ev:
+            ev.set(n=1)
+        (sp,) = [s for s in profiler.get_spans() if s.name == "probe/quiet"]
+        assert sp.args == {"n": 1}
+
+    def test_train_batch_is_one_span_per_step_on_both_routes(self):
+        """The span moved from fit()'s loop into Model.train_batch: one
+        per step whether fit or the caller drives, with the host's phases
+        as children."""
+        from paddle_tpu import nn, optimizer
+
+        net = nn.Linear(4, 2)
+        model = paddle.Model(net)
+        model.prepare(optimizer.SGD(0.1, parameters=net.parameters()),
+                      nn.MSELoss())
+        x = np.ones((8, 4), np.float32)
+        y = np.zeros((8, 2), np.float32)
+        before = histogram_snapshot("hapi.train_batch_ms")["count"]
+        profiler.enable_tracing()
+        model.train_batch([x], [y])
+        model.fit([(x[i], y[i]) for i in range(8)], epochs=1, verbose=0)
+        in_fit = histogram_snapshot("hapi.train_batch_ms")["count"] - before
+        spans = profiler.get_spans()
+        steps = [s for s in spans if s.name == "hapi/train_batch"]
+        assert in_fit >= 2 and len(steps) == 1 + in_fit
+        ids = {s.span_id for s in steps}
+        for phase in ("inputs", "dispatch", "fetch_loss", "metrics"):
+            kids = [s for s in spans
+                    if s.name == f"hapi/train_batch/{phase}"]
+            assert len(kids) == len(steps)
+            assert {k.parent_id for k in kids} == ids
+        (epoch,) = [s for s in spans if s.name == "hapi/fit.epoch"]
+        assert steps[0].parent_id is None
+        assert {s.parent_id for s in steps[1:]} == {epoch.span_id}
