@@ -11,7 +11,11 @@ from a seed), and checks what comes out by the repo's own means:
            start_http_server; six POST /generate requests (in concurrent
            pairs) over the default dispatch (unified ragged step,
            native KV); every engine token checked against the dense
-           forward's LOGITS.
+           forward's LOGITS.  Then the KV pools' layout: the bytes they
+           hold on the device against their logical bytes, and the
+           unified step's optimised program, which may hold no pad, copy
+           or transpose of a whole pool and must update each in place
+           (the check a builder runs before the benchmark does).
   train    three AdamW steps at seq 2048, batch 4, bf16 autocast through
            paddle.Model.prepare/train_batch on one repeated batch.
   kernels  every entry of contracts.CONTRACTS compiled on the chip (never
@@ -245,7 +249,55 @@ def post_generate(port, prompt, max_new_tokens, out, key):
         conn.close()
 
 
+def _bytes_in_use(device):
+    return device.memory_stats()["bytes_in_use"]
+
+
+def pool_layout_check(tag, engine, allocated, lanes, shards=(1, 1)):
+    """The pools are stored in the layout the ragged kernel reads: they
+    hold their logical bytes on the device (``allocated``: what building
+    the engine added to the device, pools and lane state; None for a
+    mesh engine, whose construction also places the weights; ``shards``
+    = its (sp, tp)), and the unified step program, as this chip's
+    compiler optimised it, rewrites no pool whole — no pad, copy or
+    transpose of pool shape, every pool aliased to its output."""
+    import jax
+
+    from paddle_tpu.serving.engine import (aliased_arguments,
+                                           whole_pool_relayouts)
+
+    sp, tp = shards
+    pools = jax.tree_util.tree_leaves(engine._kv)
+    logical = engine.kv_cache_bytes() // (sp * tp)
+    held = "not measured"
+    if allocated is not None:
+        check(allocated <= 1.02 * logical + 2 ** 24,
+              f"{tag}: building the engine took {allocated} B of the "
+              f"device for pools of {logical} logical B — the pools are "
+              f"stored padded")
+        held = f"{allocated} B (x{allocated / logical:.3f})"
+    compiled = engine.lower_ragged_step(PREFILL_CHUNK, lanes=lanes).compile()
+    text = compiled.as_text()
+    found = whole_pool_relayouts(text, engine.cache.num_pages // sp,
+                                 PAGE_SIZE)
+    check(not found, f"{tag}: the unified step program rewrites whole KV "
+          f"pools: {sorted(set(found))} x{len(found)}")
+    aliased = aliased_arguments(text)
+    check(aliased == len(pools), f"{tag}: {aliased} of {len(pools)} pool "
+          f"arguments are aliased to an output")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"[{tag}] PASS: {len(pools)} pools of {tuple(pools[0].shape)} "
+          f"{pools[0].dtype}, {logical} logical B a device, held on the "
+          f"device: {held}; unified step at "
+          f"{lanes} x {PREFILL_CHUNK} rows: no pad/copy/transpose of "
+          f"pool shape, {aliased}/{len(pools)} pools updated in place, "
+          f"{temp} B of temporaries (info: the logits among them)",
+          flush=True)
+
+
 def serve_phase(model, dense_logits):
+    import jax
+
     from paddle_tpu.inference import Config
     from paddle_tpu.ops.pallas_ops.paged_attention import PAGED_ROUTE_STATS
     from paddle_tpu.serving import (create_serving_frontend,
@@ -259,7 +311,10 @@ def serve_phase(model, dense_logits):
     cfg.enable_serving(max_batch_size=8, page_size=PAGE_SIZE,
                        max_seq_len=SERVE_MAX_SEQ_LEN, eos_id=-1,
                        prefill_chunk=PREFILL_CHUNK, replicas=1)
+    device = jax.devices()[0]
+    before = _bytes_in_use(device)
     frontend = create_serving_frontend(model, cfg)
+    allocated = _bytes_in_use(device) - before
     server = start_http_server(frontend, port=0)
     results = {}
     try:
@@ -306,6 +361,8 @@ def serve_phase(model, dense_logits):
               f"paged attention routes: pallas {pallas}, xla {xla}")
         ragged = engine.stats()["pipeline"]["ragged"]
         check(ragged is True, "the engine did not run the unified step")
+        # requests ran in pairs: the two-lane bucket is the one compiled
+        pool_layout_check("serve", engine, allocated, lanes=2)
     finally:
         server.stop()
         frontend.close()
@@ -484,10 +541,6 @@ def _assert_spread(label, array, n_devices):
     return per
 
 
-def _bytes_in_use(device):
-    return device.memory_stats()["bytes_in_use"]
-
-
 def mesh_serve_phase(model, dense_logits, prompts, one_chip_streams):
     import jax
     import numpy as np
@@ -533,6 +586,8 @@ def mesh_serve_phase(model, dense_logits, prompts, one_chip_streams):
           f"streams; paged routes pallas={pallas} xla={xla}", flush=True)
     print(f"[mesh] PASS: KV pool shards (elements per device) {pool}; "
           f"bytes in use per device {mem}", flush=True)
+    # all six requests were admitted at once: the eight-lane bucket
+    pool_layout_check("mesh", engine, None, lanes=8, shards=(2, 2))
 
 
 def mesh_train_phase(model):

@@ -174,8 +174,9 @@ def paged_attention(query, key_pages, value_pages, page_tables, seq_lens,
     (the serving engine's attention primitive; see docs/SERVING.md).
 
     query       [B, H, D]    one decode query per in-flight sequence
-    key_pages   [N, P, H, D] global K page pool (P = page size)
-    value_pages [N, P, H, D] global V page pool
+    key_pages   [N, P, H, D] global K page pool (P = page size), or the
+                             serving pools' stored form [N, P, H*D]
+    value_pages [N, P, H, D] global V page pool (or [N, P, H*D])
     page_tables [B, M] int32 per-sequence page ids (pad with 0, the
                              reserved trash page)
     seq_lens    [B] int32    valid KV length per sequence (0 = inactive)
@@ -190,7 +191,15 @@ def paged_attention(query, key_pages, value_pages, page_tables, seq_lens,
     interpret mode for testing.  Int8 pools are dequantized in-register
     inside the kernel (docs/SERVING.md "Quantized serving").
     """
-    from .pallas_ops.paged_attention import paged_attention as _core
+    from .pallas_ops.paged_attention import paged_attention as _paged
+
+    def _core(q, kp, vp, *rest):
+        # the kernels read pools as the engine stores them, heads and
+        # head_dim fused in one row; a per-head [N, P, H, D] pool is
+        # fused here (a relayout on TPU unless D is whole lane tiles —
+        # the engine's own pools never take it)
+        return _paged(q, kp.reshape(*kp.shape[:2], -1),
+                      vp.reshape(*vp.shape[:2], -1), *rest)
 
     if (key_scales is None) != (value_scales is None):
         raise ValueError("key_scales and value_scales must be passed "

@@ -48,10 +48,10 @@ def paged_inputs(H, D, page_size, *, int8, pages=40, rows=16, table=8):
     the engine dispatches it: a steady-decode lane (one live row), a
     prefill-chunk lane (every row live, ascending positions) and a
     spec-verify-shaped lane (a few rows).  Returns ``(q [3, rows, H, D],
-    k_pool, v_pool [pages, page_size, H, D], page_tables [3, table],
-    row_lens [3, rows], page_ok [3, table], k_scales, v_scales)``; the
-    pools are int8 with per-page-per-head scales when ``int8``, else f32
-    and the scales are None."""
+    k_pool, v_pool [pages, page_size, H*D] — the stored layout —
+    page_tables [3, table], row_lens [3, rows], page_ok [3, table],
+    k_scales, v_scales)``; the pools are int8 with per-page-per-head
+    scales when ``int8``, else f32 and the scales are None."""
     import jax.numpy as jnp
 
     rng = np.random.RandomState(7)
@@ -75,7 +75,9 @@ def paged_inputs(H, D, page_size, *, int8, pages=40, rows=16, table=8):
         vf = np.clip(np.round(vf / vs[:, None, :, None]), -127,
                      127).astype(np.int8)
         ks, vs = jnp.asarray(ks), jnp.asarray(vs)
-    return (jnp.asarray(q), jnp.asarray(kf), jnp.asarray(vf),
+    fused = (pages, page_size, H * D)
+    return (jnp.asarray(q), jnp.asarray(kf.reshape(fused)),
+            jnp.asarray(vf.reshape(fused)),
             jnp.asarray(pt), jnp.asarray(rl), jnp.asarray(ok), ks, vs)
 
 

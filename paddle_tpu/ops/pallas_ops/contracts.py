@@ -296,24 +296,32 @@ FLASH_BWD_DQ = KernelContract(
 # of up to ``q_align`` query rows (decode lane = 1 row — the decode
 # entry point IS this kernel at Q = 1 — chunked-prefill lane = chunk
 # rows, spec-verify lane = K rows) sharing ONE page-table row, so the
-# page DMA is paid once per lane instead of once per query row.  One
-# block = one physical KV page; the wrapper pads heads to the f32
-# sublane floor and head_dim to the lane width, so the contract dims
-# ARE the padding constants the wrapper reads.
+# page DMA is paid once per lane instead of once per query row.
+#
+# One block = one physical KV page AS THE POOL STORES IT (ISSUE 26):
+# ``[page_size, kv_width]`` with ``kv_width = heads * head_dim`` — heads
+# and head_dim fused in one row, so the stored bytes are the logical
+# bytes for every model shape and the wrapper pads, transposes and
+# copies nothing of pool size (the only padding left is the query-row
+# dim, to ``q_align``).  q and o ride in the same fused-row layout; the
+# body walks the row in ``lane``-wide windows (a head narrower than a
+# lane tile shares its window with its neighbours).
 #
 # Every VMEM block below obeys the rule the TPU lowering ENFORCES — the
 # trailing two block dims are (8k, 128k) or span the whole array extent
-# (``lanes_full``/``sublane_full``) — with no waiver: q/o/lse and the
-# online-softmax scratch are head-major ([heads, q_align, .]) so the
-# body indexes whole tiles per head; row_lens rides as [G, q_align, 1]
-# and the int8 scale rows as [N, 1, heads].
+# (``lanes_full``/``sublane_full``) — with no waiver: the fused row
+# always spans the array's whole last dim (and is whole lane tiles at
+# the declared shape); the staged query, the accumulator and the
+# online-softmax m/l scratch are per head, [heads, q_align, .]; row_lens
+# rides as [G, q_align, 1], lse as
+# [G, heads, q_align, 1] and the int8 scale rows as [N, 1, heads].
 # ===========================================================================
 PAGED_RAGGED = KernelContract(
     name="paged_attention_ragged",
     module="paddle_tpu/ops/pallas_ops/paged_attention.py",
     grid=("groups", "pages_per_seq"),
-    dims={"page_size": 16, "heads": 8, "head_dim": 128, "lane": 128,
-          "head_align": 8, "q_align": 8},
+    dims={"page_size": 16, "heads": 8, "head_dim": 128, "kv_width": 1024,
+          "lane": 128, "q_align": 8},
     blocks=(
         BlockDecl("page_tables", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
@@ -321,13 +329,19 @@ PAGED_RAGGED = KernelContract(
                   memory="smem"),
         BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
                   lanes_full=True),
-        BlockDecl("q", "in", (1, "heads", "q_align", "head_dim"),
-                  "float32"),
-        BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "float32"),
-        BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "float32"),
-        BlockDecl("o", "out", (1, "heads", "q_align", "head_dim"),
+        BlockDecl("q", "in", (1, "q_align", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("k_page", "in", (1, "page_size", "kv_width"),
+                  "float32", lanes_full=True),
+        BlockDecl("v_page", "in", (1, "page_size", "kv_width"),
+                  "float32", lanes_full=True),
+        BlockDecl("o", "out", (1, "q_align", "kv_width"), "float32",
+                  lanes_full=True),
+        # per head, one lane window wide (head_dim; the lane width where
+        # narrower heads share a window): the query staged once per lane
+        # (scaled, the window's other heads' lanes zeroed) and the
+        # accumulator
+        BlockDecl("q_stage", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("acc", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
@@ -337,12 +351,10 @@ PAGED_RAGGED = KernelContract(
                   "float32"),
     ),
     shape_buckets={"head_dim": (128, 256), "heads": (8, 16, 32)},
-    # the head padding floor is a legal relayout knob: any multiple of
-    # the f32 sublane floor tiles, padded heads are never visited;
     # q_align is the padding floor for the per-lane query-row dim —
-    # padded rows carry row_len 0 and are sliced off, so both axes are
+    # padded rows carry row_len 0 and are sliced off, so the axis is
     # exactly parity-preserving
-    sweep={"head_align": (8, 16), "q_align": (8, 16)},
+    sweep={"q_align": (8, 16)},
 )
 
 PAGED_RAGGED_INT8 = KernelContract(
@@ -355,8 +367,8 @@ PAGED_RAGGED_INT8 = KernelContract(
     # stream 1 byte/element from HBM — the choice moves the multiply
     # between the VPU epilogue and the MXU operand path, which is
     # exactly the kind of platform-dependent tie the sweep measures.
-    dims={"page_size": 16, "heads": 8, "head_dim": 128, "lane": 128,
-          "head_align": 8, "q_align": 8, "fused_dequant": 1},
+    dims={"page_size": 16, "heads": 8, "head_dim": 128, "kv_width": 1024,
+          "lane": 128, "q_align": 8, "fused_dequant": 1},
     blocks=(
         BlockDecl("page_tables", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
@@ -364,22 +376,27 @@ PAGED_RAGGED_INT8 = KernelContract(
                   memory="smem"),
         BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
                   lanes_full=True),
-        BlockDecl("q", "in", (1, "heads", "q_align", "head_dim"),
-                  "float32"),
-        # int8 pages keep the f32 page layout (heads padded to 8, not
-        # the int8 floor 32 — padding H 4x for storage tiling would
-        # quadruple page bytes and defeat the int8 win); the block spans
-        # the pool's whole (padded) head extent, which the lowering
-        # accepts at any extent
-        BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "int8", sublane_full=True),
-        BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "int8", sublane_full=True),
+        BlockDecl("q", "in", (1, "q_align", "kv_width"), "float32",
+                  lanes_full=True),
+        # int8 pages keep the f32 page layout ([page_size, kv_width]:
+        # 16 rows, not the int8 floor 32 — the block is the WHOLE page,
+        # which the lowering accepts at any extent); each lane window is
+        # converted to f32 in-register as it is loaded
+        BlockDecl("k_page", "in", (1, "page_size", "kv_width"), "int8",
+                  lanes_full=True, sublane_full=True),
+        BlockDecl("v_page", "in", (1, "page_size", "kv_width"), "int8",
+                  lanes_full=True, sublane_full=True),
         BlockDecl("k_scales", "in", (1, 1, "heads"), "float32",
                   lanes_full=True, sublane_full=True),
         BlockDecl("v_scales", "in", (1, 1, "heads"), "float32",
                   lanes_full=True, sublane_full=True),
-        BlockDecl("o", "out", (1, "heads", "q_align", "head_dim"),
+        BlockDecl("o", "out", (1, "q_align", "kv_width"), "float32",
+                  lanes_full=True),
+        # per head, one lane window wide (head_dim; the lane width where
+        # narrower heads share a window): the query staged once per lane
+        # (scaled, the window's other heads' lanes zeroed) and the
+        # accumulator
+        BlockDecl("q_stage", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("acc", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
@@ -387,40 +404,34 @@ PAGED_RAGGED_INT8 = KernelContract(
                   "float32"),
         BlockDecl("l", "scratch", ("heads", "q_align", "lane"),
                   "float32"),
-        # Mosaic's sublane-strided load (head h's [P, D] slab of the
-        # page) exists for 32-bit data only: the int8 page is converted
-        # once into an f32 stage and the slabs are loaded from there
-        BlockDecl("k_stage", "scratch",
-                  ("page_size", "heads", "head_dim"), "float32"),
-        BlockDecl("v_stage", "scratch",
-                  ("page_size", "heads", "head_dim"), "float32"),
     ),
     shape_buckets={"head_dim": (128, 256), "heads": (8, 16, 32)},
     # fused_dequant moves the scale multiply across the dot — NOT
     # bit-exact (rounding points differ), so the non-default choice only
     # survives a sweep run with an explicit tolerance (docs/TUNING.md);
-    # head_align/q_align are exactly parity-preserving
-    sweep={"head_align": (8, 16), "q_align": (8, 16),
-           "fused_dequant": (0, 1)},
+    # q_align is exactly parity-preserving
+    sweep={"q_align": (8, 16), "fused_dequant": (0, 1)},
 )
 
 # ===========================================================================
 # paged_attention.py — mesh-aware head-shard STATS form (ISSUE 19).
 # Same body/grid/scratch as the ragged contract, but the kernel runs
 # on ONE mesh shard: its page pool holds the shard's 1/sp of the pages
-# (and its H/tp head-shard of each), a third scalar-prefetch operand
-# masks page-table entries by OWNERSHIP, and alongside the locally-
-# normalized context the kernel emits the online-softmax running stats
-# as lse = m + log(l) — the cross-shard merge (pmax of lse, psum of
-# exp-weighted context/denominator) lives in the sharded serving core
+# (and its H/tp head-shard of each — a contiguous slice of the fused
+# row, so the shard's pool is again [pages, page_size, kv_width] at its
+# local width), a third scalar-prefetch operand masks page-table
+# entries by OWNERSHIP, and alongside the locally-normalized context
+# the kernel emits the online-softmax running stats as lse = m + log(l)
+# — the cross-shard merge (pmax of lse, psum of exp-weighted
+# context/denominator) lives in the sharded serving core
 # (text/generation.py), mirroring distributed/ring_attention.py.
 # ===========================================================================
 PAGED_RAGGED_STATS = KernelContract(
     name="paged_attention_ragged_stats",
     module="paddle_tpu/ops/pallas_ops/paged_attention.py",
     grid=("groups", "pages_per_seq"),
-    dims={"page_size": 16, "heads": 8, "head_dim": 128, "lane": 128,
-          "head_align": 8, "q_align": 8},
+    dims={"page_size": 16, "heads": 8, "head_dim": 128, "kv_width": 1024,
+          "lane": 128, "q_align": 8},
     blocks=(
         BlockDecl("page_tables", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
@@ -430,18 +441,24 @@ PAGED_RAGGED_STATS = KernelContract(
                   "int32", memory="smem"),
         BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
                   lanes_full=True),
-        BlockDecl("q", "in", (1, "heads", "q_align", "head_dim"),
-                  "float32"),
-        BlockDecl("k_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "float32"),
-        BlockDecl("v_page", "in", (1, "page_size", "heads", "head_dim"),
-                  "float32"),
-        BlockDecl("o", "out", (1, "heads", "q_align", "head_dim"),
-                  "float32"),
+        BlockDecl("q", "in", (1, "q_align", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("k_page", "in", (1, "page_size", "kv_width"),
+                  "float32", lanes_full=True),
+        BlockDecl("v_page", "in", (1, "page_size", "kv_width"),
+                  "float32", lanes_full=True),
+        BlockDecl("o", "out", (1, "q_align", "kv_width"), "float32",
+                  lanes_full=True),
         # one lse value per (head, row), carried [.., q_align, 1] like
         # the flash kernels' lse
         BlockDecl("lse", "out", (1, "heads", "q_align", 1), "float32",
                   lanes_full=True),
+        # per head, one lane window wide (head_dim; the lane width where
+        # narrower heads share a window): the query staged once per lane
+        # (scaled, the window's other heads' lanes zeroed) and the
+        # accumulator
+        BlockDecl("q_stage", "scratch", ("heads", "q_align", "head_dim"),
+                  "float32"),
         BlockDecl("acc", "scratch", ("heads", "q_align", "head_dim"),
                   "float32"),
         BlockDecl("m", "scratch", ("heads", "q_align", "lane"),

@@ -21,6 +21,17 @@ sequence's length are skipped with ``pl.when`` (ragged early-out), so
 decode cost is proportional to real tokens, not to the padded page
 count.
 
+Pool layout (ISSUE 26): the pools are stored ``[N, P, H*D]`` — heads
+and head_dim fused in one row — and the kernel's page block is that
+row-major page itself, ``(1, P, H*D)``: tile-exact for every (H, D), so
+the stored bytes are the logical bytes and NOTHING of pool size is
+padded, transposed or copied on the way in (a per-head ``[N, P, 12, 64]``
+pool cost GPT-2 small two relayouts and a pad of every pool in every
+step, 55% of the step on the v5e).  q and o ride in the same fused-row
+layout; the body walks the row in 128-lane windows (``_lane_groups``),
+two D = 64 heads to a window.  Every entry point
+below takes pools in this layout and refuses any other.
+
 Page-table convention (shared with serving/kv_cache.py): page id 0 is a
 reserved trash page — padding entries point at it and masked/inactive
 lanes scatter into it — so every page-table entry is always a valid
@@ -29,7 +40,8 @@ index and the kernel needs no bounds checks.
 Quantized KV (the int8 serving path): when the page pools are int8 the
 caller passes per-page-per-head fp32 scale arrays ``k_scales`` /
 ``v_scales`` ([N, H]); the kernel DMAs the page's scale row alongside
-the page and dequantizes IN-REGISTER — the q·k logits pick up the K
+the page and dequantizes IN-REGISTER (each lane window of the int8 page
+is converted to f32 as it is loaded) — the q·k logits pick up the K
 scale as a per-head multiply after the dot, the context accumulation
 picks up the V scale the same way, so HBM streams 1 byte per KV element
 instead of 2 and the f32 softmax math is unchanged.  Layout and the
@@ -57,34 +69,30 @@ from .contracts import (PAGED_RAGGED, PAGED_RAGGED_INT8,
 
 NEG_INF = -1e30
 
-# padding constants from the declared KernelContract (contracts.py):
-# heads pad to the f32 sublane floor, head_dim to the lane width, the
-# per-lane query-row dim to its own floor — the pallas-contract lint
-# checks the same values the kernel runs with
+# constants from the declared KernelContract (contracts.py): the lane
+# width the fused row is walked in, and the padding floor of the
+# per-lane query-row dim (the ONLY thing the wrapper pads) — the
+# pallas-contract lint checks the same values the kernel runs with
 _LANE = PAGED_RAGGED.dim("lane")
-_RAGGED_HEAD_ALIGN = PAGED_RAGGED.dim("head_align")
 _RAGGED_Q_ALIGN = PAGED_RAGGED.dim("q_align")
 _RAGGED_FUSED_DEQUANT = PAGED_RAGGED_INT8.dim("fused_dequant")
 # mesh-aware head-shard stats form (ISSUE 19)
-_STATS_HEAD_ALIGN = PAGED_RAGGED_STATS.dim("head_align")
 _STATS_Q_ALIGN = PAGED_RAGGED_STATS.dim("q_align")
 
 
 def _ragged_resolved_dims(H, D, quantized):
-    """(head_align, q_align, fused_dequant) for a ragged-query call:
-    tuning-table hit (validate()-gated at the (heads, head_dim) shape
-    bucket) -> contract default.  With no table installed this is a
-    single None check."""
+    """(q_align, fused_dequant) for a ragged-query call: tuning-table
+    hit (validate()-gated at the (heads, head_dim) shape bucket) ->
+    contract default.  With no table installed this is a single None
+    check."""
     from ...tune.runtime import lookup_dims
 
     contract = PAGED_RAGGED_INT8 if quantized else PAGED_RAGGED
     tuned = lookup_dims(contract, {"heads": H, "head_dim": D},
                         dtype="int8" if quantized else "float32")
     if tuned is None:
-        return (_RAGGED_HEAD_ALIGN, _RAGGED_Q_ALIGN,
-                bool(_RAGGED_FUSED_DEQUANT))
-    return (tuned.get("head_align", _RAGGED_HEAD_ALIGN),
-            tuned.get("q_align", _RAGGED_Q_ALIGN),
+        return _RAGGED_Q_ALIGN, bool(_RAGGED_FUSED_DEQUANT)
+    return (tuned.get("q_align", _RAGGED_Q_ALIGN),
             bool(tuned.get("fused_dequant", _RAGGED_FUSED_DEQUANT)))
 
 # trace-time routing telemetry, mirroring ops/attention.py ROUTE_STATS
@@ -102,14 +110,15 @@ def _compiler_params():
 
 def paged_attention_kernel(q, k_pages, v_pages, page_tables, seq_lens,
                            k_scales=None, v_scales=None, *, interpret=None,
-                           head_align=None, fused_dequant=None):
+                           fused_dequant=None):
     """One decode query per sequence — the ragged-query kernel at Q = 1
     (the same computation: one query row per lane against the lane's
     page-table row), so there is one kernel body to compile.
 
     q           [B, H, D]   one decode query per sequence
-    k_pages     [N, P, H, D] global K page pool (page_size = P)
-    v_pages     [N, P, H, D] global V page pool
+    k_pages     [N, P, H*D] global K page pool (page_size = P), stored
+                             with heads and head_dim fused in one row
+    v_pages     [N, P, H*D] global V page pool
     page_tables [B, M] int32 page ids per sequence (pad with 0)
     seq_lens    [B] int32    valid KV length per sequence (0 = inactive)
     k_scales    [N, H] fp32  per-page-per-head K dequant scales
@@ -120,14 +129,16 @@ def paged_attention_kernel(q, k_pages, v_pages, page_tables, seq_lens,
     """
     return ragged_paged_attention_kernel(
         q[:, None], k_pages, v_pages, page_tables, seq_lens[:, None],
-        k_scales, v_scales, interpret=interpret, head_align=head_align,
+        k_scales, v_scales, interpret=interpret,
         fused_dequant=fused_dequant)[:, 0]
 
 
 def paged_attention_xla(q, k_pages, v_pages, page_tables, seq_lens,
                         k_scales=None, v_scales=None):
-    """Exact XLA reference: gather the sequence's pages into a dense
-    [B, M*P, H, D] view and run masked attention.  O(B·M·P·H·D) memory
+    """Exact XLA reference: gather the sequence's pages (stored
+    [N, P, H*D]) into a dense [B, M*P, H, D] view — the split of the
+    fused row touches the gathered pages only, never the pool — and run
+    masked attention.  O(B·M·P·H·D) memory
     traffic per decode step — the thing the kernel exists to avoid — but
     bit-exact f32 softmax math, so it is the default CPU route.  Int8
     pages are dequantized after the gather with their per-page-per-head
@@ -194,8 +205,20 @@ def paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
 # ===========================================================================
 
 
-def _ragged_body(*refs, scale, page_size, num_pages_grid, heads,
-                 quantized, stats, fused_dequant, staged):
+def _lane_groups(H, D):
+    """Static ``(lo, hi)`` lane windows that tile the fused ``H*D`` row.
+
+    A head narrower than the 128-lane tile shares a window with its
+    neighbours (GPT-2's D = 64: two heads a window), so every load,
+    store and dot operand in the body starts on a lane-tile boundary
+    and is a whole tile wide; a head of one or more whole lane tiles is
+    its own window."""
+    W = _LANE if (D < _LANE and _LANE % D == 0) else D
+    return [(lo, min(lo + W, H * D)) for lo in range(0, H * D, W)]
+
+
+def _ragged_body(*refs, scale, page_size, num_pages_grid, heads, head_dim,
+                 quantized, stats, fused_dequant):
     """Grid (G, max_pages_per_seq), pages innermost: per lane g the body
     visits the lane's pages in order, keeping flash-style running
     max/denominator per (head, query row) in VMEM scratch; the page to
@@ -203,18 +226,22 @@ def _ragged_body(*refs, scale, page_size, num_pages_grid, heads,
 
     One body serves every form (``quantized``: int8 pages + scale rows;
     ``stats``: page-ownership mask + lse output) because each is the same
-    page step, written the way Mosaic accepts it:
+    page step over the page AS STORED — a ``[P, H*D]`` tile, q and o in
+    the same fused-row layout ``[Qp, H*D]``, so nothing is padded,
+    transposed or strided:
 
-    - heads are a STATIC loop of 2-D matmuls ([Qp, D] x [D, P] and
-      [Qp, P] x [P, D]) — Mosaic has no dot with a batch dim in the
-      middle of an operand, and q/o/lse are head-major ([H, Qp, .]) so
-      ``ref[0, h]`` is a whole tile and nothing is transposed in-kernel;
-    - head h's [P, D] slab of the [P, H, D] page is a sublane-strided
-      load from the page viewed as [P*H, D] (a free view: H is padded to
-      the f32 sublane tile);
-    - strided loads exist for 32-bit data only, so bf16/int8 pages are
-      converted once per page into an f32 VMEM stage (``staged``) and
-      the slabs are loaded from there.
+    - the fused row is walked in static lane windows (``_lane_groups``);
+      a window's k/v slabs are plain aligned slices of the page tile,
+      loaded (and converted to f32) once per window;
+    - heads are a STATIC loop of 2-D matmuls inside their window
+      ([Qp, W] x [W, P] and [Qp, P] x [P, W]).  Where a window holds
+      several heads (D < 128), head h's query is staged ONCE per lane
+      (``q_sc``: scaled, the other heads' lanes zeroed — they add exact
+      zeros to q.k) and its accumulator is window-wide, the other
+      heads' lanes of ``p @ v`` riding along unread until the final
+      write picks each head's own lanes.  The MXU does (128/D)-fold
+      redundant work on a 16-row page, far under its limit; the page
+      step itself has no select and no operand narrower than a tile.
 
     The lane early-out keys on the lane's LONGEST row (``gl_ref``); rows
     shorter than that mask the tail per row.  A row fully masked on an
@@ -229,17 +256,34 @@ def _ragged_body(*refs, scale, page_size, num_pages_grid, heads,
     ks_ref, vs_ref = (next(it), next(it)) if quantized else (None, None)
     o_ref = next(it)
     lse_ref = next(it) if stats else None
-    acc_sc, m_sc, l_sc = (next(it) for _ in range(3))
-    k_stage, v_stage = (next(it), next(it)) if staged else (None, None)
+    q_sc, acc_sc, m_sc, l_sc = (next(it) for _ in range(4))
 
     g = pl.program_id(0)
     i = pl.program_id(1)
+    D = head_dim
+    Qp = q_sc.shape[1]
+    groups = _lane_groups(heads, D)
+
+    def own_lanes(lo, hi):
+        """Per head of the window [lo, hi): (head, the [Qp, hi-lo] mask
+        of its own lanes — None where the window is one head)."""
+        first = lo // D
+        if hi - lo == D:
+            return [(first, None)]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Qp, hi - lo), 1)
+        return [(first + j, (lane >= j * D) & (lane < (j + 1) * D))
+                for j in range((hi - lo) // D)]
 
     @pl.when(i == 0)
     def _init():
         acc_sc[:] = jnp.zeros_like(acc_sc)
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
+        for lo, hi in groups:
+            qw = q_ref[0, :, lo:hi].astype(jnp.float32) * scale
+            for h, own in own_lanes(lo, hi):
+                q_sc[h, :, :hi - lo] = (
+                    qw if own is None else jnp.where(own, qw, 0.0))
 
     # ragged early-out: pages entirely past the lane's longest row (and,
     # in the stats form, pages this shard does not own) do no work
@@ -250,112 +294,102 @@ def _ragged_body(*refs, scale, page_size, num_pages_grid, heads,
     @pl.when(live)
     def _step():
         rl = rl_ref[0]                                    # [Qp, 1] int32
-        Qp = rl.shape[0]
-        _, P, Hq, Dq = k_ref.shape
         pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (Qp, P), 1)
+            jnp.int32, (Qp, page_size), 1)
         valid = pos < rl                                  # [Qp, P]
-        if staged:
-            k_stage[:] = k_ref[0].astype(jnp.float32)
-            v_stage[:] = v_ref[0].astype(jnp.float32)
-            k2 = k_stage.reshape(P * Hq, Dq)
-            v2 = v_stage.reshape(P * Hq, Dq)
-        else:
-            k2 = k_ref.at[0].reshape(P * Hq, Dq)
-            v2 = v_ref.at[0].reshape(P * Hq, Dq)
         if quantized:
-            ks_row = ks_ref[0]                            # [1, Hq] f32
+            ks_row = ks_ref[0]                            # [1, H] f32
             vs_row = vs_ref[0]
-        for h in range(heads):
-            q = q_ref[0, h].astype(jnp.float32) * scale   # [Qp, D]
-            k = k2[pl.ds(h, P, stride=Hq), :]             # [P, D] f32
-            v = v2[pl.ds(h, P, stride=Hq), :]
-            if quantized:
-                ks = ks_row[:, h:h + 1]                   # [1, 1]
-                vs = vs_row[:, h:h + 1]
-                if not fused_dequant:
-                    k = k * ks                            # dequant pre-dot
-                    v = v * vs
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if quantized and fused_dequant:
-                s = s * ks                                # dequant K
-            s = jnp.where(valid, s, NEG_INF)              # [Qp, P]
-            m_prev = m_sc[h][:, :1]                       # [Qp, 1]
-            l_prev = l_sc[h][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            ctx = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            if quantized and fused_dequant:
-                ctx = ctx * vs                            # dequant V
-            acc_sc[h] = acc_sc[h] * alpha + ctx
-            m_sc[h] = jnp.broadcast_to(m_new, m_sc.shape[1:])
-            l_sc[h] = jnp.broadcast_to(l_new, l_sc.shape[1:])
+        for lo, hi in groups:
+            W = hi - lo
+            kw = k_ref[0, :, lo:hi].astype(jnp.float32)   # [P, W]
+            vw = v_ref[0, :, lo:hi].astype(jnp.float32)
+            for h in range(lo // D, hi // D):
+                q = q_sc[h, :, :W]                        # [Qp, W]
+                k, v = kw, vw
+                if quantized:
+                    ks = ks_row[:, h:h + 1]               # [1, 1]
+                    vs = vs_row[:, h:h + 1]
+                    if not fused_dequant:
+                        k = k * ks                        # dequant pre-dot
+                        v = v * vs
+                s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                if quantized and fused_dequant:
+                    s = s * ks                            # dequant K
+                s = jnp.where(valid, s, NEG_INF)          # [Qp, P]
+                m_prev = m_sc[h, :, :1]                   # [Qp, 1]
+                l_prev = l_sc[h, :, :1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                ctx = jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [Qp, W]
+                if quantized and fused_dequant:
+                    ctx = ctx * vs                        # dequant V
+                acc_sc[h, :, :W] = acc_sc[h, :, :W] * alpha + ctx
+                # column 0 carries the value (a masked store; filling
+                # the lane tile cost 15% of the kernel on the v5e)
+                m_sc[h, :, :1] = m_new
+                l_sc[h, :, :1] = l_new
 
     @pl.when(i == num_pages_grid - 1)
     def _write():
-        for h in range(heads):
-            # rows with row_len == 0 (padding) have l == 0 -> exact zeros
-            l_cur = l_sc[h][:, :1]
-            l_safe = jnp.maximum(l_cur, 1e-30)
-            o_ref[0, h] = (acc_sc[h] / l_safe).astype(o_ref.dtype)
-            if stats:
-                # a row with NO owned/visible positions keeps l == 0: lse
-                # is NEG_INF so the merge weight exp(lse - M) underflows
-                lse_ref[0, h] = jnp.where(
-                    l_cur > 0, m_sc[h][:, :1] + jnp.log(l_safe), NEG_INF)
+        for lo, hi in groups:
+            out = None
+            for h, own in own_lanes(lo, hi):
+                # rows with row_len == 0 (padding) have l == 0 -> zeros
+                l_cur = l_sc[h, :, :1]
+                l_safe = jnp.maximum(l_cur, 1e-30)
+                o_h = acc_sc[h, :, :hi - lo] / l_safe
+                out = o_h if out is None else jnp.where(own, o_h, out)
+                if stats:
+                    # a row with NO owned/visible positions keeps l == 0:
+                    # lse is NEG_INF so the merge weight exp(lse - M)
+                    # underflows
+                    lse_ref[0, h] = jnp.where(
+                        l_cur > 0, m_sc[h, :, :1] + jnp.log(l_safe),
+                        NEG_INF)
+            o_ref[0, :, lo:hi] = out.astype(o_ref.dtype)
 
 
 def _ragged_call(q, k_pages, v_pages, page_tables, row_lens, page_ok,
-                 k_scales, v_scales, *, interpret, head_align, q_align,
+                 k_scales, v_scales, *, interpret, q_align,
                  fused_dequant):
-    """Pad, lay out and launch ``_ragged_body``; returns ``(out, lse)``
-    with ``lse`` None unless ``page_ok`` selects the stats form."""
+    """Lay out and launch ``_ragged_body`` on the pools AS STORED
+    (``[N, P, H*D]``: the page block is a whole tile, nothing of pool
+    size is padded, transposed or copied); returns ``(out, lse)`` with
+    ``lse`` None unless ``page_ok`` selects the stats form."""
     G, Qb, H, D = q.shape
+    HD = H * D
+    if k_pages.shape[2:] != (HD,) or v_pages.shape != k_pages.shape:
+        raise ValueError(
+            f"KV pools must be stored [pages, page_size, heads*head_dim] "
+            f"= [N, P, {H * D}] for q {q.shape}; got {k_pages.shape} / "
+            f"{v_pages.shape}")
     page_size = k_pages.shape[1]
     max_pages = page_tables.shape[1]
     quantized = k_pages.dtype == jnp.int8
     stats = page_ok is not None
     if quantized and (k_scales is None or v_scales is None):
         raise ValueError("int8 KV pages require k_scales/v_scales")
-    # the softmax temperature comes from the REAL head_dim — computed
-    # before any tile padding so the padded kernel is numerically
-    # identical to the unpadded one (zero-padded D lanes add 0 to q·k)
     scale = 1.0 / math.sqrt(D)
     row_lens = row_lens.astype(jnp.int32)
 
-    # pad the query-row dim to the contract floor (padded rows carry
-    # row_len 0 and are sliced off); mosaic wants the pages' trailing
-    # (H, D) tile-aligned — pad unconditionally so the CPU interpret
-    # tests exercise the exact same padded path as TPU
+    # q rides in the pools' own fused-row layout (the projection's
+    # [rows, hidden] output, viewed per lane); only the query-row dim is
+    # padded, to the contract floor — padded rows carry row_len 0 and
+    # are sliced off
     Qp = -(-Qb // q_align) * q_align
-    Hp = -(-H // head_align) * head_align
-    Dp = _LANE if D <= _LANE else -(-D // _LANE) * _LANE
+    q = q.reshape(G, Qb, HD)
     if Qp != Qb:
-        q = jnp.pad(q, ((0, 0), (0, Qp - Qb), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, Qp - Qb), (0, 0)))
         row_lens = jnp.pad(row_lens, ((0, 0), (0, Qp - Qb)))
-    if Hp != H or Dp != D:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        k_pages = jnp.pad(k_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        v_pages = jnp.pad(v_pages,
-                          ((0, 0), (0, 0), (0, Hp - H), (0, Dp - D)))
-        if quantized:
-            # padded heads are never visited (the body loops the REAL
-            # head count); 1.0 keeps the rows finite all the same
-            k_scales = jnp.pad(k_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-            v_scales = jnp.pad(v_scales, ((0, 0), (0, Hp - H)),
-                               constant_values=1.0)
-    # head-major query/output: ``ref[0, h]`` is a whole [Qp, D] tile
-    q = jnp.swapaxes(q, 1, 2)                             # [G, Hp, Qp, Dp]
     # the lane's page early-out keys on its longest row
     group_lens = jnp.max(row_lens, axis=1)
-    # strided slab loads need 32-bit data: other pools are staged as f32
-    staged = k_pages.dtype != jnp.float32
 
     def lane(*tail):
         return lambda g, i, *prefetch: (g,) + tail
@@ -365,42 +399,43 @@ def _ragged_call(q, k_pages, v_pages, page_tables, row_lens, page_ok,
 
     # every trailing-dims pair below is (8k, 128k) or the whole array
     # extent, the rule the TPU lowering enforces: row_lens rides as
-    # [G, Qp, 1], the scale rows as [N, 1, Hp]
+    # [G, Qp, 1], the scale rows as [N, 1, H]
     in_specs = [
         pl.BlockSpec((1, Qp, 1), lane(0, 0)),
-        pl.BlockSpec((1, Hp, Qp, Dp), lane(0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hp, Dp), page(0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hp, Dp), page(0, 0, 0)),
+        pl.BlockSpec((1, Qp, HD), lane(0, 0)),
+        pl.BlockSpec((1, page_size, HD), page(0, 0)),
+        pl.BlockSpec((1, page_size, HD), page(0, 0)),
     ]
     operands = [row_lens[:, :, None], q, k_pages, v_pages]
     if quantized:
         # the scale rows ride the same page-table index_map as the pages
-        in_specs += [pl.BlockSpec((1, 1, Hp), page(0, 0)),
-                     pl.BlockSpec((1, 1, Hp), page(0, 0))]
+        in_specs += [pl.BlockSpec((1, 1, H), page(0, 0)),
+                     pl.BlockSpec((1, 1, H), page(0, 0))]
         operands += [k_scales.astype(jnp.float32)[:, None, :],
                      v_scales.astype(jnp.float32)[:, None, :]]
-    out_specs = [pl.BlockSpec((1, Hp, Qp, Dp), lane(0, 0, 0))]
-    out_shape = [jax.ShapeDtypeStruct((G, Hp, Qp, Dp), q.dtype)]
+    out_specs = [pl.BlockSpec((1, Qp, HD), lane(0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((G, Qp, HD), q.dtype)]
     if stats:
-        out_specs.append(pl.BlockSpec((1, Hp, Qp, 1), lane(0, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((G, Hp, Qp, 1), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, H, Qp, 1), lane(0, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((G, H, Qp, 1), jnp.float32))
+    # per head: the staged query and the accumulator, one lane window
+    # wide; the running max / denominator, in column 0 of a lane tile
+    W = max(hi - lo for lo, hi in _lane_groups(H, D))
     scratch_shapes = [
-        pltpu.VMEM((Hp, Qp, Dp), jnp.float32),
-        pltpu.VMEM((Hp, Qp, _LANE), jnp.float32),
-        pltpu.VMEM((Hp, Qp, _LANE), jnp.float32),
+        pltpu.VMEM((H, Qp, W), jnp.float32),
+        pltpu.VMEM((H, Qp, W), jnp.float32),
+        pltpu.VMEM((H, Qp, _LANE), jnp.float32),
+        pltpu.VMEM((H, Qp, _LANE), jnp.float32),
     ]
-    if staged:
-        scratch_shapes += [pltpu.VMEM((page_size, Hp, Dp), jnp.float32),
-                           pltpu.VMEM((page_size, Hp, Dp), jnp.float32)]
     prefetch = [page_tables.astype(jnp.int32), group_lens]
     if stats:
         prefetch.append(page_ok.astype(jnp.int32))
 
     outs = pl.pallas_call(
         functools.partial(_ragged_body, scale=scale, page_size=page_size,
-                          num_pages_grid=max_pages, heads=H,
+                          num_pages_grid=max_pages, heads=H, head_dim=D,
                           quantized=quantized, stats=stats,
-                          fused_dequant=bool(fused_dequant), staged=staged),
+                          fused_dequant=bool(fused_dequant)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(G, max_pages),
@@ -411,24 +446,24 @@ def _ragged_call(q, k_pages, v_pages, page_tables, row_lens, page_ok,
         compiler_params=_compiler_params(),
         interpret=_interpret_mode() if interpret is None else interpret,
     )(*prefetch, *operands)
-    out = jnp.swapaxes(outs[0], 1, 2)[:, :Qb, :H, :D]
+    out = outs[0][:, :Qb].reshape(G, Qb, H, D)
     if not stats:
         return out, None
-    return out, jnp.swapaxes(outs[1][..., 0], 1, 2)[:, :Qb, :H]
+    return out, jnp.swapaxes(outs[1][..., 0], 1, 2)[:, :Qb]
 
 
 def ragged_paged_attention_kernel(q, k_pages, v_pages, page_tables,
                                   row_lens, k_scales=None, v_scales=None,
-                                  *, interpret=None, head_align=None,
-                                  q_align=None, fused_dequant=None):
+                                  *, interpret=None, q_align=None,
+                                  fused_dequant=None):
     """The ragged-query Pallas kernel proper (interpret mode off-TPU
     unless forced).
 
     q           [G, Qb, H, D]  Qb query rows per lane (decode lane: row 0
                                real, rest padded; prefill lane: chunk
                                rows; spec-verify lane: K rows)
-    k_pages     [N, P, H, D]   global K page pool
-    v_pages     [N, P, H, D]   global V page pool
+    k_pages     [N, P, H*D]    global K page pool, as stored
+    v_pages     [N, P, H*D]    global V page pool
     page_tables [G, M] int32   ONE page-table row per lane (pad with 0)
     row_lens    [G, Qb] int32  per-ROW causal KV horizon (row's absolute
                                position + 1; 0 = padded/inactive row)
@@ -436,21 +471,18 @@ def ragged_paged_attention_kernel(q, k_pages, v_pages, page_tables,
     v_scales    [N, H] fp32    per-page-per-head V scales
 
     Returns [G, Qb, H, D]; softmax scale 1/sqrt(D) applied internally.
-    ``head_align``/``q_align``/``fused_dequant`` resolve explicit
-    argument > tuning-table hit > contract default.
+    ``q_align``/``fused_dequant`` resolve explicit argument >
+    tuning-table hit > contract default.
     """
     H, D = q.shape[2:]
     quantized = k_pages.dtype == jnp.int8
-    if head_align is None or q_align is None \
-            or (quantized and fused_dequant is None):
-        t_align, t_q, t_fused = _ragged_resolved_dims(H, D, quantized)
-        head_align = t_align if head_align is None else head_align
+    if q_align is None or (quantized and fused_dequant is None):
+        t_q, t_fused = _ragged_resolved_dims(H, D, quantized)
         q_align = t_q if q_align is None else q_align
         fused_dequant = t_fused if fused_dequant is None else fused_dequant
     return _ragged_call(q, k_pages, v_pages, page_tables, row_lens, None,
                         k_scales, v_scales, interpret=interpret,
-                        head_align=head_align, q_align=q_align,
-                        fused_dequant=fused_dequant)[0]
+                        q_align=q_align, fused_dequant=fused_dequant)[0]
 
 
 def ragged_paged_attention_xla(q, k_pages, v_pages, page_tables,
@@ -512,21 +544,17 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, row_lens,
 def ragged_paged_attention_stats_kernel(q, k_pages, v_pages, page_tables,
                                         row_lens, page_ok, k_scales=None,
                                         v_scales=None, *, interpret=None,
-                                        head_align=None, q_align=None,
-                                        fused_dequant=None):
+                                        q_align=None, fused_dequant=None):
     """The stats-form Pallas kernel proper — ``ragged_paged_attention_kernel``
     plus a ``page_ok [G, M]`` ownership mask (third scalar prefetch) and
     an lse output.  Returns ``(o [G, Qb, H, D], lse [G, Qb, H] f32)``."""
-    if head_align is None:
-        head_align = _STATS_HEAD_ALIGN
     if q_align is None:
         q_align = _STATS_Q_ALIGN
     if fused_dequant is None:
         fused_dequant = bool(_RAGGED_FUSED_DEQUANT)
     return _ragged_call(q, k_pages, v_pages, page_tables, row_lens,
                         page_ok, k_scales, v_scales, interpret=interpret,
-                        head_align=head_align, q_align=q_align,
-                        fused_dequant=fused_dequant)
+                        q_align=q_align, fused_dequant=fused_dequant)
 
 
 def ragged_paged_attention_stats_xla(q, k_pages, v_pages, page_tables,

@@ -45,6 +45,7 @@ Execution model
 """
 from __future__ import annotations
 
+import re
 import time
 import weakref
 from collections import deque
@@ -71,7 +72,40 @@ from .metrics import ServingMetrics
 from .resilience import EngineSnapshot
 from .scheduler import Request, Scheduler, Sequence
 
-__all__ = ["ServingEngine", "create_serving_engine"]
+__all__ = ["ServingEngine", "create_serving_engine", "whole_pool_relayouts",
+           "aliased_arguments"]
+
+
+def whole_pool_relayouts(program_text: str, num_pages: int,
+                         page_size: int) -> List[str]:
+    """The ``pad`` / ``copy`` / ``transpose`` instructions of a step
+    program whose RESULT is pool-shaped (leads with ``[num_pages,
+    page_size, ...]``; the int8 ``[num_pages, H]`` scale rows are not) —
+    i.e. that rewrite a whole KV pool — as ``"<op> <result type>"``
+    strings.  Reads both texts a ``jax.stages`` object gives:
+    ``Lowered.as_text()`` (StableHLO: what the program's own code asks
+    for) and ``Compiled.as_text()`` (optimised HLO: what the compiler
+    made of it, fusion bodies included).  The pools are stored in the
+    layout the ragged kernel reads so that this list is EMPTY for every
+    step program (ISSUE 26: such ops were 55% of the serve step on the
+    v5e); ``chip_smoke.py``, tests/test_serving_ragged.py and
+    tests/test_pallas_tpu_lowering.py hold the programs to it."""
+    n, p = int(num_pages), int(page_size)
+    hlo = re.findall(
+        rf"= (\w+\[{n},{p},[\d,]+\])\S* (pad|copy|copy-start|transpose)\(",
+        program_text)
+    mlir = re.findall(
+        rf"stablehlo\.(pad|transpose)\b[^\n]*-> (tensor<{n}x{p}x\w+>)",
+        program_text)
+    return [f"{op} {shape}" for shape, op in hlo] \
+        + [f"{op} {shape}" for op, shape in mlir]
+
+
+def aliased_arguments(compiled_text: str) -> int:
+    """How many arguments of a compiled program (``Compiled.as_text()``)
+    are aliased to an output — updated in place on their donated buffer.
+    A step program must alias every KV pool (and int8 scale array)."""
+    return compiled_text.split("\n", 1)[0].count("-alias)")
 
 
 # --- shared compiled-program bundles -----------------------------------------
@@ -238,7 +272,9 @@ def _shared_programs(model, *, page_size: int, pages_per_seq: int,
                                             donate_argnums=(0,))
 
     # --- resilience: snapshot gather / restore scatter ---------------
-    # page payloads move as [R, P, H, D] blocks per layer/side; rows
+    # page payloads move as [R, P, H*D] blocks per layer/side — whole
+    # pages in the pools' stored layout, so gather/put/cow are row
+    # copies that never relayout a pool; rows
     # are pow2-padded with the trash page 0 so the trace set stays
     # {pow2} (padding writes zeros into the trash page — harmless by
     # the trash-page convention)
@@ -1099,11 +1135,15 @@ class ServingEngine:
                 # abs-max scales — the documented contract).  The pinned
                 # kv_cache reference fns ARE the quantization contract —
                 # snapshot/restore reuse them so the math lives once.
+                # (the reference fns speak [P, H, D]; a host reshape of
+                # the stored [P, H*D] page is a free view)
                 for side in ("k", "v"):
                     for q, s in zip(got[side], got[f"{side}_scale"]):
+                        q, s = np.asarray(q), np.asarray(s)
+                        P, H = q.shape[1], s.shape[1]
                         pages[side].append(np.stack(
-                            [dequantize_kv_page(np.asarray(q[i]),
-                                                np.asarray(s[i]))
+                            [dequantize_kv_page(q[i].reshape(P, H, -1),
+                                                s[i]).reshape(P, -1)
                              for i in range(R)]))
             else:
                 for side in ("k", "v"):
@@ -1167,12 +1207,15 @@ class ServingEngine:
             # restored pool's scales then depend only on this
             # sequence's content, preserving the dynamic mode's
             # page-reuse-independence invariant
+            H = int(self._kv["k_scale"][0].shape[1])
             for side in ("k", "v"):
                 qs, ss = [], []
-                for page_fp in snap.pages[side]:        # [R, P, H, D]
-                    pairs = [quantize_kv_page(page_fp[i])
+                for page_fp in snap.pages[side]:        # [R, P, H*D]
+                    P = page_fp.shape[1]
+                    pairs = [quantize_kv_page(page_fp[i].reshape(P, H, -1))
                              for i in range(len(page_fp))]
-                    qs.append(np.stack([q for q, _ in pairs]))
+                    qs.append(np.stack([q.reshape(P, -1)
+                                        for q, _ in pairs]))
                     ss.append(np.stack([s for _, s in pairs]
                                        ).astype(np.float32))
                 payload[side] = qs
@@ -1216,7 +1259,7 @@ class ServingEngine:
     # demote window / promote_for), never in steady decode.
     def _tier_gather(self, page_ids: List[int]) -> List[dict]:
         """D2H: one payload dict per page, in ``page_ids`` order —
-        per-layer [P, H, D] k/v arrays plus [H] scale rows in
+        per-layer [P, H*D] k/v arrays plus [H] scale rows in
         int8_static mode (the pool's own dtypes, so a restore is
         bit-exact)."""
         rows = np.asarray(page_ids, np.int32)
@@ -2335,6 +2378,25 @@ class ServingEngine:
         the streaming-server consumption path that keeps ``outputs``
         bounded."""
         return self.outputs.pop(request_id, None)
+
+    def lower_ragged_step(self, rows: int, lanes: Optional[int] = None):
+        """``jax.stages.Lowered`` of the unified step program at ``lanes``
+        (default: the largest lane bucket) x ``rows`` query rows against
+        this engine's own pools — what ``step()`` would dispatch, without
+        running it.  For inspecting the program (``whole_pool_relayouts``,
+        ``compile().memory_analysis()``); never on the serving path."""
+        B = int(lanes if lanes is not None
+                else self.scheduler.bucket_sizes[-1])
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        kv = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding), self._kv)
+        return self._ragged_jit.lower(
+            i32(B), i32(B), i32(B, self.pages_per_seq), i32(B, rows),
+            i32(B, rows), i32(B, rows), i32(B), kv)
 
     def kv_cache_bytes(self) -> int:
         """Actual device bytes of the KV page pools, scales included —

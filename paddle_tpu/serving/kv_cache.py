@@ -1,8 +1,10 @@
 """Block-paged KV-cache manager (host side).
 
 vLLM-style paging re-cut for the TPU execution model: the *device* side
-is a pair of global page pools per layer ([num_pages, page_size, H, D]
-jax arrays, owned by the engine and threaded functionally through the
+is a pair of global page pools per layer ([num_pages, page_size, H*D]
+jax arrays — heads and head_dim fused in one row, the layout the ragged
+kernel's page block reads, so a pool's stored bytes ARE its logical
+bytes — owned by the engine and threaded functionally through the
 jitted decode step); this module owns the *host* bookkeeping — which
 physical page belongs to which sequence — as plain python/numpy so
 allocation never touches the device or triggers a retrace.
@@ -52,9 +54,10 @@ always holds (the leak invariant tests pin).
 
 Quantized page layout (the int8 serving path)
 ---------------------------------------------
-With ``kv_cache_dtype="int8"`` the device pools store each [P, H, D]
-page as int8 plus ONE fp32 dequant scale per (page, head) — a [N, H]
-scale array rides next to each [N, P, H, D] pool, so a page costs
+With ``kv_cache_dtype="int8"`` the device pools store each page
+([P, H*D] on device; [P, H, D] to the reference fns below, a free host
+reshape) as int8 plus ONE fp32 dequant scale per (page, head) — a [N, H]
+scale array rides next to each [N, P, H*D] pool, so a page costs
 ``P*H*D + 4*H`` bytes instead of ``2*P*H*D`` (bf16): a ~2x cut in the
 bytes the bytes-bound decode loop streams, and 2x the sequences per HBM
 byte.  ``quantize_kv_page`` / ``dequantize_kv_page`` below are the

@@ -66,7 +66,7 @@ __all__ = ["HostTier", "DiskTier", "PageTransport", "chain_key",
            "payload_nbytes"]
 
 # one payload = ONE page's KV as host numpy arrays, the exact dict the
-# engine's page_gather returns for a single row: {"k": [L x [P,H,D]],
+# engine's page_gather returns for a single row: {"k": [L x [P,H*D]],
 # "v": [...]} plus "k_scale"/"v_scale" [H] rows in int8 modes
 Payload = Dict[str, List[np.ndarray]]
 

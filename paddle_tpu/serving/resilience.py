@@ -53,7 +53,8 @@ class EngineSnapshot:
     state is exactly (a) its consumed tokens, (b) the KV positions
     written so far, and (c) the pages holding them — all enumerable from
     the host page table.  ``pages`` holds, per layer and side, the
-    ``[R, page_size, H, D]`` page payloads covering positions
+    ``[R, page_size, H*D]`` page payloads (the pools' stored layout:
+    heads and head_dim fused in one row) covering positions
     ``[0, pos)``.
 
     KV-mode contract (pinned in tests/test_resilience.py):

@@ -94,7 +94,8 @@ def _make_mm(params, weight_quant):
 
 
 def _quant_write_page(pages, scales, page_idx, slot, val, static_scale):
-    """Scatter one new [N, H, D] K or V slab into int8 pages.
+    """Scatter one new [N, H, D] K or V slab into int8 pages (stored
+    [pages, P, H*D] like every pool: rows of the fused width).
 
     static_scale is the calibrated [H] scale (static mode) or None
     (dynamic mode: grow the written pages' [N, H] scales by abs-max and
@@ -102,22 +103,30 @@ def _quant_write_page(pages, scales, page_idx, slot, val, static_scale):
     (pages', scales').  Duplicate page indices (a prefill chunk writing
     several slots of one page) are safe: the scale update is a
     scatter-MAX and every duplicate computes identical rescaled content.
+    The per-head split below is of the N gathered pages / new rows only,
+    never of the pool.
     """
+    N, H, D = val.shape
     valf = val.astype(jnp.float32)
+
+    def rows(x):                       # quantized [N, H, D] -> pool rows
+        return jnp.clip(jnp.round(x), -_KV_QMAX,
+                        _KV_QMAX).astype(jnp.int8).reshape(N, H * D)
+
     if static_scale is not None:
-        q = jnp.clip(jnp.round(valf / static_scale[None, :, None]),
-                     -_KV_QMAX, _KV_QMAX).astype(jnp.int8)
+        q = rows(valf / static_scale[None, :, None])
         return pages.at[page_idx, slot].set(q), scales
     amax = jnp.max(jnp.abs(valf), axis=-1)                   # [N, H]
     cand = jnp.maximum(amax / _KV_QMAX, 1e-8)
     s_old = scales[page_idx]                                 # [N, H]
     scales = scales.at[page_idx].max(cand)
     s_new = scales[page_idx]
-    old = pages[page_idx].astype(jnp.float32)                # [N, P, H, D]
-    resc = jnp.round(old * (s_old / s_new)[:, None, :, None])
-    pages = pages.at[page_idx].set(resc.astype(jnp.int8))
-    q = jnp.clip(jnp.round(valf / s_new[:, :, None]),
-                 -_KV_QMAX, _KV_QMAX).astype(jnp.int8)
+    old = pages[page_idx].astype(jnp.float32)                # [N, P, H*D]
+    resc = jnp.round(old.reshape(N, -1, H, D)
+                     * (s_old / s_new)[:, None, :, None])
+    pages = pages.at[page_idx].set(
+        resc.astype(jnp.int8).reshape(old.shape))
+    q = rows(valf / s_new[:, :, None])
     return pages.at[page_idx, slot].set(q), scales
 
 
@@ -147,14 +156,15 @@ def _as_layer_scales(kv_scales, L, H):
 #
 #   tp — HEAD sharding.  qkv/fc1 weights are column-sharded by head, so
 #        each chip projects and attends over H/tp heads against its
-#        head-shard of every KV page ([N, P, H/tp, D] locally); the
+#        head-shard of every KV page ([N, P, (H/tp)*D] locally: H/tp
+#        heads are a contiguous slice of the pool's fused row); the
 #        per-head context is reassembled with one tiled all-gather and
 #        out_proj/fc2 run replicated.  Every per-element reduction is
 #        the same dot the single-device core computes, so the tp path
 #        is BITWISE identical to the unsharded core — decode just
 #        streams the pools at tp-chip aggregate HBM bandwidth.
 #   sp — SEQUENCE (page-dim) sharding for long contexts.  The page pool
-#        splits along pages ([N/sp, P, H/tp, D] locally): global page p
+#        splits along pages ([N/sp, P, (H/tp)*D] locally): global page p
 #        lives on shard p // (N/sp) at local row p % (N/sp).  Each shard
 #        runs the ragged kernel's partial-softmax form over the pages it
 #        OWNS (ownership-masked) and the shards exchange running-max /
@@ -211,10 +221,11 @@ class ServingMeshLayout:
         return PartitionSpec()
 
     def page_spec(self):
-        """[num_pages, P, H, D] pool: pages over sp, heads over tp."""
+        """[num_pages, P, H*D] pool: pages over sp, heads over tp (a
+        head shard is a contiguous slice of the fused row)."""
         from jax.sharding import PartitionSpec
 
-        return PartitionSpec(self.sp_axis, None, self.tp_axis, None)
+        return PartitionSpec(self.sp_axis, None, self.tp_axis)
 
     def scale_spec(self):
         """[num_pages, H] int8 dequant scales ride their pool's split."""
@@ -313,7 +324,7 @@ def _make_gpt_paged_sharded_core(model, page_size: int, pages_per_seq: int,
 
         def z():
             dt = jnp.int8 if quant_kv else params["wte.weight"].dtype
-            return put(jnp.zeros((num_pages, page_size, H, D), dt),
+            return put(jnp.zeros((num_pages, page_size, H * D), dt),
                        layout.page_spec())
 
         kv = {"k": [z() for _ in range(L)], "v": [z() for _ in range(L)]}
@@ -381,17 +392,21 @@ def _make_gpt_paged_sharded_core(model, page_size: int, pages_per_seq: int,
                 h = _ln(x, lpl(i, "ln1.weight"), lpl(i, "ln1.bias"))
                 q = (mm(h, f"layers.{i}.attn.q_proj.weight")
                      + lpl(i, "attn.q_proj.bias")).reshape(N, H_loc, D)
+                # k1/v1 stay [N, H_loc*D]: the projection's rows ARE the
+                # pool's rows, scattered in place on the donated buffer
                 k1 = (mm(h, f"layers.{i}.attn.k_proj.weight")
-                      + lpl(i, "attn.k_proj.bias")).reshape(N, H_loc, D)
+                      + lpl(i, "attn.k_proj.bias"))
                 v1 = (mm(h, f"layers.{i}.attn.v_proj.weight")
-                      + lpl(i, "attn.v_proj.bias")).reshape(N, H_loc, D)
+                      + lpl(i, "attn.v_proj.bias"))
                 if quant_kv:
                     kc, ksc = _quant_write_page(
                         kv_l["k"][i], kv_l["k_scale"][i], local_idx, slot,
-                        k1, ksc_l[i] if ksc_l else None)
+                        k1.reshape(N, H_loc, D),
+                        ksc_l[i] if ksc_l else None)
                     vc, vsc = _quant_write_page(
                         kv_l["v"][i], kv_l["v_scale"][i], local_idx, slot,
-                        v1, vsc_l[i] if vsc_l else None)
+                        v1.reshape(N, H_loc, D),
+                        vsc_l[i] if vsc_l else None)
                     ksc_out.append(ksc)
                     vsc_out.append(vsc)
                     scales = (ksc, vsc)
@@ -636,9 +651,12 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
         # one DISTINCT buffer per layer/side: the engine donates the
         # pools to the jitted step, and XLA rejects donating one buffer
         # twice (a shared zeros array would alias all 2L entries)
+        # stored [pages, P, H*D]: the layout the ragged kernel's page
+        # block reads and the step's scatter writes — tile-exact for
+        # every (H, D), so no program pads, transposes or copies a pool
         def z():
             dt = jnp.int8 if quant_kv else wte.dtype
-            return jnp.zeros((num_pages, page_size, H, D), dt)
+            return jnp.zeros((num_pages, page_size, H * D), dt)
 
         kv = {"k": [z() for _ in range(L)], "v": [z() for _ in range(L)]}
         if quant_kv:
@@ -693,17 +711,19 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
             h = _ln(x, lp(i, "ln1.weight"), lp(i, "ln1.bias"))
             q = (mm(h, f"layers.{i}.attn.q_proj.weight")
                  + lp(i, "attn.q_proj.bias")).reshape(N, H, D)
+            # k1/v1 stay [N, H*D]: the projection's rows ARE the pool's
+            # rows, scattered in place on the donated buffer
             k1 = (mm(h, f"layers.{i}.attn.k_proj.weight")
-                  + lp(i, "attn.k_proj.bias")).reshape(N, H, D)
+                  + lp(i, "attn.k_proj.bias"))
             v1 = (mm(h, f"layers.{i}.attn.v_proj.weight")
-                  + lp(i, "attn.v_proj.bias")).reshape(N, H, D)
+                  + lp(i, "attn.v_proj.bias"))
             if quant_kv:
                 kc, ksc = _quant_write_page(
-                    kv["k"][i], kv["k_scale"][i], page_idx, slot, k1,
-                    k_sc[i] if k_sc else None)
+                    kv["k"][i], kv["k_scale"][i], page_idx, slot,
+                    k1.reshape(N, H, D), k_sc[i] if k_sc else None)
                 vc, vsc = _quant_write_page(
-                    kv["v"][i], kv["v_scale"][i], page_idx, slot, v1,
-                    v_sc[i] if v_sc else None)
+                    kv["v"][i], kv["v_scale"][i], page_idx, slot,
+                    v1.reshape(N, H, D), v_sc[i] if v_sc else None)
                 ksc_out.append(ksc)
                 vsc_out.append(vsc)
                 scales = (ksc, vsc)
@@ -749,7 +769,7 @@ def make_gpt_paged_decode_step(model, page_size: int, pages_per_seq: int, *,
     sequence owns a page-table row of page ids.  Builds
     (step_fn, init_pages):
 
-    ``init_pages(num_pages)`` -> {"k": [L x [N, P, H, D]], "v": ...}
+    ``init_pages(num_pages)`` -> {"k": [L x [N, P, H*D]], "v": ...}
 
     ``step_fn(tokens [B], pos [B], page_tables [B, M], kv)`` ->
     (logits [B, V], kv') — one decode position per call: the new k/v is

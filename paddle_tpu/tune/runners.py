@@ -208,8 +208,7 @@ def _ragged_runner(contract: KernelContract, bucket: Mapping[str, int],
     jit_for = _per_choice(
         contract.name,
         lambda c: lambda a, b, d, e, f: ragged_paged_attention_kernel(
-            a, b, d, e, f, head_align=c["head_align"],
-            q_align=c["q_align"]))
+            a, b, d, e, f, q_align=c["q_align"]))
 
     def run(choice):
         return jit_for(choice)(q, kp, vp, pt, rl)
@@ -229,8 +228,7 @@ def _ragged_int8_runner(contract: KernelContract,
     jit_for = _per_choice(
         contract.name,
         lambda c: lambda a, b, d, e, f, g, h: ragged_paged_attention_kernel(
-            a, b, d, e, f, g, h, head_align=c["head_align"],
-            q_align=c["q_align"],
+            a, b, d, e, f, g, h, q_align=c["q_align"],
             fused_dequant=bool(c["fused_dequant"])))
 
     def run(choice):

@@ -39,8 +39,11 @@ class TestContractRegistry:
         assert (qmm.dim("block_m"), qmm.dim("block_n"),
                 qmm.dim("block_k")) == (128, 128, 128)
         paged = CONTRACTS["paged_attention_ragged"]
-        assert paged.dim("head_align") == 8
+        assert paged.dim("q_align") == 8
         assert paged.dim("lane") == 128
+        # the page block is the pool's stored row: heads x head_dim fused
+        assert paged.dim("kv_width") \
+            == paged.dim("heads") * paged.dim("head_dim")
         # the int8 epilogue axis (ISSUE 14) defaults to the historical
         # fused form — scale multiplies folded AFTER the dots
         assert CONTRACTS["paged_attention_ragged_int8"].dim(
@@ -76,8 +79,10 @@ class TestContractRegistry:
             == CONTRACTS["flash_attention_fwd"].dim("block_q")
         assert flash_attention.DEFAULT_BLOCK_K \
             == CONTRACTS["flash_attention_fwd"].dim("block_k")
-        assert paged_attention._RAGGED_HEAD_ALIGN \
-            == CONTRACTS["paged_attention_ragged"].dim("head_align")
+        assert paged_attention._RAGGED_Q_ALIGN \
+            == CONTRACTS["paged_attention_ragged"].dim("q_align")
+        assert paged_attention._LANE \
+            == CONTRACTS["paged_attention_ragged"].dim("lane")
         assert quantized_matmul._BLOCK_K \
             == CONTRACTS["quantized_matmul"].dim("block_k")
 
@@ -97,6 +102,16 @@ class TestContractRegistry:
                   if b.name == "k_scales")
         assert ks.shape == (1, 1, "heads") \
             and ks.lanes_full and ks.sublane_full
+        # the page block IS the stored page (ISSUE 26): [P, H*D], the
+        # fused row spanning the pool's whole last dim, in every form
+        for name in ("paged_attention_ragged",
+                     "paged_attention_ragged_int8",
+                     "paged_attention_ragged_stats"):
+            for side in ("k_page", "v_page"):
+                blk = next(b for b in CONTRACTS[name].blocks
+                           if b.name == side)
+                assert blk.shape == (1, "page_size", "kv_width") \
+                    and blk.lanes_full, (name, side)
 
 
 class TestValidateRules:
@@ -206,11 +221,12 @@ class TestKernelParityAfterRefactor:
             paged_attention_kernel, paged_attention_xla)
 
         rng = np.random.RandomState(1)
-        # H=3, D=20: exercises BOTH contract-driven pads (heads -> 8,
-        # head_dim -> 128)
+        # H=3, D=20: a fused row (60 lanes) that is no multiple of
+        # anything — nothing of the pool is padded, only the one query
+        # row (-> q_align)
         q = jnp.asarray(rng.randn(2, 3, 20).astype(np.float32))
-        kp = jnp.asarray(rng.randn(6, 4, 3, 20).astype(np.float32))
-        vp = jnp.asarray(rng.randn(6, 4, 3, 20).astype(np.float32))
+        kp = jnp.asarray(rng.randn(6, 4, 3 * 20).astype(np.float32))
+        vp = jnp.asarray(rng.randn(6, 4, 3 * 20).astype(np.float32))
         pt = jnp.asarray(np.array([[1, 2, 3], [4, 5, 0]], np.int32))
         sl = jnp.asarray(np.array([11, 6], np.int32))
         out = paged_attention_kernel(q, kp, vp, pt, sl, interpret=True)

@@ -118,3 +118,55 @@ def test_flash_partitions_over_a_v5e_mesh(v5e_topology):
     with pytest.raises(NotImplementedError,
                        match="cannot be automatically partitioned"):
         jax.jit(grads).lower(x, x, x).compile()
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["native", "int8"])
+@pytest.mark.parametrize("H,D", SHAPES)
+def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype,
+                                                  v5e_topology, monkeypatch):
+    """The whole unified serve step, compiled for the v5e: the pools are
+    stored in the layout the ragged kernel reads (ISSUE 26), so the
+    OPTIMISED program holds no pad, copy or transpose of a whole pool,
+    no temporary of a pool's size, and updates every pool in place.  At
+    (12, 64) the per-head [N, P, 12, 64] layout cost two relayouts and a
+    pad per pool per step — 55% of the step on the chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    import paddle_tpu
+    from paddle_tpu.ops.pallas_ops import paged_attention as pa
+    from paddle_tpu.serving.engine import (aliased_arguments,
+                                           whole_pool_relayouts)
+    from paddle_tpu.text.generation import make_gpt_paged_ragged_step
+    from paddle_tpu.text.models import GPTModel
+
+    # the kernel route, compiled and not interpreted, as on the chip
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PAGED", "1")
+    monkeypatch.setattr(pa, "_interpret_mode", lambda: False)
+    paddle_tpu.seed(0)
+    model = GPTModel(vocab_size=256, hidden_size=H * D, num_layers=1,
+                     num_heads=H, ffn_size=256, max_seq_len=1024,
+                     dropout=0.0)
+    model.eval()
+    pages, lanes, rows, page_size = 769, 8, 64, 16
+    fn, init_pages = make_gpt_paged_ragged_step(
+        model, page_size, 1024 // page_size, kv_cache_dtype=kv_dtype)
+    on_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    kv = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                jax.eval_shape(lambda: init_pages(pages)))
+    pools = jax.tree_util.tree_leaves(kv)
+    assert kv["k"][0].shape == (pages, page_size, H * D)
+    compiled = jax.jit(fn, donate_argnums=(7,)).lower(
+        spec((lanes,)), spec((lanes,)), spec((lanes, 1024 // page_size)),
+        spec((lanes, rows)), spec((lanes, rows)), spec((lanes, rows)),
+        spec((lanes,)), kv).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert whole_pool_relayouts(text, pages, page_size) == []
+    assert aliased_arguments(text) == len(pools)
+    pool_bytes = pages * page_size * H * D * pools[0].dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+

@@ -115,7 +115,8 @@ class TestKVPageRoundTrip:
         want, _ = quantize_kv_page(k1[None],
                                    scales=quant["kv_scales"]["k"][0])
         got = np.asarray(kv["k"][0])[1, 0]           # page 1, slot 0
-        np.testing.assert_array_equal(got, want[0])
+        # the pool stores the slot as one fused [H*D] row
+        np.testing.assert_array_equal(got, want[0].reshape(-1))
 
 
 class TestQuantizedMatmul:
@@ -166,12 +167,16 @@ class TestQuantizedMatmul:
 
 
 class TestPagedAttentionInt8:
-    def test_kernel_dequant_matches_dense_reference(self):
+    # the toy shape, the lane-aligned one, and GPT-2's (two D=64 heads
+    # share each 128-lane window of the stored page row)
+    @pytest.mark.parametrize("H,D", [(2, 16), (8, 128), (12, 64)],
+                             ids=["h2d16", "h8d128", "h12d64"])
+    def test_kernel_dequant_matches_dense_reference(self, H, D):
         from paddle_tpu.ops.pallas_ops.paged_attention import (
             paged_attention_kernel, paged_attention_xla)
 
         rng = np.random.RandomState(0)
-        N, P, H, D, B, M = 9, 4, 2, 16, 3, 6
+        N, P, B, M = 9, 4, 3, 6
         kf = rng.randn(N, P, H, D).astype(np.float32)
         vf = rng.randn(N, P, H, D).astype(np.float32)
         ks = (np.abs(kf).max(axis=(1, 3)) / 127 + 1e-9).astype(np.float32)
@@ -180,6 +185,12 @@ class TestPagedAttentionInt8:
                      127).astype(np.int8)
         vq = np.clip(np.round(vf / vs[:, None, :, None]), -127,
                      127).astype(np.int8)
+        # the dequantized twins, then everything in the stored layout
+        kd = (kq.astype(np.float32) * ks[:, None, :, None]
+              ).reshape(N, P, H * D)
+        vd = (vq.astype(np.float32) * vs[:, None, :, None]
+              ).reshape(N, P, H * D)
+        kq, vq = kq.reshape(N, P, H * D), vq.reshape(N, P, H * D)
         q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
         pt = np.zeros((B, M), np.int32)
         pt[0, :3] = [1, 2, 3]
@@ -188,10 +199,8 @@ class TestPagedAttentionInt8:
         sl = jnp.asarray(np.array([11, 5, 0], np.int32))
         pt = jnp.asarray(pt)
         # reference: attention over the DEQUANTIZED dense pages
-        ref = paged_attention_xla(
-            q, jnp.asarray(kq.astype(np.float32) * ks[:, None, :, None]),
-            jnp.asarray(vq.astype(np.float32) * vs[:, None, :, None]),
-            pt, sl)
+        ref = paged_attention_xla(q, jnp.asarray(kd), jnp.asarray(vd),
+                                  pt, sl)
         out = paged_attention_kernel(q, jnp.asarray(kq), jnp.asarray(vq),
                                      pt, sl, jnp.asarray(ks),
                                      jnp.asarray(vs), interpret=True)
@@ -209,7 +218,7 @@ class TestPagedAttentionInt8:
         from paddle_tpu.ops.pallas_ops.paged_attention import (
             paged_attention_xla)
 
-        z8 = jnp.zeros((2, 4, 2, 8), jnp.int8)
+        z8 = jnp.zeros((2, 4, 2 * 8), jnp.int8)
         with pytest.raises(ValueError, match="require k_scales"):
             paged_attention_xla(jnp.zeros((1, 2, 8)), z8, z8,
                                 jnp.zeros((1, 2), jnp.int32),

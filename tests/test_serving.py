@@ -15,8 +15,9 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
-from paddle_tpu.ops.pallas_ops.paged_attention import (paged_attention_kernel,
-                                                       paged_attention_xla)
+from paddle_tpu.ops.pallas_ops.paged_attention import (
+    paged_attention_kernel, paged_attention_xla,
+    ragged_paged_attention_kernel, ragged_paged_attention_xla)
 from paddle_tpu.serving import PagedKVCache, Request, Scheduler, ServingEngine
 from paddle_tpu.text.generation import generate, make_gpt_paged_decode_step
 from paddle_tpu.text.models import GPTModel
@@ -33,7 +34,8 @@ def gpt(shared_gpt_small):
 
 
 def _dense_ref(q, k_pages, v_pages, page_tables, seq_lens):
-    """Numpy dense attention over the gathered pages (no online softmax)."""
+    """Numpy dense attention over the gathered pages (no online softmax);
+    the pools arrive as stored, [N, P, H*D]."""
     q, kp, vp = map(np.asarray, (q, k_pages, v_pages))
     pt, sl = np.asarray(page_tables), np.asarray(seq_lens)
     B, H, D = q.shape
@@ -56,8 +58,9 @@ class TestPagedAttentionKernel:
     def _case(self, B=4, H=2, D=16, ps=4, M=6, N=16, seed=0):
         rng = np.random.RandomState(seed)
         q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
-        kp = jnp.asarray(rng.randn(N, ps, H, D).astype(np.float32))
-        vp = jnp.asarray(rng.randn(N, ps, H, D).astype(np.float32))
+        # pools as the engine stores them: heads x head_dim in one row
+        kp = jnp.asarray(rng.randn(N, ps, H * D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(N, ps, H * D).astype(np.float32))
         pt = jnp.asarray(rng.randint(1, N, (B, M)).astype(np.int32))
         # ragged lengths: empty, mid-page, page-aligned, full
         sl = jnp.asarray(np.array([0, 7, ps * 2, M * ps], np.int32))[:B]
@@ -88,15 +91,72 @@ class TestPagedAttentionKernel:
             q, kp, vp, pt, jnp.zeros_like(sl)))
         np.testing.assert_array_equal(out, 0.0)
 
-    def test_ops_attention_entry(self):
-        """The Tensor-level route through ops/attention.py."""
+    @pytest.mark.parametrize("per_head", [False, True],
+                             ids=["stored", "per_head"])
+    def test_ops_attention_entry(self, per_head):
+        """The Tensor-level route through ops/attention.py takes the
+        pools as stored ([N, P, H*D]) or split per head ([N, P, H, D])."""
         from paddle_tpu.ops.attention import paged_attention
 
         args = self._case(seed=3)
+        given = list(args)
+        if per_head:
+            H, D = args[0].shape[1:]
+            given[1] = args[1].reshape(*args[1].shape[:2], H, D)
+            given[2] = args[2].reshape(*args[2].shape[:2], H, D)
         out = paged_attention(*(paddle.to_tensor(np.asarray(a))
-                                for a in args))
+                                for a in given))
         np.testing.assert_allclose(out.numpy(), _dense_ref(*args),
                                    rtol=1e-3, atol=1e-3)
+
+    def test_per_head_pool_is_refused_by_the_kernel(self):
+        """The kernels read the STORED layout only: a [N, P, H, D] pool
+        is an error, not a silent whole-pool relayout."""
+        q, kp, vp, pt, sl = self._case()
+        H, D = q.shape[1:]
+        with pytest.raises(ValueError, match="heads\\*head_dim"):
+            paged_attention_kernel(q, kp.reshape(*kp.shape[:2], H, D),
+                                   vp.reshape(*vp.shape[:2], H, D), pt, sl,
+                                   interpret=True)
+
+    # the shapes that matter on the chip: the lane-aligned one, and
+    # GPT-2's (heads no multiple of 8, head_dim half a lane tile: two
+    # heads share each 128-lane window of the page's fused row)
+    @pytest.mark.parametrize("H,D", [(8, 128), (12, 64)],
+                             ids=["h8d128", "h12d64"])
+    @pytest.mark.parametrize("form", ["decode", "mixed"])
+    def test_kernel_matches_xla_at_model_shapes(self, H, D, form):
+        rng = np.random.RandomState(5)
+        N, ps, M = 24, 16, 8
+        kp = jnp.asarray(rng.randn(N, ps, H * D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(N, ps, H * D).astype(np.float32))
+        if form == "decode":
+            B = 4
+            q = jnp.asarray(rng.randn(B, H, D).astype(np.float32) * 0.5)
+            pt = jnp.asarray(rng.randint(1, N, (B, M)).astype(np.int32))
+            sl = jnp.asarray(np.array([0, 7, 2 * ps, M * ps], np.int32))
+            out = paged_attention_kernel(q, kp, vp, pt, sl, interpret=True)
+            ref = paged_attention_xla(q, kp, vp, pt, sl)
+        else:
+            # a mixed step: two decode lanes (one live row each) beside
+            # two 64-row prefill chunks, the second with a ragged tail
+            # (37 real rows, the rest padding with row_len 0)
+            G, Qb = 4, 64
+            q = jnp.asarray(rng.randn(G, Qb, H, D).astype(np.float32)
+                            * 0.5)
+            pt = jnp.asarray(rng.randint(1, N, (G, M)).astype(np.int32))
+            rl = np.zeros((G, Qb), np.int32)
+            rl[0, 0] = 101
+            rl[1, 0] = 16
+            rl[2, :] = np.arange(33, 33 + Qb)
+            rl[3, :37] = np.arange(1, 38)
+            rl = jnp.asarray(rl)
+            out = ragged_paged_attention_kernel(q, kp, vp, pt, rl,
+                                                interpret=True)
+            ref = ragged_paged_attention_xla(q, kp, vp, pt, rl)
+            assert np.abs(np.asarray(out)[3, 37:]).max() == 0.0
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
 
 
 class TestPagedKVCache:

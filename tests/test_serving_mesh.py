@@ -383,24 +383,25 @@ class TestRouterMeshSize:
 # stats-form kernel parity (the sp shard's attention primitive)
 # =============================================================================
 class TestStatsKernelParity:
-    def _case(self, rng, quantized):
+    def _case(self, rng, quantized, H=3, D=20):
         import jax.numpy as jnp
 
-        G, Qb, H, D, N, P, M = 2, 2, 3, 20, 6, 4, 3
+        # pools as a shard stores them: [pages, P, H*D] at its local H
+        G, Qb, N, P, M = 2, 2, 6, 4, 3
         q = jnp.asarray(rng.randn(G, Qb, H, D).astype(np.float32))
         if quantized:
             kp = jnp.asarray(
-                rng.randint(-127, 128, (N, P, H, D)).astype(np.int8))
+                rng.randint(-127, 128, (N, P, H * D)).astype(np.int8))
             vp = jnp.asarray(
-                rng.randint(-127, 128, (N, P, H, D)).astype(np.int8))
+                rng.randint(-127, 128, (N, P, H * D)).astype(np.int8))
             # per-page-per-head scale rows, [N, H] fp32
             ks = jnp.asarray((rng.rand(N, H) * 0.05 + 1e-3
                               ).astype(np.float32))
             vs = jnp.asarray((rng.rand(N, H) * 0.05 + 1e-3
                               ).astype(np.float32))
         else:
-            kp = jnp.asarray(rng.randn(N, P, H, D).astype(np.float32))
-            vp = jnp.asarray(rng.randn(N, P, H, D).astype(np.float32))
+            kp = jnp.asarray(rng.randn(N, P, H * D).astype(np.float32))
+            vp = jnp.asarray(rng.randn(N, P, H * D).astype(np.float32))
             ks = vs = None
         pt = jnp.asarray(np.array([[1, 2, 3], [4, 5, 0]], np.int32))
         row_lens = jnp.asarray(
@@ -411,15 +412,19 @@ class TestStatsKernelParity:
         page_ok = jnp.asarray(np.array([[1, 1, 0], [1, 0, 0]], np.int32))
         return q, kp, vp, pt, row_lens, page_ok, ks, vs
 
+    # heads x head_dim: a row that is no multiple of anything, the
+    # lane-aligned shape, and GPT-2's (12 x 64; its tp2 shard is 6 x 64)
+    @pytest.mark.parametrize("H,D", [(3, 20), (8, 128), (12, 64), (6, 64)],
+                             ids=["h3d20", "h8d128", "h12d64", "h6d64"])
     @pytest.mark.parametrize("quantized", [False, True],
                              ids=["f32", "int8"])
-    def test_kernel_matches_xla_reference(self, quantized):
+    def test_kernel_matches_xla_reference(self, quantized, H, D):
         from paddle_tpu.ops.pallas_ops.paged_attention import (
             ragged_paged_attention_stats_kernel,
             ragged_paged_attention_stats_xla)
 
         rng = np.random.RandomState(12)
-        q, kp, vp, pt, rl, ok, ks, vs = self._case(rng, quantized)
+        q, kp, vp, pt, rl, ok, ks, vs = self._case(rng, quantized, H, D)
         o, lse = ragged_paged_attention_stats_kernel(
             q, kp, vp, pt, rl, ok, ks, vs, interpret=True)
         ro, rlse = ragged_paged_attention_stats_xla(

@@ -208,3 +208,77 @@ class TestKnobAndAccounting:
         assert stat_registry.get("serving.prefill_chunks").get() - c0 == 2
         assert stat_registry.get(
             "serving.ragged.prefill_rows").get() - p0 == 8
+
+
+class TestPoolLayout:
+    """The KV pools are stored in the layout the ragged kernel reads
+    (ISSUE 26): the step program may not pad, copy or transpose a whole
+    pool, and must update every pool in place on its donated buffer.  On
+    the v5e those relayouts were 55% of the serve step; this holds the
+    program to it from the CPU (``chip_smoke.py`` asks the chip's
+    compiler the same question)."""
+
+    PAGES = 37          # a prime no other dim of the program shares
+
+    @pytest.fixture(scope="class")
+    def odd_gpt(self):
+        # heads no multiple of 8, head_dim (8) a sixteenth of a lane
+        # tile: the shape class whose pools the old layout padded
+        import paddle_tpu
+        from paddle_tpu.text.models import GPTModel
+
+        paddle_tpu.seed(5)
+        m = GPTModel(vocab_size=VOCAB, hidden_size=24, num_layers=2,
+                     num_heads=3, ffn_size=48, max_seq_len=64, dropout=0.0)
+        m.eval()
+        return m
+
+    def test_relayout_finder_reads_both_texts(self):
+        from paddle_tpu.serving.engine import whole_pool_relayouts
+
+        hlo = ("%pad.2 = f32[37,16,16,128]{3,2,1,0:T(8,128)} pad(%b, %c)\n"
+               "%copy.9 = f32[37,16,12,64]{0,3,2,1:T(8,128)} copy(%p)\n"
+               "%copy.1 = f32[48,64]{1,0} copy(%q)\n"
+               "%copy.4 = f32[37,16]{1,0} copy(%scale_rows)\n"
+               "%fusion.3 = f32[37,16,768]{2,1,0} fusion(%p, %u)\n")
+        assert whole_pool_relayouts(hlo, 37, 16) == [
+            "pad f32[37,16,16,128]", "copy f32[37,16,12,64]"]
+        mlir = ("%5 = stablehlo.pad %arg7, %cst, low = [0, 0, 0, 0] : "
+                "(tensor<37x4x3x8xf32>, tensor<f32>) -> "
+                "tensor<37x4x8x128xf32>\n"
+                "%6 = stablehlo.pad %1, %cst : (tensor<4x5xf32>, "
+                "tensor<f32>) -> tensor<4x8xf32>\n")
+        assert whole_pool_relayouts(mlir, 37, 4) == [
+            "pad tensor<37x4x8x128xf32>"]
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                             ids=["native", "int8"])
+    @pytest.mark.parametrize("rows", [1, 4], ids=["decode", "mixed"])
+    def test_step_program_never_relayouts_a_pool(self, odd_gpt, kv_dtype,
+                                                 rows, monkeypatch):
+        import warnings
+
+        from paddle_tpu.serving.engine import (aliased_arguments,
+                                               whole_pool_relayouts)
+
+        # the kernel route (what the chip runs), not its XLA twin
+        monkeypatch.setenv("PADDLE_TPU_FORCE_PAGED", "1")
+        eng = ServingEngine(odd_gpt, page_size=4, max_batch_size=2,
+                            num_pages=self.PAGES, prefill_chunk=4,
+                            eos_id=-1, kv_cache_dtype=kv_dtype)
+        pools = jax.tree_util.tree_leaves(eng._kv)
+        assert all(p.shape[0] == self.PAGES for p in pools)
+        # stored rows are heads x head_dim fused: 3 x 8
+        assert eng._kv["k"][0].shape == (self.PAGES, 4, 24)
+        lowered = eng.lower_ragged_step(rows)
+        assert whole_pool_relayouts(lowered.as_text(), self.PAGES, 4) == []
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            compiled = lowered.compile()
+        assert not [str(w.message) for w in caught
+                    if "donated" in str(w.message)]
+        # every pool (and int8 scale array) is updated in place.  (What
+        # the CPU compiler copies around the INTERPRETED kernel says
+        # nothing of the chip: tests/test_pallas_tpu_lowering.py asks the
+        # v5e's compiler for its optimised program.)
+        assert aliased_arguments(compiled.as_text()) == len(pools)

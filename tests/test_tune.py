@@ -401,9 +401,9 @@ class TestRuntimeResolution:
             == (128, 128, 128)
         assert flash_attention._resolved_blocks(1024) == (512, 1024)
         assert paged_attention._ragged_resolved_dims(2, 16, False) \
-            == (8, 8, True)
+            == (8, True)
         assert paged_attention._ragged_resolved_dims(2, 16, True) \
-            == (8, 8, True)
+            == (8, True)
 
     def test_hit_miss_and_counter_accounting(self):
         from paddle_tpu.ops.pallas_ops import quantized_matmul as qmm
@@ -545,21 +545,23 @@ class TestKernelParityPins:
         # divisor the default path picks -> bit-identical end to end
         np.testing.assert_array_equal(out_on, ref_off)
 
-    def test_paged_head_align_tuned_matches_default(self):
+    def test_paged_q_align_tuned_matches_default(self):
+        """The one relayout knob the ragged kernel has left (the pool is
+        read as stored: there is no head padding to tune)."""
         from paddle_tpu.ops.pallas_ops.paged_attention import (
-            paged_attention_kernel)
+            ragged_paged_attention_kernel)
 
         rng = np.random.RandomState(6)
-        q = jnp.asarray(rng.randn(2, 3, 20).astype(np.float32))
-        kp = jnp.asarray(rng.randn(6, 4, 3, 20).astype(np.float32))
-        vp = jnp.asarray(rng.randn(6, 4, 3, 20).astype(np.float32))
+        q = jnp.asarray(rng.randn(2, 5, 3, 20).astype(np.float32))
+        kp = jnp.asarray(rng.randn(6, 4, 3 * 20).astype(np.float32))
+        vp = jnp.asarray(rng.randn(6, 4, 3 * 20).astype(np.float32))
         pt = jnp.asarray(np.array([[1, 2, 3], [4, 5, 0]], np.int32))
-        sl = jnp.asarray(np.array([11, 6], np.int32))
-        ref = np.asarray(paged_attention_kernel(q, kp, vp, pt, sl,
-                                                interpret=True))
-        out = np.asarray(paged_attention_kernel(q, kp, vp, pt, sl,
-                                                interpret=True,
-                                                head_align=16))
+        rl = jnp.asarray(np.array([[7, 8, 9, 10, 11], [6, 0, 0, 0, 0]],
+                                  np.int32))
+        ref = np.asarray(ragged_paged_attention_kernel(
+            q, kp, vp, pt, rl, interpret=True))
+        out = np.asarray(ragged_paged_attention_kernel(
+            q, kp, vp, pt, rl, interpret=True, q_align=16))
         np.testing.assert_array_equal(out, ref)
 
     def test_paged_int8_epilogue_choice_bounded_not_identical(self):
@@ -584,7 +586,8 @@ class TestKernelParityPins:
         q = jnp.asarray(rng.randn(1, H, D).astype(np.float32))
         pt = jnp.asarray(np.array([[1, 2]], np.int32))
         sl = jnp.asarray(np.array([7], np.int32))
-        args = (q, jnp.asarray(kq), jnp.asarray(vq), pt, sl,
+        args = (q, jnp.asarray(kq.reshape(N, P, H * D)),
+                jnp.asarray(vq.reshape(N, P, H * D)), pt, sl,
                 jnp.asarray(ks), jnp.asarray(vs))
         fused = np.asarray(paged_attention_kernel(
             *args, interpret=True, fused_dequant=True))
