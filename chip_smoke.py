@@ -20,7 +20,10 @@ from a seed), and checks what comes out by the repo's own means:
            paddle.Model.prepare/train_batch on one repeated batch.
   kernels  every entry of contracts.CONTRACTS compiled on the chip (never
            interpreted) at (H=12, D=64) and (H=16, D=128), page 16,
-           against its XLA twin.
+           against its XLA twin; then the hybrid models' mixers (PR 28):
+           flash forward and backward at q/k 192, v 128 and the chunked
+           gated delta rule, forward and gradients, against the
+           token-by-token recurrence.
   mesh     only with >= 4 devices visible: the same model and requests
            on ServingEngine(mesh_axes={"tp": 2, "sp": 2}), and the train
            phase's own job (same batch, s2048 b4 bf16, three steps)
@@ -490,9 +493,31 @@ def _err_and_scale(got, want):
 def kernels_phase():
     import jax
 
-    from paddle_tpu.ops.pallas_ops.cases import kernel_cases
+    from paddle_tpu.ops.pallas_ops.cases import kernel_cases, mixer_cases
 
     lines, failed = 0, []
+
+    def line(where, label, kernel, twin, args):
+        head = f"[kernels] {where} {label:<32}"
+        # the twin is the reference: full f32 precision
+        with jax.default_matmul_precision("highest"):
+            want = jax.block_until_ready(jax.jit(twin)(*args))
+        t0 = time.perf_counter()
+        try:
+            got = jax.block_until_ready(jax.jit(kernel)(*args))
+        except Exception as e:  # noqa: BLE001 — verdict line, judged below
+            print(f"{head} REFUSED  {_first_line(e)}", flush=True)
+            failed.append(f"{label} ({where}) refused")
+            return
+        err, scale = _err_and_scale(got, want)
+        ok = err <= KERNEL_RTOL * scale
+        print(f"{head} compiled {'matches' if ok else 'MISMATCH'} XLA "
+              f"twin: max abs err {err:.3e} = {err / scale:.2e} of its "
+              f"scale (tol {KERNEL_RTOL:.2e}); info: compile+run "
+              f"{time.perf_counter() - t0:.2f} s", flush=True)
+        if not ok:
+            failed.append(f"{label} ({where}) mismatch")
+
     for H, D in KERNEL_SHAPES:
         # every CONTRACTS entry, each form it governs (the table refuses
         # to build while a contract has no case); kernels are called
@@ -500,25 +525,12 @@ def kernels_phase():
         # which main() has already required to be "tpu"
         for _, label, kernel, twin, args in kernel_cases(H, D):
             lines += 1
-            head = f"[kernels] H={H:<2} D={D:<3} {label:<32}"
-            # the twin is the reference: full f32 precision
-            with jax.default_matmul_precision("highest"):
-                want = jax.block_until_ready(jax.jit(twin)(*args))
-            t0 = time.perf_counter()
-            try:
-                got = jax.block_until_ready(jax.jit(kernel)(*args))
-            except Exception as e:  # noqa: BLE001 — verdict line, judged below
-                print(f"{head} REFUSED  {_first_line(e)}", flush=True)
-                failed.append(f"{label} ({H},{D}) refused")
-                continue
-            err, scale = _err_and_scale(got, want)
-            ok = err <= KERNEL_RTOL * scale
-            print(f"{head} compiled {'matches' if ok else 'MISMATCH'} XLA "
-                  f"twin: max abs err {err:.3e} = {err / scale:.2e} of its "
-                  f"scale (tol {KERNEL_RTOL:.2e}); info: compile+run "
-                  f"{time.perf_counter() - t0:.2f} s", flush=True)
-            if not ok:
-                failed.append(f"{label} ({H},{D}) mismatch")
+            line(f"H={H:<2} D={D:<3}", label, kernel, twin, args)
+    # the hybrid models' mixers at their own head sizes: flash at q/k
+    # 192, v 128 and the chunked delta rule at 128
+    for _, label, kernel, twin, args in mixer_cases():
+        lines += 1
+        line("mixers    ", label, kernel, twin, args)
     # a kernel may be left refused only while the option selecting it is
     # refused at engine construction; this tree leaves none, so any
     # refusal or mismatch fails the run

@@ -8,7 +8,9 @@ owns the process-global Mesh and the ring_id→axis-name mapping so the
 reference's Group/ring APIs can be reproduced on top.
 
 Canonical axis names: 'dp' (data), 'mp' (tensor/model), 'pp' (pipeline),
-'sp' (sequence/context), 'ep' (expert).
+'sp' (sequence/context), 'ep' (expert).  No exchange rides 'ep' yet:
+nn.SparseExpertShare is told which experts it holds (`experts_held` = start
+and count of the router's published width) and computes their part alone.
 """
 from __future__ import annotations
 

@@ -405,7 +405,27 @@ class StatRegistry:
         self._hists: Dict[str, Histogram] = {}
         self._gauges: Dict[str, LabeledGauge] = {}
         self._windowed: Dict[str, WindowedHistogram] = {}
+        self._held: Dict[str, object] = {}
         self._lock = threading.Lock()
+
+    def hold(self, name: str, array):
+        """Keep a reference to a counter that lives on the device (a
+        buffer a jitted step adds to): a pointer store, no transfer and no
+        sync.  The writer calls it after every step, because a step that
+        donates its state invalidates the array held before."""
+        self._held[name] = array
+
+    def held(self, name: str):
+        """The counter last held under `name` as a numpy array (the one
+        transfer, paid by the reader), or None where there is none or a
+        later step has already taken its buffer."""
+        import numpy as np
+
+        array = self._held.get(name)
+        try:
+            return None if array is None else np.asarray(array)
+        except RuntimeError:        # donated to a step still in flight
+            return None
 
     def get(self, name: str) -> _Stat:
         with self._lock:
@@ -479,6 +499,7 @@ class StatRegistry:
                 g.reset()
             for w in self._windowed.values():
                 w.reset()
+            self._held.clear()
 
 
 stat_registry = StatRegistry()

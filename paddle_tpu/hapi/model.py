@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.monitor import histogram_observe
+from ..framework.monitor import histogram_observe, stat_registry
 from ..framework.random import default_generator, py_random, rng_scope
 from ..jit.functional import functional_call, get_state
 from ..metric.metrics import Metric
@@ -296,12 +296,17 @@ class Model:
                 self._train_step = self._build_train_step(
                     self._anomaly_guard)
                 self._train_step_guarded = self._anomaly_guard
+                # buffers the network counts in, step by step on the device
+                # ({stat name: buffer name}); see _publish_counters
+                self._step_counters = tuple(getattr(
+                    self.network, "step_counters", {}).items())
             key = default_generator.split_key()
             if self._anomaly_guard:
                 prev = self._state
                 with RecordEvent("hapi/train_batch/dispatch"):
                     (self._state, loss, out,
                      gn, ok) = self._train_step(self._state, key, xv, yv)
+                    self._publish_counters()
                 with RecordEvent("hapi/train_batch/fetch_loss"):
                     lossf = float(np.asarray(loss))
                     okb = bool(np.asarray(ok))
@@ -319,6 +324,7 @@ class Model:
             with RecordEvent("hapi/train_batch/dispatch"):
                 self._state, loss, out = self._train_step(
                     self._state, key, xv, yv)
+                self._publish_counters()
             with RecordEvent("hapi/train_batch/metrics"):
                 metrics_out = self._update_metrics(out, yv)
             with RecordEvent("hapi/train_batch/fetch_loss"):
@@ -356,6 +362,13 @@ class Model:
             self._optimizer.clear_grad()
         metrics_out = self._update_metrics(outs[0]._value, yv)
         return [float(np.asarray(loss._value))] + metrics_out
+
+    def _publish_counters(self):
+        """Hand the registry the step's counter buffers as they stand on
+        the device: a pointer each, no transfer, no sync — whoever reads
+        `stat_registry.held(name)` pays for the copy."""
+        for stat, buf in self._step_counters:
+            stat_registry.hold(stat, self._state["buffers"][buf])
 
     def _update_metrics(self, out, yv):
         res = []

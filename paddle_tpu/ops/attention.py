@@ -228,6 +228,7 @@ def _pallas_ok(q, k=None) -> bool:
         # "the headline kernel is effectively bench-only")
         return False
     B, S, H, D = q.shape
+    # v's head size is free (latent attention: q/k 192, v 128)
     if k is not None and tuple(k.shape) != (B, S, H, D):
         return False  # cross-attention with different kv length: XLA path
     if not forced and S < 128:

@@ -16,8 +16,8 @@ import numpy as np
 
 from .contracts import CONTRACTS
 
-__all__ = ["KernelCase", "kernel_cases", "flash_inputs", "paged_inputs",
-           "qmm_inputs"]
+__all__ = ["KernelCase", "kernel_cases", "mixer_cases", "flash_inputs",
+           "paged_inputs", "qmm_inputs"]
 
 
 PAGE_SIZE = 16                      # the serving default
@@ -191,3 +191,63 @@ def kernel_cases(heads, head_dim):
     if missing:
         raise AssertionError(f"contracts without a kernel case: {missing}")
     return cases
+
+
+def mixer_cases(heads=4, seq=FLASH_SEQ + 72):
+    """The sequence mixers of the hybrid linear-attention models, each
+    against its XLA twin, forward and gradients: flash attention whose q/k
+    head size (192) is not its v head size (128), through the public
+    wrapper (padding and all), and the chunked gated delta rule at head
+    size 128 against the token-by-token recurrence, at a length that is no
+    multiple of a chunk and decays from 0.999 down to 0.2 a token.  The
+    delta rule is XLA loops, not a Pallas kernel: no contract governs it."""
+    import jax
+    import jax.numpy as jnp
+
+    from .. import linear_attention as la
+    from ..attention import _sdpa_core
+    from . import flash_attention as fa
+
+    rng = np.random.RandomState(11)
+    arr = lambda *shape: jnp.asarray(
+        rng.standard_normal(shape).astype(np.float32))
+    q, k, v, g = (arr(1, seq, heads, 192), arr(1, seq, heads, 192),
+                  arr(1, seq, heads, 128), arr(1, seq, heads, 128))
+
+    def flash(q, k, v, g):
+        return fa.flash_attention_bshd(q, k, v, causal=True)
+
+    def xla_attn(q, k, v, g):
+        t = lambda a: jnp.swapaxes(a, 1, 2)
+        return t(_sdpa_core(t(q), t(k), t(v), None, 0.0, True, None))
+
+    def grads_of(fn):
+        return lambda *a: jax.grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v, a[3]) * a[3]),
+            argnums=(0, 1, 2))(*a[:3])
+
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    dq, dk, dv = (unit(arr(1, seq, heads, 128)) * 128 ** -0.5,
+                  unit(arr(1, seq, heads, 128)), arr(1, seq, heads, 128))
+    decay = jnp.log(jnp.asarray(rng.uniform(
+        0.2, 0.999, (1, seq, heads, 128)).astype(np.float32)))
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, (1, seq, heads)
+                                   ).astype(np.float32))
+    w = arr(1, seq, heads, 128)
+    delta = (dq, dk, dv, decay, beta)
+
+    def delta_grads(fn):
+        return lambda *a: jax.grad(
+            lambda *b: jnp.sum(fn(*b) * w), argnums=(0, 1, 2, 3, 4))(*a)
+
+    return [
+        KernelCase("flash_attention_fwd", "flash fwd q/k 192, v 128", flash,
+                   xla_attn, (q, k, v, g)),
+        KernelCase("flash_attention_bwd_dkv", "flash bwd q/k 192, v 128",
+                   grads_of(flash), grads_of(xla_attn), (q, k, v, g)),
+        KernelCase("", "delta rule chunked fwd", la.gated_delta_rule_chunked,
+                   la.gated_delta_rule_recurrent, delta),
+        KernelCase("", "delta rule chunked bwd",
+                   delta_grads(la.gated_delta_rule_chunked),
+                   delta_grads(la.gated_delta_rule_recurrent), delta),
+    ]

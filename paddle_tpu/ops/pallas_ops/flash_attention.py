@@ -282,12 +282,13 @@ def _sds(shape, dtype, ref):
 def _flash_fwd_bhsd(q, k, v, mask, seed, scale, causal, dropout_p,
                     block_q, block_k):
     B, H, S, D = q.shape
+    Dv = v.shape[-1]            # the v/o head size may differ from q/k's
     nk = S // block_k
     grid = (B * H, S // block_q, nk)
 
     q3 = q.reshape(B * H, S, D)
     k3 = k.reshape(B * H, S, D)
-    v3 = v.reshape(B * H, S, D)
+    v3 = v.reshape(B * H, S, Dv)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -298,28 +299,28 @@ def _flash_fwd_bhsd(q, k, v, mask, seed, scale, causal, dropout_p,
             pl.BlockSpec(memory_space=pltpu.SMEM),  # seed
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, i, j, h=H: (b // h, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             # TPU mosaic tiling: trailing dims of a block must be (8k, 128k)
             # or equal to the array dims — hence lse carried as [BH, S, 1]
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _sds((B * H, S, D), q.dtype, q3),
+            _sds((B * H, S, Dv), q.dtype, q3),
             _sds((B * H, S, 1), jnp.float32, q3),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret_mode(),
     )(seed, q3, k3, v3, mask)
-    return out.reshape(B, H, S, D), lse
+    return out.reshape(B, H, S, Dv), lse
 
 
 def _flash_dkv_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
@@ -328,11 +329,11 @@ def _flash_dkv_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
     GLOBAL per-row stats of the visiting queries — summing chunk results
     over all visiting q sets gives the exact global dk/dv."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[-1]
     q3 = q.reshape(B * H, Sq, D)
     k3 = k.reshape(B * H, Sk, D)
-    v3 = v.reshape(B * H, Sk, D)
-    g3 = g.reshape(B * H, Sq, D)
+    v3 = v.reshape(B * H, Sk, Dv)
+    g3 = g.reshape(B * H, Sq, Dv)
     nq, nk = Sq // block_q, Sk // block_k
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, nq=nq, scale=scale, causal=causal,
@@ -343,39 +344,39 @@ def _flash_dkv_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, D), lambda b, jj, ii: (b, ii, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, jj, ii: (b, jj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, jj, ii: (b, jj, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, jj, ii: (b, ii, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, jj, ii: (b, jj, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, jj, ii: (b, ii, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, jj, ii: (b, ii, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, jj, ii: (b, ii, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, jj, ii, h=H: (b // h, 0, jj)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, jj, ii: (b, jj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, jj, ii: (b, jj, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, jj, ii: (b, jj, 0)),
         ],
         out_shape=[
             _sds((B * H, Sk, D), k.dtype, k3),
-            _sds((B * H, Sk, D), v.dtype, k3),
+            _sds((B * H, Sk, Dv), v.dtype, k3),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret_mode(),
     )(seed, q3, k3, v3, g3, lse, delta, mask)
-    return dk.reshape(B, H, Sk, D), dv.reshape(B, H, Sk, D)
+    return dk.reshape(B, H, Sk, D), dv.reshape(B, H, Sk, Dv)
 
 
 def _flash_dq_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
                    dropout_p, block_q, block_k):
     """dq for the local queries against one kv chunk (global lse/delta)."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[-1]
     q3 = q.reshape(B * H, Sq, D)
     k3 = k.reshape(B * H, Sk, D)
-    v3 = v.reshape(B * H, Sk, D)
-    g3 = g.reshape(B * H, Sq, D)
+    v3 = v.reshape(B * H, Sk, Dv)
+    g3 = g.reshape(B * H, Sq, Dv)
     nq, nk = Sq // block_q, Sk // block_k
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, nk=nk, scale=scale, causal=causal,
@@ -386,8 +387,8 @@ def _flash_dq_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, i, j, h=H: (b // h, 0, j)),
@@ -507,13 +508,17 @@ def flash_attention_bshd(q, k, v, causal=False, kv_mask=None, dropout_p=0.0,
     form every BERT-style model produces.  dropout_p: attention-prob dropout
     applied in-kernel with deterministic counter-based bits (`seed`).
     Sequence length and head_dim are padded to kernel-friendly shapes
-    internally and sliced back.
+    internally and sliced back.  v's head size may differ from q/k's; the
+    scale is 1/sqrt(q/k head size).
     """
     B, S, H, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / math.sqrt(D)
 
     Sp = -(-S // _LANE) * _LANE
-    Dp = _pad_head_dim(D)
+    # q/k and v/o are padded each to its own width (latent attention: q/k
+    # 192 -> 256, v 128 as it is)
+    Dp, Dvp = _pad_head_dim(D), _pad_head_dim(Dv)
     if kv_mask is None:
         mask = jnp.ones((B, Sp), jnp.float32)
         if Sp != S:
@@ -530,7 +535,8 @@ def flash_attention_bshd(q, k, v, causal=False, kv_mask=None, dropout_p=0.0,
         pad = ((0, 0), (0, Sp - S), (0, 0), (0, Dp - D))
         q = jnp.pad(q, pad)
         k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
+    if Sp != S or Dvp != Dv:
+        v = jnp.pad(v, ((0, 0), (0, Sp - S), (0, 0), (0, Dvp - Dv)))
 
     pref_q, pref_k = (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K) \
         if (block_q and block_k) else _resolved_blocks(Sp)
@@ -553,6 +559,6 @@ def flash_attention_bshd(q, k, v, causal=False, kv_mask=None, dropout_p=0.0,
     if spec is not None:
         core = _shard_over(core, spec, B, H, per_shard_seed=dropout_p > 0.0)
     out = jnp.swapaxes(core(qt, kt, vt, mask, seed), 1, 2)
-    if Sp != S or Dp != D:
-        out = out[:, :S, :, :D]
+    if Sp != S or Dvp != Dv:
+        out = out[:, :S, :, :Dv]
     return out

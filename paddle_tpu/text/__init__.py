@@ -11,4 +11,5 @@ from .models import (  # noqa: F401
     BertForSequenceClassification,
     BertModel,
     GPTModel,
+    KimiLinearModel,
 )

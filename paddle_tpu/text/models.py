@@ -192,3 +192,116 @@ class GPTModel(nn.Layer):
                          end_id=end_id, decode_strategy=decode_strategy,
                          num_beams=num_beams,
                          length_penalty=length_penalty)
+
+
+class HybridDecoderLayer(nn.Layer):
+    """x += mixer(RMSNorm(x)); x += ffn(RMSNorm(x)).  A sparse-expert ffn
+    also hands back its routing counts: forward then returns (x, counts)."""
+
+    def __init__(self, hidden_size, mixer, ffn, epsilon=1e-5):
+        super().__init__()
+        self.input_norm = nn.RMSNorm(hidden_size, epsilon)
+        self.mixer = mixer
+        self.post_norm = nn.RMSNorm(hidden_size, epsilon)
+        self.ffn = ffn
+
+    def forward(self, x):
+        x = x + self.mixer(self.input_norm(x))
+        h = self.ffn(self.post_norm(x))
+        if isinstance(h, tuple):
+            return x + h[0], h[1]
+        return x + h
+
+
+class KimiLinearModel(nn.Layer):
+    """Decoder-only LM of the Kimi-Linear family: layers of different
+    kinds by a per-layer table — `layer_kinds[i]` is "kda" (gated
+    delta-rule linear attention) or "mla" (NoPE latent attention) — a
+    dense SwiGLU FFN in the first `first_dense` layers and one chip's share
+    of a sparse-expert layer in the rest (`experts_held` = (start, count)
+    of `num_experts_published`; nn.SparseExpertShare), RMSNorm, an untied
+    head.  `vocab_size` is the rows held here: a slice of the vocabulary is
+    a smaller vocabulary.  `recompute=True` rematerialises each layer in
+    the backward pass (fleet.recompute) while training.
+
+    The buffer `moe_routed_tokens` [sparse layers, count + 1] adds up, step
+    by step inside the step's own buffers, the assignments routed to each
+    held expert and (last column) to absent ones; hapi publishes it as the
+    counter `moe.routed_tokens` (framework.monitor) without a host sync.
+    It is float32 and never reset: exact to 2**24 a column and in
+    proportion beyond, where an int32 would wrap within a long run (the
+    last column takes ~63,500 a step of 8,192 tokens: 33,800 steps)."""
+
+    step_counters = {"moe.routed_tokens": "moe_routed_tokens"}
+
+    def __init__(self, vocab_size, hidden_size, layer_kinds, num_heads,
+                 kda_head_dim, kv_lora_rank, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, intermediate_size,
+                 moe_intermediate_size, num_experts_published, experts_held,
+                 experts_per_token, routed_scale=1.0, renormalize=True,
+                 first_dense=1, conv_size=4, gate_rank=None, epsilon=1e-5,
+                 recompute=False):
+        super().__init__()
+        from ..utils.profiler import RecordEvent
+
+        kinds = list(layer_kinds)
+        with RecordEvent("text/kimi_linear/build", layers=len(kinds),
+                         kda_layers=kinds.count("kda"),
+                         mla_layers=kinds.count("mla"),
+                         experts_held=int(experts_held[1]),
+                         experts_published=int(num_experts_published)):
+            self.recompute = recompute
+            self.embed_tokens = nn.Embedding(vocab_size, hidden_size)
+            layers = []
+            for i, kind in enumerate(kinds):
+                if kind == "kda":
+                    mixer = nn.KimiDeltaAttention(
+                        hidden_size, num_heads, kda_head_dim, conv_size,
+                        gate_rank, epsilon)
+                elif kind == "mla":
+                    mixer = nn.LatentAttention(
+                        hidden_size, num_heads, kv_lora_rank,
+                        qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                        epsilon)
+                else:
+                    raise ValueError(f"layer kind {kind!r}: kda or mla")
+                ffn = nn.SwiGLU(hidden_size, intermediate_size) \
+                    if i < first_dense else nn.SparseExpertShare(
+                        hidden_size, moe_intermediate_size,
+                        num_experts_published, experts_held,
+                        experts_per_token, routed_scale, renormalize)
+                layers.append(HybridDecoderLayer(hidden_size, mixer, ffn,
+                                                 epsilon))
+            self.layers = nn.LayerList(layers)
+            self.norm = nn.RMSNorm(hidden_size, epsilon)
+            self.lm_head = nn.Linear(hidden_size, vocab_size, bias_attr=False)
+            sparse = max(0, len(kinds) - first_dense)
+            self.register_buffer("moe_routed_tokens", Tensor(np.zeros(
+                (sparse, int(experts_held[1]) + 1), np.float32)),
+                persistable=False)
+
+    def forward(self, input_ids):
+        from ..distributed.fleet.recompute import recompute
+        from ..ops.manipulation import stack
+        from ..utils.profiler import RecordEvent
+
+        B, T = input_ids.shape
+        # under jit this runs once, where the step is traced
+        with RecordEvent("text/kimi_linear/forward", tokens=int(B) * int(T),
+                         layers=len(self.layers)):
+            x = self.embed_tokens(input_ids)
+            counts = []
+            for layer in self.layers:
+                out = recompute(layer, x) \
+                    if self.recompute and self.training else layer(x)
+                if isinstance(out, (tuple, list)):
+                    x = out[0]
+                    counts.append(out[1])
+                else:
+                    x = out
+            if counts:
+                # in place: functional_call reads the buffer object back
+                self.moe_routed_tokens._value = (
+                    self.moe_routed_tokens._value
+                    + stack(counts)._value)
+            return self.lm_head(self.norm(x))

@@ -170,3 +170,27 @@ def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype,
     pool_bytes = pages * page_size * H * D * pools[0].dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
+
+
+@pytest.mark.parametrize("case", range(4), ids=[
+    "flash-fwd-192-128", "flash-bwd-192-128", "delta-rule-fwd",
+    "delta-rule-bwd"])
+def test_the_hybrid_mixers_compile_for_v5e(case, v5e_topology):
+    """PR 28's mixers at their own head sizes, bf16 as the train step
+    runs them: flash with q/k 192 and v 128 (q/k padded to 256 lanes, v
+    not), and the chunked gated delta rule with its ragged sub-blocks and
+    triangular inverse — forward and gradients, compiled for one v5e
+    chip (the case table's `mixer_cases`, which chip_smoke.py runs on the
+    chip against the XLA twins)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_ops.cases import mixer_cases
+
+    on_chip = SingleDeviceSharding(v5e_topology.devices[0])
+    label, fn, args = [(c.label, c.kernel, c.args)
+                       for c in mixer_cases()][case]
+    specs = [jax.ShapeDtypeStruct(a.shape, jnp.bfloat16 if i < 3
+                                  else a.dtype, sharding=on_chip)
+             for i, a in enumerate(args)]
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert ("tpu_custom_call" in text) == label.startswith("flash"), label
