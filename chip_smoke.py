@@ -493,7 +493,8 @@ def _err_and_scale(got, want):
 def kernels_phase():
     import jax
 
-    from paddle_tpu.ops.pallas_ops.cases import kernel_cases, mixer_cases
+    from paddle_tpu.ops.pallas_ops.cases import (kernel_cases, mixer_cases,
+                                                 serve_cell_case)
 
     lines, failed = 0, []
 
@@ -526,6 +527,11 @@ def kernels_phase():
         for _, label, kernel, twin, args in kernel_cases(H, D):
             lines += 1
             line(f"H={H:<2} D={D:<3}", label, kernel, twin, args)
+    # the ragged kernel as the long-prompt serve cell dispatches it: 48
+    # lanes x 64 rows over 64-page tables, 39 of them one-row decode lanes
+    _, label, kernel, twin, args = serve_cell_case()
+    lines += 1
+    line("H=12 D=64 ", label, kernel, twin, args)
     # the hybrid models' mixers at their own head sizes: flash at q/k
     # 192, v 128 and the chunked delta rule at 128
     for _, label, kernel, twin, args in mixer_cases():

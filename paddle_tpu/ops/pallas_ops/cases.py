@@ -16,8 +16,8 @@ import numpy as np
 
 from .contracts import CONTRACTS
 
-__all__ = ["KernelCase", "kernel_cases", "mixer_cases", "flash_inputs",
-           "paged_inputs", "qmm_inputs"]
+__all__ = ["KernelCase", "kernel_cases", "mixer_cases", "serve_cell_case",
+           "flash_inputs", "paged_inputs", "qmm_inputs"]
 
 
 PAGE_SIZE = 16                      # the serving default
@@ -79,6 +79,52 @@ def paged_inputs(H, D, page_size, *, int8, pages=40, rows=16, table=8):
     return (jnp.asarray(q), jnp.asarray(kf.reshape(fused)),
             jnp.asarray(vf.reshape(fused)),
             jnp.asarray(pt), jnp.asarray(rl), jnp.asarray(ok), ks, vs)
+
+
+def serve_cell_case(pages=513):
+    """The ragged kernel as the long-prompt serve cell dispatches it
+    (``serve-longprompt-batch``: one 64-row mixed step of 48 lanes, 64
+    pages of 16 a lane, GPT-2 small's 12 heads of 64): 39 lanes with ONE
+    live row over a context of 512-1,008 positions, the rest
+    chunked-prefill lanes — full 64-row chunks at staggered starts, the
+    last one a 16-row tail chunk.  The pool of ``pages`` pages is a
+    sixth of the cell's (lanes share pages; attention only reads them)."""
+    import jax.numpy as jnp
+
+    from . import paged_attention as pa
+
+    heads, head_dim, lanes, rows, table, decode_lanes = 12, 64, 48, 64, 64, 39
+    rng = np.random.RandomState(29)
+    cap = table * PAGE_SIZE
+    q = rng.standard_normal((lanes, rows, heads, head_dim)
+                            ).astype(np.float32) * 0.5
+    pool = (pages, PAGE_SIZE, heads * head_dim)
+    kp = rng.standard_normal(pool).astype(np.float32)
+    vp = rng.standard_normal(pool).astype(np.float32)
+    pt = rng.randint(1, pages, (lanes, table)).astype(np.int32)
+    rl = np.zeros((lanes, rows), np.int32)
+    rl[:decode_lanes, 0] = rng.randint(cap // 2, cap - PAGE_SIZE + 1,
+                                       decode_lanes)
+    for lane in range(decode_lanes, lanes):
+        live = PAGE_SIZE if lane == lanes - 1 else rows
+        start = rng.randint(0, cap - rows)
+        rl[lane, :live] = start + 1 + np.arange(live)
+    q, kp, vp, pt, rl = map(jnp.asarray, (q, kp, vp, pt, rl))
+
+    def twin(kp, vp):
+        # a lane at a time: the reference gathers every ROW's pages
+        # (3 MB a row here), all 3,072 rows at once would not fit
+        import jax
+
+        return jax.lax.map(
+            lambda lane: pa.ragged_paged_attention_xla(
+                lane[0][None], kp, vp, lane[1][None], lane[2][None])[0],
+            (q, pt, rl))
+
+    return KernelCase(
+        "paged_attention_ragged", "ragged native, serve cell's step",
+        lambda kp, vp: pa.ragged_paged_attention_kernel(
+            q, kp, vp, pt, rl, interpret=False), twin, (kp, vp))
 
 
 def qmm_inputs(M, K, N):

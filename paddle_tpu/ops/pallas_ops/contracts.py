@@ -307,6 +307,18 @@ FLASH_BWD_DQ = KernelContract(
 # body walks the row in ``lane``-wide windows (a head narrower than a
 # lane tile shares its window with its neighbours).
 #
+# One grid step = ``pages_per_step`` such pages of the lane (ISSUE 29;
+# grid ``(groups, page_groups)``, the table padded with page 0 to whole
+# groups): each pool is an operand once per page of the group, every
+# one a ``(1, page_size, kv_width)`` block with the index_map of its
+# slot — declared below as one ``[pages_per_step, page_size, kv_width]``
+# block, which is what VMEM holds.  8 pages of 16 make the score block
+# ``[rows, 128]``, a whole lane tile, and the online-softmax bookkeeping
+# is paid once per group (v5e, the serve cell's shape: 2.44 ms a call at
+# one page a step, 1.28 at 4, 0.85 at 8, 0.84 at 16).  ``live_rows`` is
+# the lane's prefetched live-row extent: rows are computed to the first
+# of (``q_align``, all) that covers it, the rest written as zeros.
+#
 # Every VMEM block below obeys the rule the TPU lowering ENFORCES — the
 # trailing two block dims are (8k, 128k) or span the whole array extent
 # (``lanes_full``/``sublane_full``) — with no waiver: the fused row
@@ -319,22 +331,26 @@ FLASH_BWD_DQ = KernelContract(
 PAGED_RAGGED = KernelContract(
     name="paged_attention_ragged",
     module="paddle_tpu/ops/pallas_ops/paged_attention.py",
-    grid=("groups", "pages_per_seq"),
+    grid=("groups", "page_groups"),
     dims={"page_size": 16, "heads": 8, "head_dim": 128, "kv_width": 1024,
-          "lane": 128, "q_align": 8},
+          "lane": 128, "q_align": 8, "pages_per_step": 8},
     blocks=(
         BlockDecl("page_tables", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
         BlockDecl("group_lens", "in", ("groups",), "int32",
                   memory="smem"),
+        BlockDecl("live_rows", "in", ("groups",), "int32",
+                  memory="smem"),
         BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
                   lanes_full=True),
         BlockDecl("q", "in", (1, "q_align", "kv_width"), "float32",
                   lanes_full=True),
-        BlockDecl("k_page", "in", (1, "page_size", "kv_width"),
-                  "float32", lanes_full=True),
-        BlockDecl("v_page", "in", (1, "page_size", "kv_width"),
-                  "float32", lanes_full=True),
+        BlockDecl("k_pages", "in",
+                  ("pages_per_step", "page_size", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("v_pages", "in",
+                  ("pages_per_step", "page_size", "kv_width"), "float32",
+                  lanes_full=True),
         BlockDecl("o", "out", (1, "q_align", "kv_width"), "float32",
                   lanes_full=True),
         # per head, one lane window wide (head_dim; the lane width where
@@ -353,14 +369,16 @@ PAGED_RAGGED = KernelContract(
     shape_buckets={"head_dim": (128, 256), "heads": (8, 16, 32)},
     # q_align is the padding floor for the per-lane query-row dim —
     # padded rows carry row_len 0 and are sliced off, so the axis is
-    # exactly parity-preserving
-    sweep={"q_align": (8, 16)},
+    # exactly parity-preserving; pages_per_step regroups the online
+    # softmax (the same sums in another order: winners must pass the
+    # sweep's parity gate)
+    sweep={"q_align": (8, 16), "pages_per_step": (4, 8, 16)},
 )
 
 PAGED_RAGGED_INT8 = KernelContract(
     name="paged_attention_ragged_int8",
     module="paddle_tpu/ops/pallas_ops/paged_attention.py",
-    grid=("groups", "pages_per_seq"),
+    grid=("groups", "page_groups"),
     # fused_dequant=1 is the historical epilogue: the per-head scales
     # multiply the LOGITS (K) and the accumulated context (V) after the
     # dots; 0 dequantizes the head's page slab BEFORE the dots.  Both
@@ -368,11 +386,14 @@ PAGED_RAGGED_INT8 = KernelContract(
     # between the VPU epilogue and the MXU operand path, which is
     # exactly the kind of platform-dependent tie the sweep measures.
     dims={"page_size": 16, "heads": 8, "head_dim": 128, "kv_width": 1024,
-          "lane": 128, "q_align": 8, "fused_dequant": 1},
+          "lane": 128, "q_align": 8, "pages_per_step": 8,
+          "fused_dequant": 1},
     blocks=(
         BlockDecl("page_tables", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
         BlockDecl("group_lens", "in", ("groups",), "int32",
+                  memory="smem"),
+        BlockDecl("live_rows", "in", ("groups",), "int32",
                   memory="smem"),
         BlockDecl("row_lens", "in", (1, "q_align", 1), "int32",
                   lanes_full=True),
@@ -382,14 +403,16 @@ PAGED_RAGGED_INT8 = KernelContract(
         # 16 rows, not the int8 floor 32 — the block is the WHOLE page,
         # which the lowering accepts at any extent); each lane window is
         # converted to f32 in-register as it is loaded
-        BlockDecl("k_page", "in", (1, "page_size", "kv_width"), "int8",
+        BlockDecl("k_pages", "in",
+                  ("pages_per_step", "page_size", "kv_width"), "int8",
                   lanes_full=True, sublane_full=True),
-        BlockDecl("v_page", "in", (1, "page_size", "kv_width"), "int8",
+        BlockDecl("v_pages", "in",
+                  ("pages_per_step", "page_size", "kv_width"), "int8",
                   lanes_full=True, sublane_full=True),
-        BlockDecl("k_scales", "in", (1, 1, "heads"), "float32",
-                  lanes_full=True, sublane_full=True),
-        BlockDecl("v_scales", "in", (1, 1, "heads"), "float32",
-                  lanes_full=True, sublane_full=True),
+        BlockDecl("k_scales", "in", ("pages_per_step", 1, "heads"),
+                  "float32", lanes_full=True, sublane_full=True),
+        BlockDecl("v_scales", "in", ("pages_per_step", 1, "heads"),
+                  "float32", lanes_full=True, sublane_full=True),
         BlockDecl("o", "out", (1, "q_align", "kv_width"), "float32",
                   lanes_full=True),
         # per head, one lane window wide (head_dim; the lane width where
@@ -409,8 +432,10 @@ PAGED_RAGGED_INT8 = KernelContract(
     # fused_dequant moves the scale multiply across the dot — NOT
     # bit-exact (rounding points differ), so the non-default choice only
     # survives a sweep run with an explicit tolerance (docs/TUNING.md);
-    # q_align is exactly parity-preserving
-    sweep={"q_align": (8, 16), "fused_dequant": (0, 1)},
+    # q_align is exactly parity-preserving, pages_per_step regroups the
+    # online softmax (parity gate applies)
+    sweep={"q_align": (8, 16), "pages_per_step": (4, 8, 16),
+           "fused_dequant": (0, 1)},
 )
 
 # ===========================================================================
@@ -419,7 +444,7 @@ PAGED_RAGGED_INT8 = KernelContract(
 # on ONE mesh shard: its page pool holds the shard's 1/sp of the pages
 # (and its H/tp head-shard of each — a contiguous slice of the fused
 # row, so the shard's pool is again [pages, page_size, kv_width] at its
-# local width), a third scalar-prefetch operand masks page-table
+# local width), a fourth scalar-prefetch operand masks page-table
 # entries by OWNERSHIP, and alongside the locally-normalized context
 # the kernel emits the online-softmax running stats as lse = m + log(l)
 # — the cross-shard merge (pmax of lse, psum of exp-weighted
@@ -429,13 +454,15 @@ PAGED_RAGGED_INT8 = KernelContract(
 PAGED_RAGGED_STATS = KernelContract(
     name="paged_attention_ragged_stats",
     module="paddle_tpu/ops/pallas_ops/paged_attention.py",
-    grid=("groups", "pages_per_seq"),
+    grid=("groups", "page_groups"),
     dims={"page_size": 16, "heads": 8, "head_dim": 128, "kv_width": 1024,
-          "lane": 128, "q_align": 8},
+          "lane": 128, "q_align": 8, "pages_per_step": 8},
     blocks=(
         BlockDecl("page_tables", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
         BlockDecl("group_lens", "in", ("groups",), "int32",
+                  memory="smem"),
+        BlockDecl("live_rows", "in", ("groups",), "int32",
                   memory="smem"),
         BlockDecl("page_ok", "in", ("groups", "pages_per_seq"),
                   "int32", memory="smem"),
@@ -443,10 +470,12 @@ PAGED_RAGGED_STATS = KernelContract(
                   lanes_full=True),
         BlockDecl("q", "in", (1, "q_align", "kv_width"), "float32",
                   lanes_full=True),
-        BlockDecl("k_page", "in", (1, "page_size", "kv_width"),
-                  "float32", lanes_full=True),
-        BlockDecl("v_page", "in", (1, "page_size", "kv_width"),
-                  "float32", lanes_full=True),
+        BlockDecl("k_pages", "in",
+                  ("pages_per_step", "page_size", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("v_pages", "in",
+                  ("pages_per_step", "page_size", "kv_width"), "float32",
+                  lanes_full=True),
         BlockDecl("o", "out", (1, "q_align", "kv_width"), "float32",
                   lanes_full=True),
         # one lse value per (head, row), carried [.., q_align, 1] like

@@ -810,6 +810,9 @@ class ServingEngine:
         self._ragged_steady: Dict[int, tuple] = {}
         from ..text.generation import RAGGED_NO_LIMIT
         self._ragged_no_limit = RAGGED_NO_LIMIT
+        # the kernel's own row-block rule, for the attn_rows_skipped count
+        from ..ops.pallas_ops.paged_attention import ragged_rows_skipped
+        self._rows_skipped = ragged_rows_skipped
 
     def _dput(self, x):
         """Host→device upload for engine state.  In mesh mode every
@@ -1672,6 +1675,14 @@ class ServingEngine:
                 if last > first:
                     ctx_tokens += last
                     attn_pairs += (first + 1 + last) * (last - first) // 2
+            # rows of the bucket the kernel skips: a lane is computed up
+            # to the row block covering its last live row — a chunk lane
+            # its chunk's rows, an idle lane none, every other lane row 0
+            attn_rows_skipped = (
+                (B - len(chunks) - len(idle)) * self._rows_skipped(1, Q)
+                + len(idle) * self._rows_skipped(0, Q)
+                + sum(self._rows_skipped(c[0].size, Q)
+                      for c in chunks.values()))
             ev.set(chunks=len(chunks), idle=len(idle))
         if self._mesh_layout is not None:
             # chaos site ``serving.shard_sync``: the last host boundary
@@ -1685,7 +1696,8 @@ class ServingEngine:
             self.metrics.on_shard_step()
         with RecordEvent("serving/ragged_step", bucket=B, rows=Q,
                          decode_rows=decode_rows, prefill_rows=prefill_rows,
-                         ctx_tokens=ctx_tokens, attn_pairs=attn_pairs):
+                         ctx_tokens=ctx_tokens, attn_pairs=attn_pairs,
+                         attn_rows_skipped=attn_rows_skipped):
             (_out_rows, out_dec, self._tokens, self._pos,
              self._kv) = self._ragged_jit(
                 self._tokens, self._pos, self._tables, rows_tok,
@@ -1704,7 +1716,7 @@ class ServingEngine:
         self.metrics.on_ragged(
             decode_rows=decode_rows, prefill_rows=prefill_rows, q_bucket=Q,
             rows_computed=B * Q, ctx_tokens=ctx_tokens,
-            attn_pairs=attn_pairs)
+            attn_pairs=attn_pairs, attn_rows_skipped=attn_rows_skipped)
         for sid, plan in done_plans:
             if not plan["count"]:
                 # barrier-only plan (fully-covered prefix hit): the
@@ -2067,7 +2079,9 @@ class ServingEngine:
                 self.metrics.on_ragged(
                     spec_rows=K * len(active), q_bucket=K,
                     rows_computed=bucket * K, ctx_tokens=ctx,
-                    attn_pairs=K * ctx - len(active) * K * (K - 1) // 2)
+                    attn_pairs=K * ctx - len(active) * K * (K - 1) // 2,
+                    attn_rows_skipped=(bucket - len(active))
+                    * self._rows_skipped(0, K))
                 t0 = time.perf_counter()
                 toks = np.ascontiguousarray(              # [K, bucket]
                     np.asarray(jax.device_get(out_rows)).T)

@@ -127,6 +127,10 @@ class ServingMetrics:
                 # positions the lanes read, and (row, key) pairs attended
                 "serving.ragged.rows_computed", "serving.ragged.ctx_tokens",
                 "serving.ragged.attn_pairs",
+                # rows of rows_computed the ragged kernel skips: the
+                # bucket beyond each lane's last live row, rounded up to
+                # the kernel's row block (ISSUE 29)
+                "serving.ragged.attn_rows_skipped",
                 # mesh-sharded serving (ISSUE 19): ragged dispatches that
                 # ran as one mesh program (every step crosses the
                 # tp/sp collectives), and maintenance traffic that had to
@@ -325,7 +329,7 @@ class ServingMetrics:
     def on_ragged(self, *, decode_rows: int = 0, prefill_rows: int = 0,
                   spec_rows: int = 0, q_bucket: int = 0,
                   rows_computed: int = 0, ctx_tokens: int = 0,
-                  attn_pairs: int = 0):
+                  attn_pairs: int = 0, attn_rows_skipped: int = 0):
         """One ``serving.ragged_step`` dispatch's row mix: ``decode_rows``
         lanes advanced one position, ``prefill_rows`` prompt positions
         rode along as chunk rows (instead of serializing ahead of the
@@ -334,12 +338,16 @@ class ServingMetrics:
         query-row bucket Q (gauged — 1 in steady decode).  The step's
         work: ``rows_computed`` = lane bucket x Q, ``ctx_tokens`` = KV
         positions read over the lanes with a row that carries a token,
-        ``attn_pairs`` = position + 1 over every such row."""
+        ``attn_pairs`` = position + 1 over every such row,
+        ``attn_rows_skipped`` = the rows of ``rows_computed`` the ragged
+        kernel's row blocks leave out (past a lane's last live row)."""
         stat_registry.get("serving.ragged.steps").add(1)
         stat_registry.get("serving.ragged.rows_computed").add(
             int(rows_computed))
         stat_registry.get("serving.ragged.ctx_tokens").add(int(ctx_tokens))
         stat_registry.get("serving.ragged.attn_pairs").add(int(attn_pairs))
+        stat_registry.get("serving.ragged.attn_rows_skipped").add(
+            int(attn_rows_skipped))
         if decode_rows:
             stat_registry.get("serving.ragged.decode_rows").add(
                 int(decode_rows))
@@ -509,7 +517,7 @@ class ServingMetrics:
             short: stat_registry.get(f"serving.ragged.{short}").get()
             for short in ("steps", "decode_rows", "prefill_rows",
                           "spec_rows", "row_bucket", "rows_computed",
-                          "ctx_tokens", "attn_pairs")}
+                          "ctx_tokens", "attn_pairs", "attn_rows_skipped")}
         snap["disagg"] = {"shipped_pages": stat_registry.get(
             "serving.disagg.shipped_pages").get()}
         snap["shard"] = {

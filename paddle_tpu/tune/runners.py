@@ -13,9 +13,10 @@ Runners exist for the kernels with a runtime-swappable config:
 ``flash_attention_bwd_dkv`` / ``..._bwd_dq`` (the grad-path pair:
 forward stats are precomputed ONCE at the default blocks, each
 candidate re-tiles only the backward kernel under the sweep's parity
-gate — ISSUE 18), ``paged_attention_ragged`` / ``..._int8`` (query-row
-and head padding floors for the unified serving dispatch, and the int8
-fused-dequant epilogue choice) and ``quantized_matmul`` (block_m/n/k).
+gate — ISSUE 18), ``paged_attention_ragged`` / ``..._int8`` (the
+query-row padding floor and the pages a grid step covers for the unified
+serving dispatch, and the int8 fused-dequant epilogue choice) and
+``quantized_matmul`` (block_m/n/k).
 
 Kernel modules are imported lazily inside each runner so this package
 never participates in an import cycle with ``ops.pallas_ops``.
@@ -208,7 +209,8 @@ def _ragged_runner(contract: KernelContract, bucket: Mapping[str, int],
     jit_for = _per_choice(
         contract.name,
         lambda c: lambda a, b, d, e, f: ragged_paged_attention_kernel(
-            a, b, d, e, f, q_align=c["q_align"]))
+            a, b, d, e, f, q_align=c["q_align"],
+            pages_per_step=c["pages_per_step"]))
 
     def run(choice):
         return jit_for(choice)(q, kp, vp, pt, rl)
@@ -229,6 +231,7 @@ def _ragged_int8_runner(contract: KernelContract,
         contract.name,
         lambda c: lambda a, b, d, e, f, g, h: ragged_paged_attention_kernel(
             a, b, d, e, f, g, h, q_align=c["q_align"],
+            pages_per_step=c["pages_per_step"],
             fused_dequant=bool(c["fused_dequant"])))
 
     def run(choice):

@@ -83,6 +83,10 @@ class TestContractRegistry:
             == CONTRACTS["paged_attention_ragged"].dim("q_align")
         assert paged_attention._LANE \
             == CONTRACTS["paged_attention_ragged"].dim("lane")
+        assert paged_attention._RAGGED_PAGES_PER_STEP \
+            == CONTRACTS["paged_attention_ragged"].dim("pages_per_step")
+        assert paged_attention._STATS_PAGES_PER_STEP == CONTRACTS[
+            "paged_attention_ragged_stats"].dim("pages_per_step")
         assert quantized_matmul._BLOCK_K \
             == CONTRACTS["quantized_matmul"].dim("block_k")
 
@@ -100,18 +104,26 @@ class TestContractRegistry:
         ks = next(b for b in
                   CONTRACTS["paged_attention_ragged_int8"].blocks
                   if b.name == "k_scales")
-        assert ks.shape == (1, 1, "heads") \
+        assert ks.shape == ("pages_per_step", 1, "heads") \
             and ks.lanes_full and ks.sublane_full
         # the page block IS the stored page (ISSUE 26): [P, H*D], the
-        # fused row spanning the pool's whole last dim, in every form
+        # fused row spanning the pool's whole last dim, in every form —
+        # pages_per_step of them a grid step (ISSUE 29), and the lane's
+        # live-row extent prefetched beside its longest row
         for name in ("paged_attention_ragged",
                      "paged_attention_ragged_int8",
                      "paged_attention_ragged_stats"):
-            for side in ("k_page", "v_page"):
-                blk = next(b for b in CONTRACTS[name].blocks
-                           if b.name == side)
-                assert blk.shape == (1, "page_size", "kv_width") \
+            c = CONTRACTS[name]
+            assert c.grid == ("groups", "page_groups")
+            assert c.dim("pages_per_step") * c.dim("page_size") \
+                == c.dim("lane")
+            for side in ("k_pages", "v_pages"):
+                blk = next(b for b in c.blocks if b.name == side)
+                assert blk.shape == ("pages_per_step", "page_size",
+                                     "kv_width") \
                     and blk.lanes_full, (name, side)
+            ext = next(b for b in c.blocks if b.name == "live_rows")
+            assert ext.memory == "smem" and ext.shape == ("groups",)
 
 
 class TestValidateRules:

@@ -120,16 +120,21 @@ def test_flash_partitions_over_a_v5e_mesh(v5e_topology):
         jax.jit(grads).lower(x, x, x).compile()
 
 
+# rows 64: the mixed step, whose kernel branches on a lane's live-row
+# extent (rows 0-7 | all 64); rows 8: the program with no second row block
+@pytest.mark.parametrize("rows", [64, 8], ids=["rows64", "rows8"])
 @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["native", "int8"])
 @pytest.mark.parametrize("H,D", SHAPES)
-def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype,
+def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype, rows,
                                                   v5e_topology, monkeypatch):
     """The whole unified serve step, compiled for the v5e: the pools are
     stored in the layout the ragged kernel reads (ISSUE 26), so the
     OPTIMISED program holds no pad, copy or transpose of a whole pool,
-    no temporary of a pool's size, and updates every pool in place.  At
-    (12, 64) the per-head [N, P, 12, 64] layout cost two relayouts and a
-    pad per pool per step — 55% of the step on the chip."""
+    no temporary of a pool's size, and updates every pool in place —
+    with each pool handed to the kernel once per page of a grid step
+    (ISSUE 29: the same buffer eight times, no copy).  At (12, 64) the
+    per-head [N, P, 12, 64] layout cost two relayouts and a pad per pool
+    per step — 55% of the step on the chip."""
     from jax.sharding import SingleDeviceSharding
 
     import paddle_tpu
@@ -147,7 +152,7 @@ def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype,
                      num_heads=H, ffn_size=256, max_seq_len=1024,
                      dropout=0.0)
     model.eval()
-    pages, lanes, rows, page_size = 769, 8, 64, 16
+    pages, lanes, page_size = 769, 8, 16
     fn, init_pages = make_gpt_paged_ragged_step(
         model, page_size, 1024 // page_size, kv_cache_dtype=kv_dtype)
     on_chip = SingleDeviceSharding(v5e_topology.devices[0])
@@ -170,6 +175,22 @@ def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype,
     pool_bytes = pages * page_size * H * D * pools[0].dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
+
+
+def test_the_serve_cells_kernel_call_compiles_for_v5e(v5e_topology):
+    """The ragged kernel at the long-prompt serve cell's own shape (48
+    lanes x 64 rows over 64-page tables, 12 heads of 64): six grid steps
+    of eight pages a lane, the row-block branch in each."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_ops.cases import serve_cell_case
+
+    on_chip = SingleDeviceSharding(v5e_topology.devices[0])
+    case = serve_cell_case(pages=33)
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip)
+             for a in case.args]
+    text = jax.jit(case.kernel).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("case", range(4), ids=[

@@ -158,6 +158,141 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
+    # ISSUE 29: a lane's rows are computed up to the row block covering
+    # its last live row, and one grid step covers several pages
+    def _mixed_step(self, H, D, M):
+        """A 64-row mixed step as the engine dispatches it: a decode lane
+        (one live row), a full chunk, a tail chunk of 16 and an idle lane
+        (every row_len 0), each over a table of M pages whose entries past
+        the lane's live pages are the trash page 0."""
+        rng = np.random.RandomState(11)
+        G, Qb, N, ps = 4, 64, 40, 16
+        q = rng.randn(G, Qb, H, D).astype(np.float32) * 0.5
+        kf = rng.randn(N, ps, H, D).astype(np.float32)
+        vf = rng.randn(N, ps, H, D).astype(np.float32)
+        rl = np.zeros((G, Qb), np.int32)
+        rl[0, 0] = M * ps - 5
+        rl[1, :] = np.arange(ps + 3, ps + 3 + Qb)
+        rl[2, :16] = np.arange(2 * ps + 1, 2 * ps + 17)
+        pt = rng.randint(1, N, (G, M)).astype(np.int32)
+        live = -(-rl.max(axis=1) // ps)
+        pt[np.arange(M)[None, :] >= live[:, None]] = 0
+        return q, kf, vf, pt, rl
+
+    @pytest.mark.parametrize("H,D", [(8, 128), (12, 64)],
+                             ids=["h8d128", "h12d64"])
+    @pytest.mark.parametrize("form", ["native", "int8_fused",
+                                      "int8_prescaled", "stats"])
+    def test_mixed_step_computes_live_row_blocks_only(self, H, D, form):
+        """Every paged form at Qb = 64 over a table no multiple of the
+        pages-a-step dim (12 -> two groups of 8), the trash page poisoned:
+        the output is finite, equals the reference over a clean pool, and
+        the rows past each lane's extent are exact zeros."""
+        from paddle_tpu.ops.pallas_ops.paged_attention import (
+            ragged_paged_attention_stats_kernel,
+            ragged_paged_attention_stats_xla)
+
+        q, kf, vf, pt, rl = self._mixed_step(H, D, M=12)
+        N, ps = kf.shape[:2]
+        fused = (N, ps, H * D)
+        scales = clean_scales = ()
+        if form.startswith("int8"):
+            ks = (np.abs(kf).max(axis=(1, 3)) / 127 + 1e-9
+                  ).astype(np.float32)
+            vs = (np.abs(vf).max(axis=(1, 3)) / 127 + 1e-9
+                  ).astype(np.float32)
+            kf = np.clip(np.round(kf / ks[:, None, :, None]), -127,
+                         127).astype(np.int8)
+            vf = np.clip(np.round(vf / vs[:, None, :, None]), -127,
+                         127).astype(np.int8)
+            clean_scales = (jnp.asarray(ks), jnp.asarray(vs))
+            ks, vs = ks.copy(), vs.copy()
+            ks[0], vs[0] = np.nan, np.nan       # int8 has no NaN: scales
+            scales = (jnp.asarray(ks), jnp.asarray(vs))
+            kf[0], vf[0] = 127, 127
+            poisoned = (kf, vf)
+        else:
+            poisoned = (kf.copy(), vf.copy())
+            poisoned[0][0] = np.nan
+            poisoned[1][0] = np.nan
+            kf[0], vf[0] = 0.0, 0.0
+        clean = [jnp.asarray(a.reshape(fused)) for a in (kf, vf)]
+        dirty = [jnp.asarray(a.reshape(fused)) for a in poisoned]
+        q, pt, rl = jnp.asarray(q), jnp.asarray(pt), jnp.asarray(rl)
+        if form == "stats":
+            # a shard that owns every other live page; the caller aims
+            # the entries it does not own at its trash page
+            ok = (np.arange(pt.shape[1])[None, :] % 2 == 0) \
+                & (np.asarray(pt) != 0)
+            pt = jnp.asarray(np.where(ok, np.asarray(pt), 0))
+            ok = jnp.asarray(ok.astype(np.int32))
+            out, lse = ragged_paged_attention_stats_kernel(
+                q, *dirty, pt, rl, ok, interpret=True)
+            ref, ref_lse = ragged_paged_attention_stats_xla(
+                q, *clean, pt, rl, ok)
+            np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                                       rtol=2e-5, atol=2e-5)
+        else:
+            out = ragged_paged_attention_kernel(
+                q, *dirty, pt, rl, *scales, interpret=True,
+                fused_dequant=form != "int8_prescaled")
+            ref = ragged_paged_attention_xla(q, *clean, pt, rl,
+                                             *clean_scales)
+        out = np.asarray(out)
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-5,
+                                   atol=2e-5)
+        assert np.abs(out[0, 1:]).max() == 0.0      # decode lane's pad rows
+        assert np.abs(out[2, 16:]).max() == 0.0     # past the tail chunk
+        assert np.abs(out[3]).max() == 0.0          # the idle lane
+
+    @pytest.mark.parametrize("n", [2, 4, 8])
+    @pytest.mark.parametrize("rows", [1, 8], ids=["decode", "rows8"])
+    def test_lanes_of_one_n_and_n_plus_one_live_pages(self, n, rows):
+        """n pages a grid step over a table of 2n + 1 entries (padded to
+        3n), lanes whose live pages end inside the first group, exactly
+        on it and one page into the second — at the row buckets that have
+        no second row block (the decode entry and Qb = 8)."""
+        rng = np.random.RandomState(13)
+        H, D, ps, N, M = 12, 64, 16, 24, 2 * n + 1
+        kp = rng.randn(N, ps, H * D).astype(np.float32)
+        vp = rng.randn(N, ps, H * D).astype(np.float32)
+        pt = rng.randint(1, N, (3, M)).astype(np.int32)
+        pages = np.array([1, n, n + 1])
+        pt[np.arange(M)[None, :] >= pages[:, None]] = 0
+        dirty_k, dirty_v = kp.copy(), vp.copy()
+        dirty_k[0], dirty_v[0] = np.nan, np.nan
+        kp[0], vp[0] = 0.0, 0.0
+        rl = np.zeros((3, rows), np.int32)
+        rl[:, 0] = pages * ps - np.array([9, 0, 15])
+        if rows > 1:
+            rl[1, :] = rl[1, 0] - np.arange(rows)[::-1]
+        q = jnp.asarray(rng.randn(3, rows, H, D).astype(np.float32) * 0.5)
+        pt, rl = jnp.asarray(pt), jnp.asarray(rl)
+        if rows == 1:
+            out = paged_attention_kernel(
+                q[:, 0], jnp.asarray(dirty_k), jnp.asarray(dirty_v), pt,
+                rl[:, 0], interpret=True, pages_per_step=n)[:, None]
+        else:
+            out = ragged_paged_attention_kernel(
+                q, jnp.asarray(dirty_k), jnp.asarray(dirty_v), pt, rl,
+                interpret=True, pages_per_step=n)
+        ref = ragged_paged_attention_xla(q, jnp.asarray(kp),
+                                         jnp.asarray(vp), pt, rl)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("live,rows,skipped", [
+        (1, 64, 56), (8, 64, 56), (9, 64, 0), (16, 64, 0), (64, 64, 0),
+        (0, 64, 56), (1, 1, 0), (0, 8, 0), (1, 16, 8), (3, 20, 16)])
+    def test_rows_skipped_is_the_kernels_row_block_rule(self, live, rows,
+                                                        skipped):
+        from paddle_tpu.ops.pallas_ops.paged_attention import (
+            ragged_rows_skipped)
+
+        assert ragged_rows_skipped(live, rows) == skipped
+
 
 class TestPagedKVCache:
     def test_alloc_free_roundtrip_and_stats(self):

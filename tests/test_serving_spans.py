@@ -166,6 +166,56 @@ def test_work_counts_equal_a_brute_force_count(gpt):
     assert eng.cache.pages_in_use == 0
 
 
+@pytest.mark.parametrize("chunk", [4, 16, 32])
+def test_rows_skipped_follow_the_kernels_row_blocks(gpt, chunk):
+    """``attn_rows_skipped`` — span arg and counter, from host state —
+    against the rule the kernel branches on, applied to the row lengths
+    each dispatch handed it: the bucket beyond a lane's last live row,
+    rounded up to its row block (ISSUE 29).  A chunk of 4 fits the first
+    block, so nothing is ever skipped there."""
+    from paddle_tpu.ops.pallas_ops.paged_attention import ragged_rows_skipped
+
+    rng = np.random.RandomState(17)
+    eng = _mixed_engine(gpt, prefill_chunk=chunk, prefix_cache=True)
+    seen = []
+    real = eng._ragged_jit
+
+    def spy(tokens, pos, tables, rows_tok, rows_pos, row_valid, advance,
+            kv):
+        valid = np.asarray(jax.device_get(row_valid))
+        Q = valid.shape[1]
+        ext = np.where(valid > 0, np.arange(1, Q + 1), 0).max(axis=1)
+        seen.append(sum(ragged_rows_skipped(int(e), Q) for e in ext))
+        return real(tokens, pos, tables, rows_tok, rows_pos, row_valid,
+                    advance, kv)
+
+    eng._ragged_jit = spy
+    base = stat_registry.get("serving.ragged.attn_rows_skipped").get()
+    shared = rng.randint(1, VOCAB, (8,)).astype(np.int32)
+    profiler.enable_tracing()
+    try:
+        eng.add_request(rng.randint(1, VOCAB, (3,)).astype(np.int32),
+                        max_new_tokens=10)
+        eng.step()
+        for tail in (5, 3):
+            eng.add_request(np.concatenate(
+                [shared, rng.randint(1, VOCAB, (tail,)).astype(np.int32)]),
+                max_new_tokens=4)
+        eng.add_request(rng.randint(1, VOCAB, (37,)).astype(np.int32),
+                        max_new_tokens=3)
+        eng.drain()
+        steps = sorted(_spans_by_name()["serving/ragged_step"],
+                       key=lambda s: s.start_ns)
+    finally:
+        profiler.disable_tracing()
+    assert [s.args["attn_rows_skipped"] for s in steps] == seen
+    assert stat_registry.get("serving.ragged.attn_rows_skipped").get() \
+        - base == sum(seen)
+    assert (sum(seen) > 0) == (chunk > 8)
+    assert all(0 <= k <= s.args["bucket"] * s.args["rows"]
+               for k, s in zip(seen, steps))
+
+
 def test_queue_wait_counts_each_admission_from_arrival(gpt):
     rng = np.random.RandomState(9)
     eng = _mixed_engine(gpt, max_batch_size=2)

@@ -401,9 +401,9 @@ class TestRuntimeResolution:
             == (128, 128, 128)
         assert flash_attention._resolved_blocks(1024) == (512, 1024)
         assert paged_attention._ragged_resolved_dims(2, 16, False) \
-            == (8, True)
+            == (8, True, 8)
         assert paged_attention._ragged_resolved_dims(2, 16, True) \
-            == (8, True)
+            == (8, True, 8)
 
     def test_hit_miss_and_counter_accounting(self):
         from paddle_tpu.ops.pallas_ops import quantized_matmul as qmm
@@ -563,6 +563,35 @@ class TestKernelParityPins:
         out = np.asarray(ragged_paged_attention_kernel(
             q, kp, vp, pt, rl, interpret=True, q_align=16))
         np.testing.assert_array_equal(out, ref)
+
+    @pytest.mark.parametrize("pages", [1, 2, 4])
+    def test_paged_pages_per_step_tuned_matches_default(self, pages):
+        """The pages a grid step covers, resolved through the table like
+        q_align: a tuned value regroups the online softmax (the same
+        sums in another order), so the default's result to rounding."""
+        from paddle_tpu.ops.pallas_ops.paged_attention import (
+            _ragged_resolved_dims, ragged_paged_attention_kernel)
+
+        rng = np.random.RandomState(8)
+        H, D = 3, 20
+        q = jnp.asarray(rng.randn(2, 5, H, D).astype(np.float32))
+        kp = jnp.asarray(rng.randn(12, 4, H * D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(12, 4, H * D).astype(np.float32))
+        pt = jnp.asarray(rng.randint(1, 12, (2, 9)).astype(np.int32))
+        rl = jnp.asarray(np.array([[30, 31, 32, 33, 34], [17, 0, 0, 0, 0]],
+                                  np.int32))
+        ref = np.asarray(ragged_paged_attention_kernel(
+            q, kp, vp, pt, rl, interpret=True))
+        contract = CONTRACTS["paged_attention_ragged"]
+        t = tune.TuningTable()
+        t.put(contract.name,
+              tune.bucket_key(contract, {"heads": H, "head_dim": D}),
+              "float32", "cpu", {"q_align": 8, "pages_per_step": pages})
+        tune.set_active_table(t)
+        assert _ragged_resolved_dims(H, D, False) == (8, True, pages)
+        out = np.asarray(ragged_paged_attention_kernel(
+            q, kp, vp, pt, rl, interpret=True))
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
     def test_paged_int8_epilogue_choice_bounded_not_identical(self):
         """The fused-dequant axis is measurable but NOT bit-exact —
