@@ -1,9 +1,8 @@
 """Weight-only int8 matmul Pallas kernel (TPU) — ``x @ dequant(w)``.
 
-The serving decode loop is bytes-bound (every BENCH_r05 serving section
-reports ``binding_wall: "hbm"``): each decode step streams every weight
-matrix once for a handful of query rows, so halving weight bytes is a
-direct throughput win.  This kernel keeps the weights RESIDENT AS INT8
+The serving decode loop is bytes-bound: each decode step streams every
+weight matrix once for a handful of query rows, so halving weight bytes
+is a direct throughput win.  This kernel keeps the weights RESIDENT AS INT8
 — [K, N] s8 plus one fp32 dequant scale per output channel — and
 dequantizes in-register after the DMA, immediately before the MXU
 contraction.  HBM sees 1 byte/weight instead of 2 (bf16) or 4 (f32);

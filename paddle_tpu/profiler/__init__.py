@@ -63,7 +63,7 @@ __all__ = [
 def metrics_snapshot() -> dict:
     """One-call observability dump: counters, gauges, histogram
     percentiles, span aggregates, per-jit cost attribution, and device
-    memory stats — the artifact BENCH_TRACE writes next to the trace."""
+    memory stats."""
     return {
         "stats": stat_registry.stat_values(),
         "gauges": {

@@ -176,19 +176,16 @@ def _shared_programs(model, *, page_size: int, pages_per_seq: int,
     qkw = dict(kv_cache_dtype=kv_cache_dtype, kv_scales=kv_scales,
                weight_quant=weight_quant)
 
-    step_fn, init_pages = make_gpt_paged_decode_step(
+    # the pools come from the builder that knows the mesh layout; mesh
+    # engines run ragged-only, so the split decode/prefill programs are
+    # never traced there (profiled_jit is lazy)
+    step_fn, _ = make_gpt_paged_decode_step(
         model, page_size, pages_per_seq, **qkw)
     prefill_fn, _ = make_gpt_paged_prefill_step(
         model, page_size, pages_per_seq, **qkw)
-    ragged_fn, ragged_init = make_gpt_paged_ragged_step(
+    ragged_fn, init_pages = make_gpt_paged_ragged_step(
         model, page_size, pages_per_seq, with_guard=numeric_guards,
         mesh_layout=mesh_layout, **qkw)
-    if mesh_layout is not None and mesh_layout.size > 1:
-        # mesh engines run ragged-only: the pools must come from the
-        # SHARDED builder (laid out per the mesh layout), and the split
-        # decode/prefill programs are never traced (profiled_jit is
-        # lazy) — the sharded core would reject them anyway
-        init_pages = ragged_init
 
     def _decode(tokens, pos, page_tables, kv):
         logits, kv = step_fn(tokens, pos, page_tables, kv)
@@ -426,7 +423,9 @@ class ServingEngine:
                                                    None]] = None):
         self.model = model
         self.page_size = int(page_size)
-        model_max = int(model.wpe.weight.shape[0])
+        from ..text.generation import _gpt_geometry
+
+        _, heads, _, _, model_max, _ = _gpt_geometry(model)
         self.max_seq_len = int(max_seq_len) if max_seq_len else model_max
         if self.max_seq_len > model_max:
             raise InvalidArgumentError(
@@ -465,7 +464,6 @@ class ServingEngine:
                     f"mesh_axes degrees must be >= 1, got tp={mesh_tp} "
                     f"sp={mesh_sp}")
             if mesh_tp * mesh_sp > 1:
-                heads = int(model.layers[0].attn.num_heads)
                 if heads % mesh_tp:
                     raise InvalidArgumentError(
                         f"mesh_axes tp={mesh_tp} must divide the "

@@ -248,232 +248,62 @@ class ServingMeshLayout:
         return tuple(s * pl for s in range(int(self.sp)))
 
 
-def _make_gpt_paged_sharded_core(model, page_size: int, pages_per_seq: int,
-                                 layout: ServingMeshLayout, *,
-                                 kv_cache_dtype=None, kv_scales=None,
-                                 weight_quant=None):
-    """Mesh-sharded twin of ``_make_gpt_paged_core`` (ISSUE 19).
+def _gpt_geometry(model):
+    """``(L, H, D, hidden, max_pos, vocab)`` of a GPTModel — the one place
+    the serving path (the step builders here, ``ServingEngine``) reads the
+    model's shape."""
+    vocab, hidden = model.wte.weight.shape
+    H = int(model.layers[0].attn.num_heads)
+    return (len(model.layers), H, int(hidden) // H, int(hidden),
+            int(model.wpe.weight.shape[0]), int(vocab))
 
-    Same ``(core, init_pages)`` contract, but the core is an explicit
-    ``shard_map`` over the layout's (tp, sp, data) mesh: weights enter
-    pre-sharded per ``layout.param_spec``, the KV pools per
-    ``page_spec``/``scale_spec``, and the partial-softmax exchange is
-    spelled out in code (pmax/psum of lse-space stats) rather than left
-    to GSPMD — which is what keeps the tp path bitwise identical to the
-    single-device core and the sp merge auditable.  Serves the unified
-    ragged layout only (``qgroup`` required): the mesh engine always
-    runs ``ragged=True``.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec
-    from ..distributed import mesh as mesh_lib
-    from ..ops.pallas_ops.paged_attention import (
-        ragged_paged_attention as ragged_paged_attn,
-        ragged_paged_attention_stats as ragged_stats)
 
-    P = PartitionSpec
-    params, _ = get_state(model)
-    L = len(model.layers)
-    H = model.layers[0].attn.num_heads
-    hidden = model.wte.weight.shape[1]
-    D = hidden // H
-    max_pos = params["wpe.weight"].shape[0]
-    tp, sp = int(layout.tp), int(layout.sp)
-    tpn, spn = layout.tp_axis, layout.sp_axis
-    if H % tp:
-        raise ValueError(
-            f"num_heads ({H}) must be divisible by tp ({tp})")
-    H_loc = H // tp
-    quant_kv = kv_cache_dtype == "int8"
+def _gpt_embed(p, tokens, pos, max_pos):
+    # positions past the table belong to masked lanes (bucket padding)
+    # whose output is discarded: clamp instead of relying on gather
+    # clipping
+    return p["wte.weight"][tokens] + p["wpe.weight"][
+        jnp.minimum(pos, max_pos - 1)]
+
+
+def _gpt_head(p, x):
+    x = _ln(x, p["ln_f.weight"], p["ln_f.bias"])
+    return x @ p["wte.weight"].T                             # tied head
+
+
+def _gpt_block(p, mm, i, x, attend, tp_axis=None):
+    """Layer ``i`` of the GPT-2 stack over N independent rows ``x``
+    [N, hidden] — THE block: the dense step and the paged core (one chip
+    or a mesh) all run this one.
+
+    The seam is block against cache: ``attend(i, q, k1, v1) -> ctx
+    [N, hidden]`` takes the layer's projections as flat rows (under a
+    mesh, this shard's heads), owns the cache write and the attention
+    over it, and hands back the context of ALL heads.  ``tp_axis`` names
+    the mesh axis fc1's columns are sharded over (None on one chip): the
+    activations are all-gathered before the replicated fc2."""
+    def lp(name):
+        return p[f"layers.{i}.{name}"]
+
+    h = _ln(x, lp("ln1.weight"), lp("ln1.bias"))
+    q = mm(h, f"layers.{i}.attn.q_proj.weight") + lp("attn.q_proj.bias")
+    k1 = mm(h, f"layers.{i}.attn.k_proj.weight") + lp("attn.k_proj.bias")
+    v1 = mm(h, f"layers.{i}.attn.v_proj.weight") + lp("attn.v_proj.bias")
+    ctx = attend(i, q, k1, v1)
+    x = x + (mm(ctx, f"layers.{i}.attn.out_proj.weight")
+             + lp("attn.out_proj.bias"))
+    h2 = _ln(x, lp("ln2.weight"), lp("ln2.bias"))
+    ff = _gelu(mm(h2, f"layers.{i}.fc1.weight") + lp("fc1.bias"))
+    if tp_axis is not None:
+        ff = jax.lax.all_gather(ff, tp_axis, axis=1, tiled=True)
+    return x + mm(ff, f"layers.{i}.fc2.weight") + lp("fc2.bias")
+
+
+def _check_kv_cache_dtype(kv_cache_dtype):
     if kv_cache_dtype not in (None, "int8"):
         raise ValueError(f"kv_cache_dtype must be None or 'int8', got "
                          f"{kv_cache_dtype!r}")
-    k_sc, v_sc = _as_layer_scales(kv_scales, L, H)
-    mesh = mesh_lib.init_mesh(layout.axes())
-
-    def put(v, spec_):
-        return jax.device_put(v, NamedSharding(mesh, spec_))
-
-    # weights land on-device PRE-SHARDED (tp column shards for qkv/fc1,
-    # replicated otherwise): the compiled step's input layouts already
-    # match, so no weight movement happens per dispatch — decode streams
-    # each chip's weight shard at that chip's HBM bandwidth
-    params = {name: put(v, layout.param_spec(name))
-              for name, v in params.items()}
-    consts = {"p": params}
-    cspecs = {"p": {name: layout.param_spec(name) for name in params}}
-    if weight_quant:
-        wq, wqs = {}, {}
-        for name, (qv, sv) in weight_quant.items():
-            qspec = layout.param_spec(name)
-            sspec = P(tpn) if qspec != P() else P()
-            wq[name] = (put(jnp.asarray(qv), qspec),
-                        put(jnp.asarray(sv, jnp.float32), sspec))
-            wqs[name] = (qspec, sspec)
-        consts["wq"] = wq
-        cspecs["wq"] = wqs
-    if k_sc is not None:
-        consts["ksc"] = [put(a, P(tpn)) for a in k_sc]
-        consts["vsc"] = [put(a, P(tpn)) for a in v_sc]
-        cspecs["ksc"] = [P(tpn)] * L
-        cspecs["vsc"] = [P(tpn)] * L
-
-    def init_pages(num_pages: int):
-        if num_pages % sp:
-            raise ValueError(
-                f"num_pages ({num_pages}) must be divisible by sp ({sp})")
-
-        def z():
-            dt = jnp.int8 if quant_kv else params["wte.weight"].dtype
-            return put(jnp.zeros((num_pages, page_size, H * D), dt),
-                       layout.page_spec())
-
-        kv = {"k": [z() for _ in range(L)], "v": [z() for _ in range(L)]}
-        if quant_kv:
-            def sc(static):
-                from ..serving.kv_cache import KV_SCALE_EPS
-
-                if static is None:
-                    arr = jnp.full((num_pages, H), KV_SCALE_EPS,
-                                   jnp.float32)
-                else:
-                    arr = jnp.broadcast_to(
-                        static[None, :],
-                        (num_pages, H)).astype(jnp.float32) + 0
-                return put(arr, layout.scale_spec())
-            kv["k_scale"] = [sc(k_sc[i] if k_sc else None)
-                             for i in range(L)]
-            kv["v_scale"] = [sc(v_sc[i] if v_sc else None)
-                             for i in range(L)]
-        return kv
-
-    def core(tokens, pos, page_tables, kv, valid_len=None, with_head=True,
-             qgroup=None):
-        if qgroup is None:
-            raise NotImplementedError(
-                "the mesh-sharded paged core serves the unified ragged "
-                "layout only (the mesh engine runs ragged=True)")
-        has_vl = valid_len is not None
-        Q = int(qgroup)
-
-        def body(consts_l, tokens, pos, page_tables, vlen, kv_l):
-            pl_ = consts_l["p"]
-            mm = _make_mm(pl_, consts_l.get("wq"))
-            ksc_l = consts_l.get("ksc")
-            vsc_l = consts_l.get("vsc")
-            sp_i = jax.lax.axis_index(spn)
-            pages_local = kv_l["k"][0].shape[0]
-
-            def lpl(i, name):
-                return pl_[f"layers.{i}.{name}"]
-
-            N = tokens.shape[0]
-            row_tables = jnp.repeat(page_tables, Q, axis=0)
-            pos_c = jnp.minimum(pos, max_pos - 1)
-            x = pl_["wte.weight"][tokens] + pl_["wpe.weight"][pos_c]
-            page_of = jnp.minimum(pos // page_size, pages_per_seq - 1)
-            page_idx = jnp.take_along_axis(row_tables, page_of[:, None],
-                                           axis=1)[:, 0]
-            slot = pos % page_size
-            seq_lens = pos + 1
-            if has_vl:
-                page_idx = jnp.where(pos < vlen, page_idx, 0)
-                seq_lens = jnp.minimum(seq_lens, vlen)
-            # global -> shard-local page ids: a non-owned row scatters
-            # into this shard's reserved trash row (local 0, a global
-            # reserved page) and attention masks pages by OWNERSHIP, so
-            # each chip holds and streams 1/sp of every sequence's KV
-            owner = (page_idx // pages_local) == sp_i
-            local_idx = jnp.where(owner, page_idx % pages_local, 0)
-            G = N // Q
-            pt_owner = (page_tables // pages_local) == sp_i
-            pt_local = jnp.where(pt_owner, page_tables % pages_local, 0)
-            ks, vs, ksc_out, vsc_out = [], [], [], []
-            for i in range(L):
-                h = _ln(x, lpl(i, "ln1.weight"), lpl(i, "ln1.bias"))
-                q = (mm(h, f"layers.{i}.attn.q_proj.weight")
-                     + lpl(i, "attn.q_proj.bias")).reshape(N, H_loc, D)
-                # k1/v1 stay [N, H_loc*D]: the projection's rows ARE the
-                # pool's rows, scattered in place on the donated buffer
-                k1 = (mm(h, f"layers.{i}.attn.k_proj.weight")
-                      + lpl(i, "attn.k_proj.bias"))
-                v1 = (mm(h, f"layers.{i}.attn.v_proj.weight")
-                      + lpl(i, "attn.v_proj.bias"))
-                if quant_kv:
-                    kc, ksc = _quant_write_page(
-                        kv_l["k"][i], kv_l["k_scale"][i], local_idx, slot,
-                        k1.reshape(N, H_loc, D),
-                        ksc_l[i] if ksc_l else None)
-                    vc, vsc = _quant_write_page(
-                        kv_l["v"][i], kv_l["v_scale"][i], local_idx, slot,
-                        v1.reshape(N, H_loc, D),
-                        vsc_l[i] if vsc_l else None)
-                    ksc_out.append(ksc)
-                    vsc_out.append(vsc)
-                    scales = (ksc, vsc)
-                else:
-                    kc = kv_l["k"][i].at[local_idx, slot].set(k1)
-                    vc = kv_l["v"][i].at[local_idx, slot].set(v1)
-                    scales = ()
-                qg = q.reshape(G, Q, H_loc, D)
-                sl = seq_lens.reshape(G, Q)
-                if sp == 1:
-                    ctx_l = ragged_paged_attn(qg, kc, vc, pt_local, sl,
-                                              *scales)
-                else:
-                    # partial-softmax exchange: each shard reduces over
-                    # its OWNED pages only, then the running-max /
-                    # denominator stats merge across sp in lse space
-                    # (the ring_attention.py recipe)
-                    o, lse = ragged_stats(qg, kc, vc, pt_local, sl,
-                                          pt_owner, *scales)
-                    mx = jax.lax.pmax(lse, spn)
-                    w = jnp.exp(lse - mx)
-                    num = jax.lax.psum(o * w[..., None], spn)
-                    den = jax.lax.psum(w, spn)
-                    ctx_l = num / jnp.maximum(den, 1e-30)[..., None]
-                ctx_l = ctx_l.reshape(N, H_loc, D)
-                if tp > 1:
-                    ctx = jax.lax.all_gather(ctx_l, tpn, axis=1,
-                                             tiled=True)
-                else:
-                    ctx = ctx_l
-                ks.append(kc)
-                vs.append(vc)
-                x = x + (mm(ctx.reshape(N, hidden),
-                            f"layers.{i}.attn.out_proj.weight")
-                         + lpl(i, "attn.out_proj.bias"))
-                h2 = _ln(x, lpl(i, "ln2.weight"), lpl(i, "ln2.bias"))
-                ff = _gelu(mm(h2, f"layers.{i}.fc1.weight")
-                           + lpl(i, "fc1.bias"))
-                if tp > 1:
-                    ff = jax.lax.all_gather(ff, tpn, axis=1, tiled=True)
-                x = x + mm(ff, f"layers.{i}.fc2.weight") + lpl(i, "fc2.bias")
-            kv_out = {"k": ks, "v": vs}
-            if quant_kv:
-                kv_out["k_scale"] = ksc_out
-                kv_out["v_scale"] = vsc_out
-            if not with_head:
-                return kv_out
-            x = _ln(x, pl_["ln_f.weight"], pl_["ln_f.bias"])
-            return x @ pl_["wte.weight"].T, kv_out
-
-        kvs = layout.kv_spec(kv)
-        in_specs = (cspecs, P(), P(), P(), P(), kvs)
-        out_specs = (P(), kvs) if with_head else kvs
-        # check_vma=False for the whole core, because the one typing
-        # problem cannot be opted out of alone: the logits are replicated
-        # in VALUE (every shard all-gathers the same context), but jax
-        # types an all_gather result as varying, jax 0.9 has no public
-        # invariant all_gather (`all_gather_invariant` lives in jax._src)
-        # and pcast only goes invariant -> varying.  The value assumption
-        # is pinned by the byte-identity tests in test_serving_mesh.py.
-        f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-        vlen = valid_len if has_vl else jnp.zeros((), jnp.int32)
-        out = f(consts, tokens, pos, page_tables, vlen, kv)
-        return out if with_head else (None, out)
-
-    return core, init_pages
+    return kv_cache_dtype == "int8"
 
 
 def make_gpt_decode_step(model, max_len: int, *, kv_cache_dtype=None,
@@ -494,28 +324,17 @@ def make_gpt_decode_step(model, max_len: int, *, kv_cache_dtype=None,
     through the weight-only int8 kernel.
     """
     params, _ = get_state(model)
-    L = len(model.layers)
-    H = model.layers[0].attn.num_heads
-    hidden = model.wte.weight.shape[1]
-    D = hidden // H
+    L, H, D, hidden, max_pos, _ = _gpt_geometry(model)
     scale = 1.0 / np.sqrt(D)
-    wte = params["wte.weight"]          # [V, hidden]
-    wpe = params["wpe.weight"]          # [max_pos, hidden]
-    quant_kv = kv_cache_dtype == "int8"
-    if kv_cache_dtype not in (None, "int8"):
-        raise ValueError(f"kv_cache_dtype must be None or 'int8', got "
-                         f"{kv_cache_dtype!r}")
+    quant_kv = _check_kv_cache_dtype(kv_cache_dtype)
     if quant_kv and kv_scales is None:
         raise ValueError("the dense decode cache supports int8 only with "
                          "calibrated kv_scales (slim.export_serving_quant)")
     k_sc, v_sc = _as_layer_scales(kv_scales, L, H)
     mm = _make_mm(params, weight_quant)
 
-    def lp(i, name):
-        return params[f"layers.{i}.{name}"]
-
     def init_state(batch: int):
-        cache_dtype = jnp.int8 if quant_kv else wte.dtype
+        cache_dtype = jnp.int8 if quant_kv else params["wte.weight"].dtype
         z = jnp.zeros((batch, max_len, H, D), cache_dtype)
         return {
             "k": [z for _ in range(L)],
@@ -542,40 +361,30 @@ def make_gpt_decode_step(model, max_len: int, *, kv_cache_dtype=None,
     def step_fn(tokens, state):
         pos = state["pos"]                                   # [N]
         N = tokens.shape[0]
-        x = wte[tokens] + wpe[pos]                           # [N, hidden]
         ks, vs = [], []
-        for i in range(L):
-            h = _ln(x, lp(i, "ln1.weight"), lp(i, "ln1.bias"))
-            q = (mm(h, f"layers.{i}.attn.q_proj.weight")
-                 + lp(i, "attn.q_proj.bias")).reshape(N, H, D)
-            k1 = (mm(h, f"layers.{i}.attn.k_proj.weight")
-                  + lp(i, "attn.k_proj.bias")).reshape(N, H, D)
-            v1 = (mm(h, f"layers.{i}.attn.v_proj.weight")
-                  + lp(i, "attn.v_proj.bias")).reshape(N, H, D)
+
+        def attend(i, q, k1, v1):
+            # the dense ring: write this position, attend over the
+            # cache's valid prefix (<= pos)
             kc = state["k"][i].at[jnp.arange(N), pos].set(
-                _store(k1, i, k_sc))
+                _store(k1.reshape(N, H, D), i, k_sc))
             vc = state["v"][i].at[jnp.arange(N), pos].set(
-                _store(v1, i, v_sc))
+                _store(v1.reshape(N, H, D), i, v_sc))
             ks.append(kc)
             vs.append(vc)
-            # attend over the cache's valid prefix (<= pos)
-            kcf = _load(kc, i, k_sc)
-            vcf = _load(vc, i, v_sc)
-            logits = jnp.einsum("nhd,nshd->nhs", q, kcf) * scale
+            logits = jnp.einsum("nhd,nshd->nhs", q.reshape(N, H, D),
+                                _load(kc, i, k_sc)) * scale
             valid = (jnp.arange(max_len)[None, :]
                      <= pos[:, None])[:, None, :]            # [N,1,S]
             logits = jnp.where(valid, logits, -1e9)
             probs = jax.nn.softmax(logits, axis=-1)
-            ctx = jnp.einsum("nhs,nshd->nhd", probs,
-                             vcf).reshape(N, hidden)
-            x = x + (mm(ctx, f"layers.{i}.attn.out_proj.weight")
-                     + lp(i, "attn.out_proj.bias"))
-            h2 = _ln(x, lp(i, "ln2.weight"), lp(i, "ln2.bias"))
-            ff = _gelu(mm(h2, f"layers.{i}.fc1.weight") + lp(i, "fc1.bias"))
-            x = x + mm(ff, f"layers.{i}.fc2.weight") + lp(i, "fc2.bias")
-        x = _ln(x, params["ln_f.weight"], params["ln_f.bias"])
-        out = x @ wte.T                                      # tied head
-        return out, {"k": ks, "v": vs, "pos": pos + 1}
+            return jnp.einsum("nhs,nshd->nhd", probs,
+                              _load(vc, i, v_sc)).reshape(N, hidden)
+
+        x = _gpt_embed(params, tokens, pos, max_pos)         # [N, hidden]
+        for i in range(L):
+            x = _gpt_block(params, mm, i, x, attend)
+        return _gpt_head(params, x), {"k": ks, "v": vs, "pos": pos + 1}
 
     return step_fn, init_state
 
@@ -583,11 +392,9 @@ def make_gpt_decode_step(model, max_len: int, *, kv_cache_dtype=None,
 def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
                          kv_cache_dtype=None, kv_scales=None,
                          weight_quant=None, mesh_layout=None):
-    """Shared paged-KV transformer core behind the serving step builders.
-
-    ``mesh_layout`` (a ``ServingMeshLayout`` spanning > 1 chip) swaps in
-    the mesh-sharded twin ``_make_gpt_paged_sharded_core`` — same
-    contract, weights/pools sharded over the named (tp, sp, data) mesh.
+    """THE paged-KV transformer core behind the serving step builders —
+    on one chip and, with a ``mesh_layout`` (a ``ServingMeshLayout``)
+    spanning > 1 chip, over the named (tp, sp, data) mesh.
 
     Returns ``(core, init_pages)`` where ``core(tokens [N], pos [N],
     page_tables [N, M], kv, valid_len=None, with_head=True)`` runs one
@@ -609,6 +416,24 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
     ``with_head=False`` skips the [N, V] logits matmul (prefill discards
     logits — the first decode step consumes the last prompt token).
 
+    ``qgroup=Q`` selects the ragged-group layout (ISSUE 18): the N rows
+    are G = N // Q lanes of Q query rows each and ``page_tables`` is ONE
+    row per lane ([G, M]); the scatter path expands it per row while
+    attention takes the grouped form so the ragged kernel pays each
+    lane's page DMA once per page, not once per row.  The flat form
+    (``qgroup=None``) serves the split builders, on one chip only.
+
+    One ``body`` serves every layout; what only a mesh needs sits behind
+    the layout's STATIC degrees.  On one chip (no layout, or one of size
+    1) the body is called directly — no ``shard_map``, no ``device_put``,
+    no collective is traced.  Over a mesh (ISSUE 19) the same body runs
+    under an explicit ``shard_map``: weights enter pre-sharded per
+    ``layout.param_spec``, the KV pools per ``page_spec``/``scale_spec``,
+    and the partial-softmax exchange is spelled out in code (pmax/psum
+    of lse-space stats) rather than left to GSPMD — which is what keeps
+    the tp path bitwise identical to the one-chip program and the sp
+    merge auditable.
+
     Quantization (docs/SERVING.md "Quantized serving"):
     ``kv_cache_dtype="int8"`` makes ``init_pages`` return int8 pools
     plus per-page-per-head fp32 scale arrays (``k_scale``/``v_scale``,
@@ -620,34 +445,63 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
     page's scales when it is reallocated).  ``weight_quant`` routes the
     projection/MLP matmuls through the weight-only int8 kernel.
     """
-    if mesh_layout is not None and mesh_layout.size > 1:
-        return _make_gpt_paged_sharded_core(
-            model, page_size, pages_per_seq, mesh_layout,
-            kv_cache_dtype=kv_cache_dtype, kv_scales=kv_scales,
-            weight_quant=weight_quant)
-    from ..ops.pallas_ops.paged_attention import paged_attention as paged_attn
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from ..ops.pallas_ops.paged_attention import (
-        ragged_paged_attention as ragged_paged_attn)
+        paged_attention as paged_attn,
+        ragged_paged_attention as ragged_paged_attn,
+        ragged_paged_attention_stats as ragged_stats)
 
     params, _ = get_state(model)
-    L = len(model.layers)
-    H = model.layers[0].attn.num_heads
-    hidden = model.wte.weight.shape[1]
-    D = hidden // H
-    wte = params["wte.weight"]
-    wpe = params["wpe.weight"]
-    max_pos = wpe.shape[0]
-    quant_kv = kv_cache_dtype == "int8"
-    if kv_cache_dtype not in (None, "int8"):
-        raise ValueError(f"kv_cache_dtype must be None or 'int8', got "
-                         f"{kv_cache_dtype!r}")
+    L, H, D, hidden, max_pos, _ = _gpt_geometry(model)
+    layout = mesh_layout if mesh_layout is not None else ServingMeshLayout()
+    tp, sp = int(layout.tp), int(layout.sp)
+    tpn, spn = layout.tp_axis, layout.sp_axis
+    meshed = layout.size > 1
+    if H % tp:
+        raise ValueError(
+            f"num_heads ({H}) must be divisible by tp ({tp})")
+    H_loc = H // tp
+    quant_kv = _check_kv_cache_dtype(kv_cache_dtype)
     k_sc, v_sc = _as_layer_scales(kv_scales, L, H)
-    mm = _make_mm(params, weight_quant)
+    if meshed:
+        from ..distributed import mesh as mesh_lib
 
-    def lp(i, name):
-        return params[f"layers.{i}.{name}"]
+        mesh = mesh_lib.init_mesh(layout.axes())
+
+        def put(v, spec):
+            return jax.device_put(v, NamedSharding(mesh, spec))
+    else:
+        def put(v, spec):
+            return v
+
+    # THE one site that gathers what the step programs close over:
+    # weights, int8 weights, static KV scales (ROADMAP S4 — weights as
+    # arguments — edits here).  Under a mesh they land on-device
+    # PRE-SHARDED (tp column shards for qkv/fc1, replicated otherwise):
+    # the compiled step's input layouts already match, so no weight
+    # moves per dispatch and decode streams each chip's weight shard at
+    # that chip's HBM bandwidth.
+    cspecs = {"p": {name: layout.param_spec(name) for name in params}}
+    consts = {"p": {name: put(v, cspecs["p"][name])
+                    for name, v in params.items()}}
+    if weight_quant:
+        consts["wq"], cspecs["wq"] = {}, {}
+        for name, (qv, sv) in weight_quant.items():
+            qspec = layout.param_spec(name)
+            sspec = P(tpn) if qspec != P() else P()
+            consts["wq"][name] = (put(jnp.asarray(qv), qspec),
+                                  put(jnp.asarray(sv, jnp.float32), sspec))
+            cspecs["wq"][name] = (qspec, sspec)
+    if k_sc is not None:
+        consts["ksc"] = [put(a, P(tpn)) for a in k_sc]
+        consts["vsc"] = [put(a, P(tpn)) for a in v_sc]
+        cspecs["ksc"] = cspecs["vsc"] = [P(tpn)] * L
 
     def init_pages(num_pages: int):
+        if num_pages % sp:
+            raise ValueError(
+                f"num_pages ({num_pages}) must be divisible by sp ({sp})")
+
         # one DISTINCT buffer per layer/side: the engine donates the
         # pools to the jitted step, and XLA rejects donating one buffer
         # twice (a shared zeros array would alias all 2L entries)
@@ -655,8 +509,9 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
         # block reads and the step's scatter writes — tile-exact for
         # every (H, D), so no program pads, transposes or copies a pool
         def z():
-            dt = jnp.int8 if quant_kv else wte.dtype
-            return jnp.zeros((num_pages, page_size, H * D), dt)
+            dt = jnp.int8 if quant_kv else params["wte.weight"].dtype
+            return put(jnp.zeros((num_pages, page_size, H * D), dt),
+                       layout.page_spec())
 
         kv = {"k": [z() for _ in range(L)], "v": [z() for _ in range(L)]}
         if quant_kv:
@@ -667,93 +522,134 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
                 from ..serving.kv_cache import KV_SCALE_EPS
 
                 if static is None:
-                    return jnp.full((num_pages, H), KV_SCALE_EPS,
-                                    jnp.float32)
-                return jnp.broadcast_to(
-                    static[None, :], (num_pages, H)).astype(jnp.float32) + 0
+                    arr = jnp.full((num_pages, H), KV_SCALE_EPS,
+                                   jnp.float32)
+                else:
+                    arr = jnp.broadcast_to(
+                        static[None, :],
+                        (num_pages, H)).astype(jnp.float32) + 0
+                return put(arr, layout.scale_spec())
             kv["k_scale"] = [sc(k_sc[i] if k_sc else None)
                              for i in range(L)]
             kv["v_scale"] = [sc(v_sc[i] if v_sc else None)
                              for i in range(L)]
         return kv
 
-    def core(tokens, pos, page_tables, kv, valid_len=None, with_head=True,
-             qgroup=None):
+    def body(consts, tokens, pos, page_tables, vlen, kv, with_head, Q):
+        p = consts["p"]
+        mm = _make_mm(p, consts.get("wq"))
+        ksc, vsc = consts.get("ksc"), consts.get("vsc")
         N = tokens.shape[0]
-        # ``qgroup=Q`` selects the ragged-group layout (ISSUE 18): the N
-        # rows are G = N // Q lanes of Q query rows each and
-        # ``page_tables`` is ONE row per lane ([G, M]); the scatter path
-        # expands it per row while attention takes the grouped form so
-        # the ragged kernel pays each lane's page DMA once per page, not
-        # once per row
-        if qgroup is not None:
-            row_tables = jnp.repeat(page_tables, qgroup, axis=0)
-        else:
-            row_tables = page_tables
-        # clamp junk lanes (prefill bucket padding) instead of relying on
-        # gather clipping: positions past the wpe table or the page table
-        # width belong to masked lanes whose output is discarded
-        pos_c = jnp.minimum(pos, max_pos - 1)
-        x = wte[tokens] + wpe[pos_c]
+        row_tables = (page_tables if Q is None
+                      else jnp.repeat(page_tables, Q, axis=0))
+        x = _gpt_embed(p, tokens, pos, max_pos)
+        # positions past the page table width belong to masked lanes too
         page_of = jnp.minimum(pos // page_size, pages_per_seq - 1)
         page_idx = jnp.take_along_axis(row_tables, page_of[:, None],
                                        axis=1)[:, 0]
         slot = pos % page_size
         seq_lens = pos + 1
-        if valid_len is not None:
+        if vlen is not None:
             # padded lanes write to the trash page and attend to nothing
             # past the real prompt — live pages stay untouched
-            page_idx = jnp.where(pos < valid_len, page_idx, 0)
-            seq_lens = jnp.minimum(seq_lens, valid_len)
-        ks, vs = [], []
-        ksc_out, vsc_out = [], []
-        for i in range(L):
-            h = _ln(x, lp(i, "ln1.weight"), lp(i, "ln1.bias"))
-            q = (mm(h, f"layers.{i}.attn.q_proj.weight")
-                 + lp(i, "attn.q_proj.bias")).reshape(N, H, D)
-            # k1/v1 stay [N, H*D]: the projection's rows ARE the pool's
-            # rows, scattered in place on the donated buffer
-            k1 = (mm(h, f"layers.{i}.attn.k_proj.weight")
-                  + lp(i, "attn.k_proj.bias"))
-            v1 = (mm(h, f"layers.{i}.attn.v_proj.weight")
-                  + lp(i, "attn.v_proj.bias"))
+            page_idx = jnp.where(pos < vlen, page_idx, 0)
+            seq_lens = jnp.minimum(seq_lens, vlen)
+        if sp > 1:
+            # global -> shard-local page ids: a non-owned row scatters
+            # into this shard's reserved trash row (local 0, a global
+            # reserved page) and attention masks pages by OWNERSHIP, so
+            # each chip holds and streams 1/sp of every sequence's KV
+            sp_i = jax.lax.axis_index(spn)
+            pages_local = kv["k"][0].shape[0]
+            page_idx = jnp.where((page_idx // pages_local) == sp_i,
+                                 page_idx % pages_local, 0)
+            pt_owner = (page_tables // pages_local) == sp_i
+            page_tables = jnp.where(pt_owner, page_tables % pages_local, 0)
+        kv_out = {key: [] for key in kv}
+
+        def attend(i, q, k1, v1):
+            # k1/v1 stay [N, H_loc*D]: the projection's rows ARE the
+            # pool's rows, scattered in place on the donated buffer
             if quant_kv:
-                kc, ksc = _quant_write_page(
+                kc, ks_ = _quant_write_page(
                     kv["k"][i], kv["k_scale"][i], page_idx, slot,
-                    k1.reshape(N, H, D), k_sc[i] if k_sc else None)
-                vc, vsc = _quant_write_page(
+                    k1.reshape(N, H_loc, D), ksc[i] if ksc else None)
+                vc, vs_ = _quant_write_page(
                     kv["v"][i], kv["v_scale"][i], page_idx, slot,
-                    v1.reshape(N, H, D), v_sc[i] if v_sc else None)
-                ksc_out.append(ksc)
-                vsc_out.append(vsc)
-                scales = (ksc, vsc)
+                    v1.reshape(N, H_loc, D), vsc[i] if vsc else None)
+                kv_out["k_scale"].append(ks_)
+                kv_out["v_scale"].append(vs_)
+                scales = (ks_, vs_)
             else:
                 kc = kv["k"][i].at[page_idx, slot].set(k1)
                 vc = kv["v"][i].at[page_idx, slot].set(v1)
                 scales = ()
-            if qgroup is None:
-                ctx = paged_attn(q, kc, vc, page_tables, seq_lens,
-                                 *scales).reshape(N, hidden)
+            kv_out["k"].append(kc)
+            kv_out["v"].append(vc)
+            if Q is None:
+                ctx = paged_attn(q.reshape(N, H_loc, D), kc, vc,
+                                 page_tables, seq_lens, *scales)
+                return ctx.reshape(N, hidden)
+            qg = q.reshape(N // Q, Q, H_loc, D)
+            sl = seq_lens.reshape(N // Q, Q)
+            if sp == 1:
+                ctx = ragged_paged_attn(qg, kc, vc, page_tables, sl,
+                                        *scales)
             else:
-                G = N // qgroup
-                ctx = ragged_paged_attn(
-                    q.reshape(G, qgroup, H, D), kc, vc, page_tables,
-                    seq_lens.reshape(G, qgroup), *scales).reshape(N, hidden)
-            ks.append(kc)
-            vs.append(vc)
-            x = x + (mm(ctx, f"layers.{i}.attn.out_proj.weight")
-                     + lp(i, "attn.out_proj.bias"))
-            h2 = _ln(x, lp(i, "ln2.weight"), lp(i, "ln2.bias"))
-            ff = _gelu(mm(h2, f"layers.{i}.fc1.weight") + lp(i, "fc1.bias"))
-            x = x + mm(ff, f"layers.{i}.fc2.weight") + lp(i, "fc2.bias")
-        kv_out = {"k": ks, "v": vs}
-        if quant_kv:
-            kv_out["k_scale"] = ksc_out
-            kv_out["v_scale"] = vsc_out
-        if not with_head:
-            return None, kv_out
-        x = _ln(x, params["ln_f.weight"], params["ln_f.bias"])
-        return x @ wte.T, kv_out                             # tied head
+                # partial-softmax exchange: each shard reduces over its
+                # OWNED pages only, then the running-max / denominator
+                # stats merge across sp in lse space (the
+                # ring_attention.py recipe)
+                o, lse = ragged_stats(qg, kc, vc, page_tables, sl,
+                                      pt_owner, *scales)
+                mx = jax.lax.pmax(lse, spn)
+                w = jnp.exp(lse - mx)
+                num = jax.lax.psum(o * w[..., None], spn)
+                den = jax.lax.psum(w, spn)
+                ctx = num / jnp.maximum(den, 1e-30)[..., None]
+            if tp > 1:
+                ctx = jax.lax.all_gather(ctx.reshape(N, H_loc, D), tpn,
+                                         axis=1, tiled=True)
+            return ctx.reshape(N, hidden)
+
+        for i in range(L):
+            x = _gpt_block(p, mm, i, x, attend,
+                           tp_axis=tpn if tp > 1 else None)
+        return (_gpt_head(p, x) if with_head else None), kv_out
+
+    def core(tokens, pos, page_tables, kv, valid_len=None, with_head=True,
+             qgroup=None):
+        Q = None if qgroup is None else int(qgroup)
+        if not meshed:
+            return body(consts, tokens, pos, page_tables, valid_len, kv,
+                        with_head, Q)
+        if Q is None:
+            raise NotImplementedError(
+                "the mesh-sharded paged core serves the unified ragged "
+                "layout only (the mesh engine runs ragged=True)")
+        has_vl = valid_len is not None
+
+        def shard(consts_l, tokens, pos, page_tables, vlen, kv_l):
+            logits, kv_out = body(consts_l, tokens, pos, page_tables,
+                                  vlen if has_vl else None, kv_l,
+                                  with_head, Q)
+            return (logits, kv_out) if with_head else kv_out
+
+        kvs = layout.kv_spec(kv)
+        # check_vma=False for the whole core, because the one typing
+        # problem cannot be opted out of alone: the logits are replicated
+        # in VALUE (every shard all-gathers the same context), but jax
+        # types an all_gather result as varying, jax 0.9 has no public
+        # invariant all_gather (`all_gather_invariant` lives in jax._src)
+        # and pcast only goes invariant -> varying.  The value assumption
+        # is pinned by the byte-identity tests in test_serving_mesh.py.
+        f = jax.shard_map(shard, mesh=mesh,
+                          in_specs=(cspecs, P(), P(), P(), P(), kvs),
+                          out_specs=(P(), kvs) if with_head else kvs,
+                          check_vma=False)
+        out = f(consts, tokens, pos, page_tables,
+                valid_len if has_vl else jnp.zeros((), jnp.int32), kv)
+        return out if with_head else (None, out)
 
     return core, init_pages
 
@@ -1016,10 +912,10 @@ def make_gpt_paged_ragged_step(model, page_size: int, pages_per_seq: int, *,
     ``with_guard=True`` negative-packs non-finite rows in-band, exactly
     like the split programs; the clean argmax still feeds device state.
 
-    ``mesh_layout`` (ISSUE 19) builds the step over the mesh-sharded
-    core: same host-visible contract, device state sharded per the
-    layout — the engine's one-mixed-batch-program-per-step dispatch
-    drives tp*sp chips.
+    ``mesh_layout`` (ISSUE 19) runs the same core under the mesh: same
+    host-visible contract, device state sharded per the layout — the
+    engine's one-mixed-batch-program-per-step dispatch drives tp*sp
+    chips.
     """
     core, init_pages = _make_gpt_paged_core(
         model, page_size, pages_per_seq, kv_cache_dtype=kv_cache_dtype,
@@ -1086,7 +982,7 @@ def generate(model, input_ids, max_new_tokens: int = 32, end_id: int = 0,
     ids = ids.astype(jnp.int32)
     B, P = ids.shape
     max_len = P + max_new_tokens + 1
-    max_pos = model.wpe.weight.shape[0]
+    max_pos = _gpt_geometry(model)[4]
     if P + max_new_tokens > max_pos:
         # past the wpe table the gather would silently clamp positions —
         # degraded text with no error (review r4)

@@ -1,6 +1,6 @@
 """tools/analyze AST lint suite (ISSUE 7) — planted-violation fixtures
 per checker, live-repo cleanliness, and the CLI exit-code contract
-(bench_diff-style, in-process `main(argv)` plus one stdlib-only
+(in-process `main(argv)` plus one stdlib-only
 subprocess proving `python -m tools.analyze`).
 """
 import os

@@ -203,8 +203,7 @@ class TestDecodeThreadScaling:
     """Decode-path scaling evidence (VERDICT r4 next-round #9): the
     pthread partition must be thread-count-INVARIANT in output, and the
     recorded rates demonstrate scaling wherever cores exist (this CI
-    image has 1 core — rates are recorded with that caveat; bench.py
-    records the same table into BENCH detail)."""
+    image has 1 core — rates are recorded with that caveat)."""
 
     def _samples(self, n=48, size=96):
         from paddle_tpu.vision.image_pipeline import synthetic_jpeg_dataset

@@ -1,7 +1,7 @@
 """CLI driver: ``python -m tools.analyze [--check NAME] [--baseline]
 [--changed-only]``.
 
-Exit codes (pinned by tests/test_analyze.py, bench_diff-style):
+Exit codes (pinned by tests/test_analyze.py):
 
 - 0  no findings beyond the committed baseline
 - 1  new findings (printed as ``file:line CODE message``)
