@@ -396,6 +396,46 @@ class _Pending:
         self.lanes = lanes
 
 
+class SnapshotCapture:
+    """One request checkpoint CAPTURED but not yet on the host: the host
+    half of the snapshot (consumed tokens, ``pos``, drafter state) read
+    at capture, plus the device handles of the gathered KV pages whose
+    copy to the host was started and not waited for.  The gather sits in
+    stream order behind every step already dispatched and ahead of every
+    later one, so the pages are those ``jax.device_get`` would have
+    returned at capture — however many steps run before
+    ``ServingEngine.land_snapshot`` turns this into an EngineSnapshot.
+    ``stale`` tells a request that finished, was aborted or was
+    preempted since (by ``(seq, epoch)``, the ``_Pending`` rule): the
+    pages are still the capture's, but the periodic path drops it."""
+
+    __slots__ = ("seq", "epoch", "generated", "pos", "rows", "gathered",
+                 "spec", "created_at", "land_wait_s")
+
+    def __init__(self, seq: Sequence, generated: np.ndarray, pos: int,
+                 rows: int, gathered: Optional[dict],
+                 spec: Optional[dict]):
+        self.seq = seq
+        self.epoch = seq.epoch
+        self.generated = generated
+        self.pos = pos
+        self.rows = rows              # live pages of the pow2-padded gather
+        self.gathered = gathered      # device arrays; None once landed
+        self.spec = spec
+        self.created_at = time.monotonic()
+        # host seconds land_snapshot waited for the bytes (None: not
+        # landed) — ~0 when the copy crossed under a running step
+        self.land_wait_s: Optional[float] = None
+
+    @property
+    def request_id(self) -> str:
+        return self.seq.seq_id
+
+    @property
+    def stale(self) -> bool:
+        return self.seq.done or self.seq.epoch != self.epoch
+
+
 class ServingEngine:
     """Continuous-batching serving over a paged KV cache."""
 
@@ -1101,12 +1141,31 @@ class ServingEngine:
         currently decoding (queued / preempted-back-to-queue / finished
         — the caller keeps its previous snapshot).
 
+        The SYNCHRONOUS form — ``capture_snapshot`` landed at once: the
+        caller waits for the in-flight step and for the pages to cross
+        to the host.  Callers that need the bytes now (the prefill →
+        decode ship, tests) use it; the frontend's periodic checkpoint
+        captures in one pump turn and lands in the next instead.
+
         Consistency: ``generated`` is the CONSUMED stream (what the
         token_callback has emitted); the pages may additionally contain
         writes from a still-in-flight dispatch — harmless, the resumed
         decode deterministically rewrites every position >= ``pos``.
         Call from the thread that drives ``step()`` (the pump thread).
         """
+        cap = self.capture_snapshot(request_id)
+        return None if cap is None else self.land_snapshot(cap)
+
+    def capture_snapshot(self, request_id: str
+                         ) -> Optional[SnapshotCapture]:
+        """The half of ``snapshot`` that costs its caller no wait: read
+        ``generated`` / ``pos`` / the page ids on the host, enqueue the
+        page gather behind the dispatched steps, start its copy to the
+        host, fetch nothing.  Same refusals as ``snapshot`` (None: not
+        decoding, or mid prefill plan).  ``land_snapshot`` finishes it —
+        any number of steps later: pages this request frees meanwhile,
+        and whoever is prefilled into them, are behind the gather in
+        stream order.  Pump thread only."""
         seq = next((s for s in self.scheduler.running
                     if s.seq_id == request_id and not s.done), None)
         if seq is None:
@@ -1122,14 +1181,38 @@ class ServingEngine:
         pos = seq.request.prompt.size - 1 + g
         need = self.cache.pages_needed(pos)
         rows = self.cache.seq_page_ids(request_id)[:need]
-        pages: Dict[str, List[np.ndarray]] = {"k": [], "v": []}
-        mode = self.kv_mode()
+        gathered = None
         if rows:
             padded = np.zeros((next_pow2(len(rows)),), np.int32)
             padded[: len(rows)] = rows
-            got = jax.device_get(
-                self._page_gather_jit(self._kv, self._dput(padded)))
-            R = len(rows)
+            gathered = self._page_gather_jit(self._kv, self._dput(padded))
+            for a in jax.tree_util.tree_leaves(gathered):
+                a.copy_to_host_async()
+        spec_state = None
+        if self.spec is not None:
+            # the drafter's adaptive lane state rides along so a
+            # resumed request keeps speculating where the donor left
+            # off (its n-gram index rebuilds from prompt + generated)
+            spec_state = self.spec.drafter.export_lane(request_id) or None
+        return SnapshotCapture(seq, np.asarray(seq.generated, np.int32),
+                               int(pos), len(rows), gathered, spec_state)
+
+    def land_snapshot(self, cap: SnapshotCapture) -> EngineSnapshot:
+        """Finish a capture: wait for its pages on the host (the wait is
+        left in ``cap.land_wait_s``), release the gathered device
+        buffers, build the EngineSnapshot — of the request as it stood
+        at capture, whatever became of it since (``cap.stale`` is the
+        caller's to ask)."""
+        gathered, cap.gathered = cap.gathered, None
+        seq = cap.seq
+        pages: Dict[str, List[np.ndarray]] = {"k": [], "v": []}
+        mode = self.kv_mode()
+        t0 = time.perf_counter()
+        got = None if gathered is None else jax.device_get(gathered)
+        cap.land_wait_s = time.perf_counter() - t0
+        del gathered
+        if got is not None:
+            R = cap.rows
             if mode == "int8_dynamic":
                 # dynamic per-page scales are device state owned by the
                 # donor pool: store DEQUANTIZED pages (restore re-derives
@@ -1149,19 +1232,13 @@ class ServingEngine:
             else:
                 for side in ("k", "v"):
                     pages[side] = [np.asarray(p[:R]) for p in got[side]]
-        spec_state = None
-        if self.spec is not None:
-            # the drafter's adaptive lane state rides along so a
-            # resumed request keeps speculating where the donor left
-            # off (its n-gram index rebuilds from prompt + generated)
-            spec_state = self.spec.drafter.export_lane(request_id) or None
         snap = EngineSnapshot(
-            request_id=request_id, prompt=seq.request.prompt,
+            request_id=cap.request_id, prompt=seq.request.prompt,
             max_new_tokens=seq.request.max_new_tokens,
             deadline=seq.request.deadline,
-            generated=np.asarray(seq.generated, np.int32), pos=int(pos),
+            generated=cap.generated, pos=cap.pos,
             kv_mode=mode, page_size=self.page_size, pages=pages,
-            spec=spec_state)
+            created_at=cap.created_at, spec=cap.spec)
         self.metrics.on_snapshot(snap.nbytes)
         return snap
 

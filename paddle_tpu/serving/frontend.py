@@ -1242,8 +1242,11 @@ class ServingFrontend:
         """One replica's drive loop (the ONLY thread touching its
         engine): intake (add or snapshot-restore) → cancellations →
         brownout sheds → one engine step (crash-contained, watchdog-
-        probed) → harvest expiries/completions → periodic snapshots →
-        chaos / failure-injection checks."""
+        probed) → harvest expiries/completions → periodic snapshots
+        (land the previous turn's captures under the step just
+        dispatched, capture this turn's) → chaos / failure-injection
+        checks.  No capture outlives the loop: a turn without a step
+        and the exit both drop what is pending."""
         eng = rep.engine
         while True:
             with self._lock:
@@ -1291,7 +1294,7 @@ class ServingFrontend:
                     self._maybe_snapshot(rep, eng)
                     if rep.role == "prefill":
                         self._ship_ready(rep, eng)
-                    # snapshot/ship calls SYNC a pipelined engine: a
+                    # a ship's abort SYNCs a pipelined engine: a
                     # request whose final token was still in flight at
                     # the harvest above retires during that sync, and
                     # with no work left the pump would idle with its
@@ -1307,11 +1310,16 @@ class ServingFrontend:
                     self._kill(rep,
                                f"injected failure at step {rep.steps}")
                     break
-            elif closing:
-                break
             else:
+                if rep.captures:
+                    # nothing is running: what the last turn captured
+                    # belongs to requests that finished since
+                    self._drop_captures(rep)
+                if closing:
+                    break
                 rep.wake.wait(self._poll_interval)
                 rep.wake.clear()
+        self._drop_captures(rep)
 
     def _intake(self, eng: ServingEngine, work, cancels, sheds):
         """The pump's intake: hand the inbox to the engine (add or
@@ -1365,49 +1373,85 @@ class ServingFrontend:
             # else: it finished first — the outputs harvest owns it
 
     def _maybe_snapshot(self, rep: Replica, eng: ServingEngine):
-        """Checkpoint every request on ``rep`` that consumed
-        ``snapshot_interval`` tokens since its last snapshot — the warm
-        failover freshness bound (≤ K tokens ever need recomputing)."""
+        """The periodic warm-failover checkpoint, off the pump's
+        critical path.  First LAND what the previous turn captured —
+        this turn's ``eng.step()`` has just put a new step on the
+        device, so the wait for the bytes (if any is left) costs the
+        device nothing — then CAPTURE every request on ``rep`` that
+        consumed ``snapshot_interval`` tokens since its last landed
+        snapshot: page gather enqueued in stream order, copy to the
+        host started, nothing fetched.  A capture is landed or dropped
+        within one pump turn, so a replica death costs a request at
+        most ``snapshot_interval`` tokens plus the step in flight."""
         if self.snapshot_interval is None:
             return
+        self._land_captures(rep, eng)
         k = self.snapshot_interval
         with self._lock:
             due = [e for e in self._live.values()
                    if e.replica is rep and e.in_engine
                    and not e.cancel_requested and not e.shed_requested
                    and e.handle.num_tokens - e.snap_tokens >= k]
+        captured = []
         for entry in due:
-            # the request's KV pages come off the device here, on the
-            # pump thread: nothing is dispatched while they do
             with RecordEvent("serving/snapshot"):
-                snap = eng.snapshot(entry.handle.request_id)
-            if snap is None:
-                continue          # finished/preempted meanwhile — keep old
-            updated = False
+                cap = eng.capture_snapshot(entry.handle.request_id)
+            if cap is not None:   # else finished/preempted meanwhile
+                captured.append((entry, cap))
+        if captured:
             with self._lock:
-                if (self._live.get(entry.handle.request_id) is entry
-                        and entry.replica is rep):
-                    entry.snapshot = snap
-                    entry.snap_tokens = snap.num_generated
-                    updated = True
-            if updated:
-                flight.request_event(entry.handle.request_id,
-                                     EV_SNAPSHOT, replica=rep.id,
+                rep.captures.extend(captured)
+
+    def _land_captures(self, rep: Replica, eng: ServingEngine):
+        """Land (or drop) every capture pending on ``rep``: host arrays
+        materialised, EngineSnapshot installed under the live-entry
+        guard, gathered device buffers released.  A request that
+        finished, was cancelled, shed or preempted since its capture
+        installs nothing and keeps its previous snapshot."""
+        with self._lock:
+            pending, rep.captures = rep.captures, []
+        for entry, cap in pending:
+            rid = entry.handle.request_id
+            with RecordEvent("serving/snapshot"):
+                if cap.stale:
+                    self.engine_metrics.on_snapshot_dropped()
+                    continue
+                snap = eng.land_snapshot(cap)
+                with self._lock:
+                    live = (self._live.get(rid) is entry
+                            and entry.replica is rep)
+                    if live:
+                        entry.snapshot = snap
+                        entry.snap_tokens = snap.num_generated
+                if not live:
+                    self.engine_metrics.on_snapshot_dropped()
+                    continue
+                self.engine_metrics.on_snapshot_landed(cap.land_wait_s)
+                flight.request_event(rid, EV_SNAPSHOT, replica=rep.id,
                                      tokens=snap.num_generated)
-            if updated and self._snapshot_store is not None:
-                # disk durability rides on the warm-failover checkpoint
-                # (pump thread, outside the frontend lock).  Best-effort:
-                # a persist failure never fails the live stream — the
-                # in-memory snapshot still drives warm failover; the
-                # error count is surfaced in stats()["resilience"]
-                rid = entry.handle.request_id
-                try:
-                    self._snapshot_store.save_named(
-                        f"req-{rid}", snap.to_state(),
-                        metadata={"request_id": rid})
-                except Exception:  # noqa: BLE001 — durability degraded,
-                    with self._lock:  # stream unaffected
-                        self._persist_errors += 1
+                if self._snapshot_store is not None:
+                    # disk durability rides on the warm-failover
+                    # checkpoint (pump thread, outside the frontend
+                    # lock).  Best-effort: a persist failure never fails
+                    # the live stream — the in-memory snapshot still
+                    # drives warm failover; the error count is surfaced
+                    # in stats()["resilience"]
+                    try:
+                        self._snapshot_store.save_named(
+                            f"req-{rid}", snap.to_state(),
+                            metadata={"request_id": rid})
+                    except Exception:  # noqa: BLE001 — durability
+                        with self._lock:  # degraded, stream unaffected
+                            self._persist_errors += 1
+
+    def _drop_captures(self, rep: Replica):
+        """Discard ``rep``'s pending captures unlanded (replica death,
+        pump exit): the gathered device buffers go with the last
+        reference, every request keeps its last LANDED snapshot."""
+        with self._lock:
+            dropped, rep.captures = rep.captures, []
+        if dropped:
+            self.engine_metrics.on_snapshot_dropped(len(dropped))
 
     def _ship_ready(self, rep: Replica, eng: ServingEngine):
         """Disaggregation hand-off (ISSUE 16): move every request on a
@@ -1519,6 +1563,9 @@ class ServingFrontend:
             rep.inbox.clear()
             rep.cancels.clear()
             rep.sheds.clear()
+        # the dead engine's device state is suspect: what it captured
+        # and had not landed is dropped, never installed
+        self._drop_captures(rep)
         now = time.monotonic()
         for entry in victims:
             h = entry.handle

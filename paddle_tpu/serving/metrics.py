@@ -87,6 +87,10 @@ class ServingMetrics:
                 "serving.deadline_miss", "serving.snapshots",
                 "serving.restores", "serving.watchdog_trips",
                 "serving.retries_backoff",
+                # periodic checkpoints captured on the device and never
+                # landed on the host: the request ended or was preempted
+                # before the next pump turn, or its replica died
+                "serving.snapshots_dropped",
                 # prefix cache (ISSUE 10): per-admission hit/miss, the
                 # prefill tokens the hits skipped, LRU page evictions,
                 # and copy-on-write page copies on divergence
@@ -145,6 +149,9 @@ class ServingMetrics:
                   # arrival (the frontend's submit time) to admission
                   "serving.queue_wait_ms",
                   "serving.failover_recovery_ms",
+                  # host wait of one periodic checkpoint's landing for
+                  # its pages — ~0 when the copy crossed under a step
+                  "serving.snapshot_land_wait_ms",
                   # disaggregation (ISSUE 16): one prefill→decode ship,
                   # snapshot-gather through re-admission on the decode
                   # replica
@@ -232,6 +239,19 @@ class ServingMetrics:
         snapshot's size (tokens + KV pages, host bytes)."""
         stat_registry.get("serving.snapshots").add(1)
         stat_registry.get("serving.snapshot_bytes").set(int(nbytes))
+
+    def on_snapshot_landed(self, wait_seconds: float):
+        """A periodic checkpoint captured the turn before reached the
+        host; ``wait_seconds`` is what the pump waited for its pages
+        (near zero when the copy hid under the running step)."""
+        stat_registry.histogram("serving.snapshot_land_wait_ms").observe(
+            wait_seconds * 1e3)
+
+    def on_snapshot_dropped(self, n: int = 1):
+        """Captured checkpoints discarded unlanded (request ended or
+        preempted meanwhile, replica died, pump exit) — each request
+        keeps its last landed snapshot."""
+        stat_registry.get("serving.snapshots_dropped").add(n)
 
     def on_restore(self, n: int = 1):
         """A snapshot was re-admitted mid-stream (warm failover)."""
@@ -497,9 +517,9 @@ class ServingMetrics:
         snap["aborts"] = stat_registry.get("serving.aborts").get()
         snap["deadline_miss"] = stat_registry.get(
             "serving.deadline_miss").get()
-        for short in ("snapshots", "restores", "watchdog_trips",
-                      "retries_backoff", "brownout_stage",
-                      "snapshot_bytes"):
+        for short in ("snapshots", "snapshots_dropped", "restores",
+                      "watchdog_trips", "retries_backoff",
+                      "brownout_stage", "snapshot_bytes"):
             snap[short] = stat_registry.get(f"serving.{short}").get()
         snap["prefix"] = {
             short: stat_registry.get(f"serving.prefix.{short}").get()

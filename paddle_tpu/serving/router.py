@@ -108,6 +108,10 @@ class Replica:
         self.inbox: List = []                # guarded by the frontend lock
         self.cancels: List = []              # guarded by the frontend lock
         self.sheds: List = []                # guarded by the frontend lock
+        # periodic checkpoints captured last pump turn, landed in the
+        # next: (entry, SnapshotCapture) pairs (frontend lock; the pump
+        # fills and lands it, _kill empties it)
+        self.captures: List = []
         self.wake = threading.Event()
         self.thread: Optional[threading.Thread] = None
         # engine steps taken by the pump thread — the fault-injection

@@ -25,9 +25,13 @@ Acceptance bars exercised here:
 The full randomized chaos soak is ``slow``-marked (tier-1 runs
 ``-m 'not slow'``).
 """
+import gc
+import threading
 import time
+import weakref
 from collections import Counter
 
+import jax
 import numpy as np
 import pytest
 
@@ -524,6 +528,362 @@ class TestWarmFailover:
             assert fe.engine_metrics.snapshot()["restores"] >= len(resumed)
         finally:
             fe.close()
+
+
+# =============================================================================
+# Deferred periodic checkpoints (ISSUE 31): captured on the device in
+# stream order in one pump turn, landed on the host in the next
+# =============================================================================
+class _SnapshotTap:
+    """Records, in the order the pump thread does them, every capture
+    (``capture_snapshot`` that returned one), landing (``land_snapshot``)
+    and device-to-host fetch of gathered KV pages (``jax.device_get`` of
+    a page dict) with the replica's step count at that moment, keeps a
+    weak reference to every gathered device array, and runs
+    ``on_capture(eng, cap)`` on the pump thread right after a capture —
+    the deterministic place to arm a kill, a cancel or a denial between
+    a capture and its landing."""
+
+    def __init__(self, monkeypatch):
+        self.events = []    # (kind, rid, generated, replica steps, replica)
+        self.refs = []
+        self.unfetched_after_turn = []
+        self.on_capture = None
+        self.fe = None
+        real_capture = ServingEngine.capture_snapshot
+        real_land = ServingEngine.land_snapshot
+        real_maybe = ServingFrontend._maybe_snapshot
+        real_get = jax.device_get
+
+        def capture(eng, rid):
+            cap = real_capture(eng, rid)
+            if cap is not None:
+                self.refs += [weakref.ref(a)
+                              for arrs in (cap.gathered or {}).values()
+                              for a in arrs]
+                self._note("capture", eng, cap)
+                if self.on_capture is not None:
+                    self.on_capture(eng, cap)
+            return cap
+
+        def land(eng, cap):
+            self._note("land", eng, cap)
+            return real_land(eng, cap)
+
+        def device_get(x):
+            if isinstance(x, dict) and "k" in x:
+                self.events.append(("fetch",))
+            return real_get(x)
+
+        def maybe_snapshot(fe, rep, eng):
+            real_maybe(fe, rep, eng)
+            # what the turn leaves pending has no host value yet
+            self.unfetched_after_turn += [
+                getattr(a, "_npy_value", None) is None
+                for _, cap in rep.captures
+                for arrs in (cap.gathered or {}).values() for a in arrs]
+
+        monkeypatch.setattr(ServingEngine, "capture_snapshot", capture)
+        monkeypatch.setattr(ServingEngine, "land_snapshot", land)
+        monkeypatch.setattr(ServingFrontend, "_maybe_snapshot",
+                            maybe_snapshot)
+        monkeypatch.setattr(jax, "device_get", device_get)
+
+    def replica(self, eng):
+        return next(r for r in self.fe._replicas if r.engine is eng)
+
+    def _note(self, kind, eng, cap):
+        rep = self.replica(eng)
+        self.events.append((kind, cap.request_id, len(cap.generated),
+                            rep.steps, rep.id))
+
+    def of(self, kind, rid=None):
+        return [e for e in self.events
+                if e[0] == kind and rid in (None, e[1])]
+
+    def assert_released(self):
+        gc.collect()
+        assert self.refs, "no page gather was ever captured"
+        assert all(r() is None for r in self.refs)
+        assert all(not rep.captures for rep in self.fe._replicas)
+
+
+@pytest.fixture
+def tap(monkeypatch):
+    return _SnapshotTap(monkeypatch)
+
+
+def _dropped():
+    from paddle_tpu.framework.monitor import stat_registry
+
+    return stat_registry.get("serving.snapshots_dropped").get()
+
+
+class TestDeferredSnapshots:
+    K = 4
+    FE_KW = dict(queue_cap=8, snapshot_interval=K,
+                 engine_kwargs=dict(page_size=4, max_batch_size=4,
+                                    eos_id=-1))
+
+    @pytest.mark.parametrize("mode",
+                             ["native", "int8_static", "int8_dynamic"])
+    def test_capture_landed_later_equals_snapshot_at_capture(
+            self, gpt, quant, mode):
+        """(a) The gather sits in stream order: a capture landed after
+        the request finished, its pages were freed and ANOTHER request
+        was prefilled into them is, array for array, the synchronous
+        ``snapshot()`` taken at the moment of capture."""
+        kw = dict(page_size=4, max_batch_size=4, eos_id=-1, num_pages=6)
+        if mode != "native":
+            kw["kv_cache_dtype"] = "int8"
+        if mode == "int8_static":
+            kw["quant_scales"] = quant
+        rng = np.random.RandomState(31)
+        eng = ServingEngine(gpt, **kw)
+        assert eng.kv_mode() == mode
+        a = eng.add_request(rng.randint(1, VOCAB, (6,)).astype(np.int32),
+                            max_new_tokens=8)
+        TestSnapshotRestore()._run_until(eng, a, 5)
+        cap = eng.capture_snapshot(a)
+        want = eng.snapshot(a)
+        held = set(eng.cache.seq_page_ids(a)[:cap.rows])
+        assert cap.rows == want.num_pages >= 3 and not cap.stale
+        # five usable pages: B's prompt fits only once A is gone
+        b = eng.add_request(rng.randint(1, VOCAB, (12,)).astype(np.int32),
+                            max_new_tokens=4)
+        reused = set()
+        while eng.scheduler.has_work() or eng._pending:
+            eng.step()
+            reused |= held & set(eng.cache.seq_page_ids(b))
+        assert cap.stale and a in eng.outputs and b in eng.outputs
+        assert reused, "B was never prefilled into A's freed pages"
+        got = eng.land_snapshot(cap)
+        assert cap.gathered is None and cap.land_wait_s >= 0
+        assert (got.request_id, got.pos, got.kv_mode, got.page_size,
+                got.max_new_tokens, got.nbytes) == (
+            want.request_id, want.pos, want.kv_mode, want.page_size,
+            want.max_new_tokens, want.nbytes)
+        np.testing.assert_array_equal(got.generated, want.generated)
+        np.testing.assert_array_equal(got.prompt, want.prompt)
+        for side in ("k", "v"):
+            assert len(got.pages[side]) == len(want.pages[side]) == LAYERS
+            for g, w in zip(got.pages[side], want.pages[side]):
+                assert g.dtype == w.dtype and g.shape == w.shape
+                np.testing.assert_array_equal(g, w)
+        gs, ws = got.to_state(), want.to_state()
+        assert sorted(gs) == sorted(ws)
+        assert eng.cache.pages_in_use == 0
+
+    def test_capturing_turn_fetches_nothing_next_turn_lands_before_kill(
+            self, gpt, tap):
+        """(b) The turn that captures performs no device-to-host fetch of
+        KV pages; the NEXT turn lands the capture before its
+        failure-injection check — a replica armed to die on that turn
+        hands the request over at exactly the captured checkpoint."""
+        fe = tap.fe = ServingFrontend(gpt, replicas=2, **self.FE_KW)
+        armed = []
+
+        def die_next_turn(eng, cap):
+            if not armed:
+                rep = tap.replica(eng)
+                armed.append(rep)
+                rep.fail_at_step = rep.steps + 1
+
+        tap.on_capture = die_next_turn
+        try:
+            prompt = np.array([3, 5, 9], np.int32)
+            h = fe.submit(prompt, max_new_tokens=14)
+            assert h.wait(timeout=300) == "completed"
+            rid = h.request_id
+            first = tap.of("capture", rid)[0]
+            assert first[2] == self.K
+            i = tap.events.index(first)
+            # next thing the pump did for snapshots: land it, one turn on
+            assert tap.events[i + 1] == ("land", rid, self.K, first[3] + 1,
+                                         first[4])
+            assert tap.events[i + 2][0] == "fetch"
+            for j, ev in enumerate(tap.events):
+                if ev[0] == "fetch":
+                    assert tap.events[j - 1][0] == "land"
+            assert tap.unfetched_after_turn \
+                and all(tap.unfetched_after_turn)
+            assert armed[0].state == DEAD
+            assert h.retried and h.resumed_from == self.K
+            np.testing.assert_array_equal(
+                h.tokens, _reference(gpt, prompt, 14)[:14])
+            hist = fe.engine_metrics.snapshot()["snapshot_land_wait_ms"]
+            assert hist["count"] == len(tap.of("land")) >= 1
+        finally:
+            fe.close()
+        tap.assert_released()
+
+    def test_kill_with_captures_pending_resumes_from_last_landed(
+            self, gpt, tap):
+        """(c) A replica that dies between a capture and its landing
+        drops the capture: every victim resumes from its last LANDED
+        snapshot, within snapshot_interval + one step of what it had
+        consumed, byte-identical; the counter counts the dropped."""
+        K = self.K
+        fe = tap.fe = ServingFrontend(gpt, replicas=2, **self.FE_KW)
+        kill = {}
+
+        def die_this_turn(eng, cap):
+            if not kill and len(cap.generated) >= 2 * K:
+                rep = tap.replica(eng)
+                with fe._lock:
+                    consumed = {e.handle.request_id: e.handle.num_tokens
+                                for e in fe._live.values()
+                                if e.replica is rep}
+                kill.update(rep=rep, steps=rep.steps, consumed=consumed)
+                rep.fail_at_step = rep.steps
+
+        tap.on_capture = die_this_turn
+        try:
+            rng = np.random.RandomState(17)
+            prompts = [rng.randint(1, VOCAB, (p,)).astype(np.int32)
+                       for p in (3, 6, 5)]
+            handles = [fe.submit(p, max_new_tokens=14) for p in prompts]
+            assert [h.wait(timeout=300) for h in handles] \
+                == ["completed"] * 3
+            rep = kill["rep"]
+            assert rep.state == DEAD
+            pending = [e for e in tap.of("capture")
+                       if e[3:] == (kill["steps"], rep.id)]
+            # every capture is landed one turn later or dropped, the
+            # pending ones of the dying turn among the dropped
+            lands = {(rid, g, steps - 1, at) for _, rid, g, steps, at in
+                     tap.of("land")}
+            unlanded = [e for e in tap.of("capture")
+                        if e[1:] not in lands]
+            assert pending and set(pending) <= set(unlanded)
+            assert _dropped() == len(unlanded)
+            landed = {}
+            for _, rid, g, _, at in tap.of("land"):
+                if at == rep.id:
+                    landed[rid] = g
+            victims = [h for h in handles
+                       if h.request_id in kill["consumed"]]
+            assert victims and all(h.retried for h in victims)
+            for h in victims:
+                last = landed.get(h.request_id)
+                assert h.resumed_from == last
+                assert kill["consumed"][h.request_id] - (last or 0) \
+                    <= K + 1
+            assert any(h.resumed_from == K for h in victims)
+            for p, h in zip(prompts, handles):
+                np.testing.assert_array_equal(
+                    h.tokens, _reference(gpt, p, 14)[:14])
+            for r in fe._replicas:
+                if r.state != DEAD:
+                    assert r.engine.cache.pages_in_use == 0
+        finally:
+            fe.close()
+        tap.assert_released()
+
+    @pytest.mark.parametrize("fate", ["completes", "cancelled",
+                                      "preempted"])
+    def test_request_gone_before_landing_installs_and_leaks_nothing(
+            self, gpt, tap, fate):
+        """(d) A request that completes, is cancelled or is preempted
+        between its capture and the landing turn installs nothing (its
+        capture is dropped unfetched) and leaks nothing."""
+        K = self.K
+        fe = tap.fe = ServingFrontend(gpt, replicas=1, **self.FE_KW)
+        eng = fe._replicas[0].engine
+        prompts = [np.array([3, 5, 9], np.int32),
+                   np.array([7, 2, 8, 4], np.int32)]
+        budget = K + 1 if fate == "completes" else 14
+        state = {}
+
+        def between(eng_, cap):
+            if "hit" in state or cap.request_id != state["target"]:
+                return
+            state["hit"] = cap
+            if fate == "cancelled":
+                state["handle"].cancel()
+            elif fate == "preempted":
+                # an allocation denied to the OLDER request evicts the
+                # youngest other one — the captured request
+                real = eng.cache.allocate
+
+                def deny_once(seq_id, n):
+                    if seq_id == state["other"] and "denied" not in state:
+                        state["denied"] = True
+                        return False
+                    return real(seq_id, n)
+
+                eng.cache.allocate = deny_once
+
+        tap.on_capture = between
+        try:
+            if fate == "preempted":
+                older = fe.submit(prompts[1], max_new_tokens=budget)
+                state["other"] = older.request_id
+            h = fe.submit(prompts[0], max_new_tokens=budget)
+            state["handle"], state["target"] = h, h.request_id
+            want = "cancelled" if fate == "cancelled" else "completed"
+            assert h.wait(timeout=300) == want
+            cap = state.pop("hit")
+            assert cap.stale and cap.land_wait_s is None
+            del cap
+            c = next(e for e in tap.of("capture", h.request_id))
+            # the turn after the capture landed nothing of this request
+            assert ("land", h.request_id, c[2], c[3] + 1, c[4]) \
+                not in tap.events
+            assert _dropped() >= 1
+            if fate == "preempted":
+                assert state.get("denied")
+                assert older.wait(timeout=300) == "completed"
+                np.testing.assert_array_equal(
+                    older.tokens, _reference(gpt, prompts[1], 14)[:14])
+                assert eng.scheduler.num_preemptions >= 1
+            else:
+                assert not tap.of("land") and not tap.of("fetch")
+                assert fe.engine_metrics.snapshot()["snapshots"] == 0
+            if fate != "cancelled":
+                np.testing.assert_array_equal(
+                    h.tokens, _reference(gpt, prompts[0], budget)[:budget])
+            assert eng.cache.pages_in_use == 0
+        finally:
+            fe.close()
+        tap.assert_released()
+
+    def test_close_with_captures_pending_leaves_no_device_buffer(
+            self, gpt, tap):
+        """(e) ``close()`` called while a capture is pending returns;
+        the pump lands or drops what it holds on its way out and no
+        gathered device buffer stays alive."""
+        fe = tap.fe = ServingFrontend(gpt, replicas=1, **self.FE_KW)
+        captured, go = threading.Event(), threading.Event()
+
+        def hold(eng, cap):
+            if not captured.is_set():
+                captured.set()
+                assert go.wait(timeout=60)
+
+        tap.on_capture = hold
+        closer = threading.Thread(target=fe.close)
+        try:
+            h = fe.submit(np.array([3, 5, 9], np.int32), max_new_tokens=14)
+            assert captured.wait(timeout=300)
+            closer.start()
+            deadline = time.monotonic() + 60
+            while not fe._closing and time.monotonic() < deadline:
+                time.sleep(0.001)
+            assert fe._closing and fe._replicas[0].captures == []
+            go.set()
+            closer.join(timeout=120)
+            assert not closer.is_alive()
+            # closing drains what was admitted: the stream finished and
+            # its pending capture landed on the way
+            assert h.status == "completed"
+            assert tap.of("land", h.request_id)
+            assert fe._replicas[0].engine.cache.pages_in_use == 0
+        finally:
+            go.set()
+            if closer.is_alive() or not closer.ident:
+                fe.close()
+        tap.assert_released()
 
 
 # =============================================================================
