@@ -493,7 +493,8 @@ def _err_and_scale(got, want):
 def kernels_phase():
     import jax
 
-    from paddle_tpu.ops.pallas_ops.cases import (kernel_cases, mixer_cases,
+    from paddle_tpu.ops.pallas_ops.cases import (grouped_cases,
+                                                 kernel_cases, mixer_cases,
                                                  serve_cell_case)
 
     lines, failed = 0, []
@@ -537,6 +538,12 @@ def kernels_phase():
     for _, label, kernel, twin, args in mixer_cases():
         lines += 1
         line("mixers    ", label, kernel, twin, args)
+    # the three flash kernels with 32 query heads over 8 KV heads of 64
+    # (grouped-query attention): K and V are read in place, dk/dv summed
+    # over each group inside the kernel
+    for _, label, kernel, twin, args in grouped_cases():
+        lines += 1
+        line("grouped   ", label, kernel, twin, args)
     # a kernel may be left refused only while the option selecting it is
     # refused at engine construction; this tree leaves none, so any
     # refusal or mismatch fails the run

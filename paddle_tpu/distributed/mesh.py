@@ -10,7 +10,10 @@ reference's Group/ring APIs can be reproduced on top.
 Canonical axis names: 'dp' (data), 'mp' (tensor/model), 'pp' (pipeline),
 'sp' (sequence/context), 'ep' (expert).  No exchange rides 'ep' yet:
 nn.SparseExpertShare is told which experts it holds (`experts_held` = start
-and count of the router's published width) and computes their part alone.
+and count of the router's published width) and computes their part alone —
+in both expert models that wait for the axis, text.models.KimiLinearModel
+(8 of 256 experts a chip, EP32) and text.models.Lfm2MoeModel (8 of 32,
+EP4).
 """
 from __future__ import annotations
 

@@ -21,6 +21,14 @@ import jax
 import numpy as np
 
 
+@jax.jit
+def _split_pair(key):
+    # one dispatch for (next key, subkey); unpacking jax.random.split's
+    # result on the host costs two more
+    nxt, sub = jax.random.split(key)
+    return nxt, sub
+
+
 class Generator:
     """The key is built from the seed on FIRST USE, not at construction:
     ``jax.random.key`` initialises the backend, and ``import paddle_tpu``
@@ -31,6 +39,7 @@ class Generator:
     def __init__(self, seed: int = 0):
         self._seed = seed
         self._key = None
+        self._ahead = None      # (key, its split) — see split_ahead
         self._lock = threading.Lock()
 
     def _live_key(self):
@@ -49,10 +58,28 @@ class Generator:
     def set_state(self, key):
         self._key = key
 
+    def _split_of(self, key):
+        """(key, next key, subkey): the split of `key`, from split_ahead
+        if that already ran on this very key object."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None or ahead[0] is not key:
+            ahead = (key, *_split_pair(key))
+        return ahead
+
     def split_key(self):
         with self._lock:
-            self._key, sub = jax.random.split(self._live_key())
+            _, self._key, sub = self._split_of(self._live_key())
             return sub
+
+    def split_ahead(self):
+        """Dispatch now the split the next ``split_key()`` will hand out,
+        and change nothing: the state stays the key it was, and the
+        result is kept only for that key object (a reseed, ``set_state``
+        or ``set_state_dict`` in between drops it).  A caller about to
+        wait on the device (a train step's loss) takes the next step's
+        split off the host's path between two steps this way."""
+        with self._lock:
+            self._ahead = self._split_of(self._live_key())
 
     def state_dict(self):
         """Serializable snapshot of the generator (exact-resume leaf:

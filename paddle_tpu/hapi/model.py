@@ -284,10 +284,11 @@ class Model:
         x = inputs[0]
         y = labels[0] if labels else None
         with RecordEvent("hapi/train_batch/inputs"):
-            xv = x._value if isinstance(x, Tensor) \
-                else jnp.asarray(np.asarray(x))
-            yv = y._value if isinstance(y, Tensor) \
-                else jnp.asarray(np.asarray(y))
+            # host arrays stay host arrays: the jitted step moves them with
+            # its own dispatch (same avals), a put of their own is one more
+            # round of the runtime between two steps
+            xv = x._value if isinstance(x, Tensor) else np.asarray(x)
+            yv = y._value if isinstance(y, Tensor) else np.asarray(y)
 
         if self._accelerate:
             self._ensure_state()
@@ -307,6 +308,7 @@ class Model:
                     (self._state, loss, out,
                      gn, ok) = self._train_step(self._state, key, xv, yv)
                     self._publish_counters()
+                    default_generator.split_ahead()
                 with RecordEvent("hapi/train_batch/fetch_loss"):
                     lossf = float(np.asarray(loss))
                     okb = bool(np.asarray(ok))
@@ -325,6 +327,8 @@ class Model:
                 self._state, loss, out = self._train_step(
                     self._state, key, xv, yv)
                 self._publish_counters()
+                # the next step's key, while this one runs
+                default_generator.split_ahead()
             with RecordEvent("hapi/train_batch/metrics"):
                 metrics_out = self._update_metrics(out, yv)
             with RecordEvent("hapi/train_batch/fetch_loss"):

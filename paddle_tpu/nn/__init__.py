@@ -12,8 +12,8 @@ from .layer import Layer  # noqa: F401
 from .loss_layers import *  # noqa: F401,F403
 from .norm_layers import *  # noqa: F401,F403
 from .hybrid_layers import (  # noqa: F401
-    GatedRMSNorm, KimiDeltaAttention, LatentAttention, RMSNorm, ShortConv1D,
-    SparseExpertShare, SwiGLU)
+    GatedRMSNorm, GatedShortConv, GroupedQueryAttention, KimiDeltaAttention,
+    LatentAttention, RMSNorm, ShortConv1D, SparseExpertShare, SwiGLU)
 from .pool_layers import *  # noqa: F401,F403
 
 # sequence / attention stacks

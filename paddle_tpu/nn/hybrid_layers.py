@@ -1,7 +1,9 @@
-"""Layers of hybrid linear-attention / sparse-expert decoders (the
-Kimi-Linear family): RMSNorm plain and sigmoid-gated, a SwiGLU FFN, a
-causal depthwise short convolution, the gated-delta-rule mixer (KDA), NoPE
-latent attention (MLA), and one chip's share of a sparse-expert layer.
+"""Layers of hybrid sequence-mixer / sparse-expert decoders (the
+Kimi-Linear and LFM2 families): RMSNorm plain and sigmoid-gated, a SwiGLU
+FFN, a causal depthwise short convolution, the gated-delta-rule mixer
+(KDA), NoPE latent attention (MLA), the gated short-convolution mixer,
+grouped-query attention with per-head QK-norm and rotary positions, and
+one chip's share of a sparse-expert layer.
 
 Every Linear is without bias.  Norms and gates compute in float32 and
 return their input's dtype; the matmuls follow `amp.auto_cast`.
@@ -68,19 +70,26 @@ class SwiGLU(Layer):
 class ShortConv1D(Layer):
     """Causal depthwise convolution over the sequence axis of [B, T, C],
     one filter of `kernel_size` taps a channel (weight [C, kernel_size],
-    the last tap on the current token), then SiLU."""
+    the last tap on the current token, zero history), then `activation`:
+    "silu" (the default, as the KDA mixer's branches take it) or None for
+    the taps alone (the gated short-convolution mixer gates outside)."""
 
-    def __init__(self, channels, kernel_size=4):
+    def __init__(self, channels, kernel_size=4, activation="silu"):
         super().__init__()
+        if activation not in ("silu", None):
+            raise ValueError(f"activation {activation!r}: 'silu' or None")
+        self._activation = activation
         self.weight = self.create_parameter(shape=[channels, kernel_size])
 
     def forward(self, x):
+        silu = self._activation == "silu"
+
         def conv(v, w):
             taps, T = w.shape[1], v.shape[1]
             padded = jnp.pad(v, ((0, 0), (taps - 1, 0), (0, 0)))
             y = sum(padded[:, i:i + T].astype(jnp.float32)
                     * w[:, i].astype(jnp.float32) for i in range(taps))
-            return jax.nn.silu(y).astype(v.dtype)
+            return (jax.nn.silu(y) if silu else y).astype(v.dtype)
 
         return apply("short_conv1d", conv, x, self.weight)
 
@@ -183,6 +192,69 @@ class LatentAttention(Layer):
         return self.o_proj(o.reshape([B, T, H * self.v_dim]))
 
 
+class GatedShortConv(Layer):
+    """The gated short-convolution mixer of the LFM2 family: one
+    projection to three streams [B, C, X]; z = B * X; a causal depthwise
+    convolution of `kernel_size` taps over z with no activation
+    (`ShortConv1D(..., activation=None)`); out_proj(C * conv(z)).  Its
+    whole state is the last `kernel_size - 1` tokens of z."""
+
+    def __init__(self, hidden_size, kernel_size=3):
+        super().__init__()
+        self.in_proj = Linear(hidden_size, 3 * hidden_size, bias_attr=False)
+        self.conv = ShortConv1D(hidden_size, kernel_size, activation=None)
+        self.out_proj = Linear(hidden_size, hidden_size, bias_attr=False)
+
+    def forward(self, x):
+        d = x.shape[-1]
+        bcx = self.in_proj(x)
+        gate_in, gate_out, v = (bcx[:, :, i * d:(i + 1) * d]
+                                for i in range(3))
+        return self.out_proj(gate_out * self.conv(gate_in * v))
+
+
+class GroupedQueryAttention(Layer):
+    """Causal self-attention with `num_kv_heads` K/V heads shared by
+    `num_heads` query heads (query head h reads KV head
+    h // (num_heads / num_kv_heads)), RMSNorm over each head of q and k
+    (one weight of the head size, hidden_size / num_heads, each), rotary
+    positions over the whole head (rotate-half, base `rope_theta`,
+    position = index in the sequence; ops/rotary), softmax(q k^T /
+    sqrt(head size)) v through the attention op — the flash kernels read
+    the shared K/V heads in place."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads,
+                 rope_theta=10000.0, epsilon=1e-5):
+        super().__init__()
+        if num_heads % num_kv_heads or hidden_size % num_heads:
+            raise ValueError(
+                f"{num_kv_heads} KV heads must divide {num_heads} query "
+                f"heads, and those the hidden size {hidden_size}")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = hidden_size // num_heads
+        self.rope_theta = float(rope_theta)
+        lin = lambda i, o: Linear(i, o, bias_attr=False)
+        self.q_proj = lin(hidden_size, num_heads * self.head_dim)
+        self.k_proj = lin(hidden_size, num_kv_heads * self.head_dim)
+        self.v_proj = lin(hidden_size, num_kv_heads * self.head_dim)
+        self.q_norm = RMSNorm(self.head_dim, epsilon)
+        self.k_norm = RMSNorm(self.head_dim, epsilon)
+        self.o_proj = lin(num_heads * self.head_dim, hidden_size)
+
+    def forward(self, x):
+        from ..ops.rotary import rotary_embedding
+
+        B, T, _ = x.shape
+        H, G, D = self.num_heads, self.num_kv_heads, self.head_dim
+        q = self.q_norm(self.q_proj(x).reshape([B, T, H, D]))
+        k = self.k_norm(self.k_proj(x).reshape([B, T, G, D]))
+        v = self.v_proj(x).reshape([B, T, G, D])
+        q = rotary_embedding(q, self.rope_theta)
+        k = rotary_embedding(k, self.rope_theta)
+        o = F.scaled_dot_product_attention(q, k, v, is_causal=True)
+        return self.o_proj(o.reshape([B, T, H * D]))
+
+
 class SparseExpertShare(Layer):
     """One chip's share of a sparse-expert FFN under expert parallelism.
 
@@ -193,7 +265,10 @@ class SparseExpertShare(Layer):
     renormalised, times `routed_scale`); only the chosen experts held here
     are computed, grouped over the tokens routed to them, none dropped;
     the shared expert, which every chip computes alike, is added (the
-    routed part alone is `ops.moe.sparse_expert_share`).  The exchange
+    routed part alone is `ops.moe.sparse_expert_share`, and the layer's
+    whole result with `shared_expert=False`, for a model that has none:
+    no `shared` sublayer is built then; the default builds and adds it).
+    The exchange
     with the chips that hold the other experts is not implemented: what
     they would have added is left out of the result.
 
@@ -204,7 +279,7 @@ class SparseExpertShare(Layer):
 
     def __init__(self, hidden_size, expert_size, num_experts_published,
                  experts_held, experts_per_token, routed_scale=1.0,
-                 renormalize=True):
+                 renormalize=True, shared_expert=True):
         super().__init__()
         self.start, held = experts_held
         assert 0 <= self.start and self.start + held <= num_experts_published
@@ -220,7 +295,8 @@ class SparseExpertShare(Layer):
             shape=[held, hidden_size, expert_size])
         self.experts_down = self.create_parameter(
             shape=[held, expert_size, hidden_size])
-        self.shared = SwiGLU(hidden_size, expert_size)
+        self.shared = SwiGLU(hidden_size, expert_size) \
+            if shared_expert else None
 
     def forward(self, x):
         from ..ops.moe import sparse_expert_share
@@ -229,4 +305,6 @@ class SparseExpertShare(Layer):
             x, self.router.weight, self.correction_bias, self.experts_gate,
             self.experts_up, self.experts_down, self.start, self.k,
             self.scale, self.renormalize)
-        return y + self.shared(x), counts
+        if self.shared is not None:
+            y = y + self.shared(x)
+        return y, counts

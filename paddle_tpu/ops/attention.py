@@ -35,8 +35,12 @@ ROUTE_STATS = {"pallas": 0, "xla": 0}
 
 
 def _sdpa_core(q, k, v, mask, dropout_p, is_causal, key, scale=None):
-    # q,k,v: [B, H, S, D]
+    # q: [B, H, S, D]; k, v: [B, Hkv, S, D], Hkv dividing H (query head h
+    # reads KV head h // (H // Hkv))
     d = q.shape[-1]
+    if k.shape[1] != q.shape[1]:
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = scale if scale is not None else 1.0 / (d**0.5)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * s
     if is_causal:
@@ -85,7 +89,9 @@ def _as_kv_mask(mask_val, B, S):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.0,
                                  is_causal=False, training=True, name=None):
-    """Inputs [B, S, H, D] (paddle convention); returns [B, S, H, D].
+    """Inputs [B, S, H, D] (paddle convention); returns [B, S, H, D].  key
+    and value may have fewer heads than query, a divisor of its count:
+    query head h then reads KV head h // (H // Hkv).
 
     Routes to the Pallas flash kernel when the mask is padding-style (or
     absent) and shapes/platform allow; otherwise XLA-fused attention.
@@ -228,9 +234,13 @@ def _pallas_ok(q, k=None) -> bool:
         # "the headline kernel is effectively bench-only")
         return False
     B, S, H, D = q.shape
-    # v's head size is free (latent attention: q/k 192, v 128)
-    if k is not None and tuple(k.shape) != (B, S, H, D):
-        return False  # cross-attention with different kv length: XLA path
+    # v's head size is free (latent attention: q/k 192, v 128), and so is
+    # the count of KV heads where it divides the query heads' (grouped-query
+    # attention)
+    if k is not None:
+        Bk, Sk, Hk, Dk = k.shape
+        if (Bk, Sk, Dk) != (B, S, D) or H % Hk:
+            return False  # cross-attention (another kv length): XLA path
     if not forced and S < 128:
         return False  # short sequences: XLA fused attention is already fine
     return D <= 256

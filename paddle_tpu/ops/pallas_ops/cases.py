@@ -16,7 +16,8 @@ import numpy as np
 
 from .contracts import CONTRACTS
 
-__all__ = ["KernelCase", "kernel_cases", "mixer_cases", "serve_cell_case",
+__all__ = ["KernelCase", "kernel_cases", "mixer_cases", "grouped_cases",
+           "serve_cell_case",
            "flash_inputs", "paged_inputs", "qmm_inputs"]
 
 
@@ -139,25 +140,18 @@ def qmm_inputs(M, K, N):
                         / 127.0))
 
 
-def kernel_cases(heads, head_dim):
-    """The cases at (heads, head_dim): f32 inputs of unit scale; the paged
-    kernels take ``interpret=False``, the flash wrappers decide from
-    ``flash_attention._interpret_mode()`` (the chip, or a test's patch)."""
+def _flash_cases(qkvg, mask, seed, block, label=""):
+    """The three flash kernels on (q, k, v, g) [B, H or Hkv, S, D] in
+    square causal blocks of `block`, the backward kernels on the forward's
+    own statistics, each against XLA attention and its vjp."""
     import jax
     import jax.numpy as jnp
 
     from ..attention import _sdpa_core
     from . import flash_attention as fa
-    from . import paged_attention as pa
-    from . import quantized_matmul as qm
 
-    H, D = heads, head_dim
-    cases = []
-
-    # --- flash: fwd, then the two backward kernels on the fwd's stats ----
-    B, S = 1, FLASH_SEQ
-    qkvg, mask, seed = flash_inputs(B, H, S, D)
-    tail = (1.0 / float(np.sqrt(D)), True, 0.0, FLASH_BLOCK, FLASH_BLOCK)
+    B, H, S, D = qkvg[0].shape
+    tail = (1.0 / float(np.sqrt(D)), True, 0.0, block, block)
 
     def xla_attn(q, k, v):
         return _sdpa_core(q, k, v, None, 0.0, True, None)
@@ -180,14 +174,29 @@ def kernel_cases(heads, head_dim):
         return fa._flash_dq_bhsd(q, k, v, g, *flash_stats(q, k, v, g),
                                  mask, seed, *tail)
 
-    cases += [
-        KernelCase("flash_attention_fwd", "flash fwd", flash_fwd,
+    return [
+        KernelCase("flash_attention_fwd", "flash fwd" + label, flash_fwd,
                    lambda q, k, v, g: xla_attn(q, k, v), qkvg),
-        KernelCase("flash_attention_bwd_dkv", "flash bwd dk/dv", flash_dkv,
-                   lambda *a: xla_grads(*a)[1:], qkvg),
-        KernelCase("flash_attention_bwd_dq", "flash bwd dq", flash_dq,
-                   lambda *a: xla_grads(*a)[0], qkvg),
+        KernelCase("flash_attention_bwd_dkv", "flash bwd dk/dv" + label,
+                   flash_dkv, lambda *a: xla_grads(*a)[1:], qkvg),
+        KernelCase("flash_attention_bwd_dq", "flash bwd dq" + label,
+                   flash_dq, lambda *a: xla_grads(*a)[0], qkvg),
     ]
+
+
+def kernel_cases(heads, head_dim):
+    """The cases at (heads, head_dim): f32 inputs of unit scale; the paged
+    kernels take ``interpret=False``, the flash wrappers decide from
+    ``flash_attention._interpret_mode()`` (the chip, or a test's patch)."""
+    from . import paged_attention as pa
+    from . import quantized_matmul as qm
+
+    H, D = heads, head_dim
+    cases = []
+
+    # --- flash: fwd, then the two backward kernels on the fwd's stats ----
+    qkvg, mask, seed = flash_inputs(1, H, FLASH_SEQ, D)
+    cases += _flash_cases(qkvg, mask, seed, FLASH_BLOCK)
 
     # --- paged: ragged, decode (ragged at Q = 1) and stats, x native/int8 --
     def paged(int8):
@@ -297,3 +306,18 @@ def mixer_cases(heads=4, seq=FLASH_SEQ + 72):
                    delta_grads(la.gated_delta_rule_chunked),
                    delta_grads(la.gated_delta_rule_recurrent), delta),
     ]
+
+
+def grouped_cases(heads=32, kv_heads=8, head_dim=64, seq=FLASH_SEQ,
+                  block=FLASH_BLOCK):
+    """The three flash kernels with fewer KV heads than query heads (the
+    grouped-query attention of the LFM2 models: 32 / 8 heads of 64), each
+    against its XLA twin on K and V repeated to the query heads' count:
+    the forward and dq kernels read KV head h // group through their
+    index maps, the dk/dv kernel sums the group in its scratch.  Square
+    blocks of `block`, 2 x 2 of them by default, so the causal skip and
+    the walk over a group's heads both run."""
+    (q, _, _, g), mask, seed = flash_inputs(1, heads, seq, head_dim)
+    (k, v, _, _), _, _ = flash_inputs(1, kv_heads, seq, head_dim)
+    return _flash_cases((q, k, v, g), mask, seed, block,
+                        f" {heads}/{kv_heads} heads x {head_dim}")

@@ -200,6 +200,15 @@ class KernelContract:
 # kernels.  Block defaults tuned on v5e @ S=4096, D=128 (see the module
 # docstring); the wrapper's _pick_block halves them to a divisor for
 # shorter (always x128-padded) sequences.
+#
+# Grouped-query attention (ISSUE 33) changes no block: with `group` query
+# heads to a KV head, k and v stay [B * kv_heads, S, D] in HBM and the
+# forward and dq kernels' k/v index maps read block (b // group, j) —
+# "batch_heads" counts QUERY heads there.  The dk/dv kernel's first grid
+# axis counts KV heads and its last walks the q blocks of each of the
+# group's heads in turn (group * q_blocks steps), so dk/dv are summed
+# over the group in the float32 scratch the contract already declares
+# and written once.  At group 1 all three are the programs they were.
 # ===========================================================================
 FLASH_FWD = KernelContract(
     name="flash_attention_fwd",
@@ -232,7 +241,7 @@ FLASH_FWD = KernelContract(
 FLASH_BWD_DKV = KernelContract(
     name="flash_attention_bwd_dkv",
     module="paddle_tpu/ops/pallas_ops/flash_attention.py",
-    grid=("batch_heads", "k_blocks", "q_blocks"),
+    grid=("batch_kv_heads", "k_blocks", "group_q_blocks"),
     dims={"block_q": 512, "block_k": 1024, "head_dim": 128},
     blocks=(
         BlockDecl("seed", "in", (1,), "int32", memory="smem"),

@@ -160,14 +160,20 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, mask_ref, dk_ref, dv_ref, dk_sc, dv_sc, *,
-                    scale, causal, dropout_p, block_q, block_k, nq):
-    """Grid (BH, nk, nq): fixed KV block, stream q/do blocks, accumulate
-    dk/dv in VMEM scratch."""
+                    scale, causal, dropout_p, block_q, block_k, nq, group=1):
+    """Grid (B*Hkv, nk, group*nq): fixed KV block, stream q/do blocks,
+    accumulate dk/dv in VMEM scratch.  With `group` query heads to a KV
+    head the innermost axis walks the group's heads one after another, so
+    dk/dv are summed over the group in the float32 scratch and written
+    once."""
     b = pl.program_id(0)
     jj = pl.program_id(1)
-    ii = pl.program_id(2)
+    t = ii = pl.program_id(2)
+    if group > 1:
+        # b the query head (the dropout hash's index), ii its q block
+        b, ii = b * group + t // nq, t % nq
 
-    @pl.when(ii == 0)
+    @pl.when(t == 0)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
@@ -209,7 +215,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_sc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
 
-    @pl.when(ii == nq - 1)
+    @pl.when(t == group * nq - 1)
     def _write():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
@@ -279,16 +285,28 @@ def _sds(shape, dtype, ref):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(ref).vma)
 
 
+def _kv_block(group):
+    """Index map of a K or V block under a grid whose first axis is the
+    QUERY head (batch * H + h) and whose last is the KV block: query head
+    h reads KV head h // group straight from the [B * Hkv, S, D] array, so
+    no copy of K or V at the query heads' count is ever written."""
+    if group == 1:
+        return lambda b, i, j: (b, j, 0)
+    return lambda b, i, j: (b // group, j, 0)
+
+
 def _flash_fwd_bhsd(q, k, v, mask, seed, scale, causal, dropout_p,
                     block_q, block_k):
     B, H, S, D = q.shape
     Dv = v.shape[-1]            # the v/o head size may differ from q/k's
+    Hkv = k.shape[1]            # fewer KV heads: each serves H // Hkv
+    kv = _kv_block(H // Hkv)
     nk = S // block_k
     grid = (B * H, S // block_q, nk)
 
     q3 = q.reshape(B * H, S, D)
-    k3 = k.reshape(B * H, S, D)
-    v3 = v.reshape(B * H, S, Dv)
+    k3 = k.reshape(B * Hkv, S, D)
+    v3 = v.reshape(B * Hkv, S, Dv)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -298,8 +316,8 @@ def _flash_fwd_bhsd(q, k, v, mask, seed, scale, causal, dropout_p,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # seed
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), kv),
+            pl.BlockSpec((1, block_k, Dv), kv),
             pl.BlockSpec((1, 1, block_k), lambda b, i, j, h=H: (b // h, 0, j)),
         ],
         out_specs=[
@@ -329,34 +347,42 @@ def _flash_dkv_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
     GLOBAL per-row stats of the visiting queries — summing chunk results
     over all visiting q sets gives the exact global dk/dv."""
     B, H, Sq, D = q.shape
-    Sk, Dv = k.shape[2], v.shape[-1]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    group = H // Hkv
     q3 = q.reshape(B * H, Sq, D)
-    k3 = k.reshape(B * H, Sk, D)
-    v3 = v.reshape(B * H, Sk, Dv)
+    k3 = k.reshape(B * Hkv, Sk, D)
+    v3 = v.reshape(B * Hkv, Sk, Dv)
     g3 = g.reshape(B * H, Sq, Dv)
     nq, nk = Sq // block_q, Sk // block_k
+    if group == 1:
+        rows = lambda b, jj, ii: (b, ii, 0)
+    else:
+        # the grid's first axis is the KV head; its last walks the q
+        # blocks of each of the group's query heads in turn
+        rows = lambda b, jj, t: (b * group + t // nq, t % nq, 0)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, nq=nq, scale=scale, causal=causal,
                           dropout_p=dropout_p, block_q=block_q,
-                          block_k=block_k),
-        grid=(B * H, nk, nq),
+                          block_k=block_k, group=group),
+        grid=(B * Hkv, nk, group * nq),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, D), lambda b, jj, ii: (b, ii, 0)),
+            pl.BlockSpec((1, block_q, D), rows),
             pl.BlockSpec((1, block_k, D), lambda b, jj, ii: (b, jj, 0)),
             pl.BlockSpec((1, block_k, Dv), lambda b, jj, ii: (b, jj, 0)),
-            pl.BlockSpec((1, block_q, Dv), lambda b, jj, ii: (b, ii, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, jj, ii: (b, ii, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, jj, ii: (b, ii, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, jj, ii, h=H: (b // h, 0, jj)),
+            pl.BlockSpec((1, block_q, Dv), rows),
+            pl.BlockSpec((1, block_q, 1), rows),
+            pl.BlockSpec((1, block_q, 1), rows),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda b, jj, ii, h=Hkv: (b // h, 0, jj)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, jj, ii: (b, jj, 0)),
             pl.BlockSpec((1, block_k, Dv), lambda b, jj, ii: (b, jj, 0)),
         ],
         out_shape=[
-            _sds((B * H, Sk, D), k.dtype, k3),
-            _sds((B * H, Sk, Dv), v.dtype, k3),
+            _sds((B * Hkv, Sk, D), k.dtype, k3),
+            _sds((B * Hkv, Sk, Dv), v.dtype, k3),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
@@ -365,17 +391,18 @@ def _flash_dkv_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
         compiler_params=_compiler_params(),
         interpret=_interpret_mode(),
     )(seed, q3, k3, v3, g3, lse, delta, mask)
-    return dk.reshape(B, H, Sk, D), dv.reshape(B, H, Sk, Dv)
+    return dk.reshape(B, Hkv, Sk, D), dv.reshape(B, Hkv, Sk, Dv)
 
 
 def _flash_dq_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
                    dropout_p, block_q, block_k):
     """dq for the local queries against one kv chunk (global lse/delta)."""
     B, H, Sq, D = q.shape
-    Sk, Dv = k.shape[2], v.shape[-1]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    kv = _kv_block(H // Hkv)
     q3 = q.reshape(B * H, Sq, D)
-    k3 = k.reshape(B * H, Sk, D)
-    v3 = v.reshape(B * H, Sk, Dv)
+    k3 = k.reshape(B * Hkv, Sk, D)
+    v3 = v.reshape(B * Hkv, Sk, Dv)
     g3 = g.reshape(B * H, Sq, Dv)
     nq, nk = Sq // block_q, Sk // block_k
     dq = pl.pallas_call(
@@ -386,8 +413,8 @@ def _flash_dq_bhsd(q, k, v, g, lse, delta, mask, seed, scale, causal,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), kv),
+            pl.BlockSpec((1, block_k, Dv), kv),
             pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
@@ -509,10 +536,15 @@ def flash_attention_bshd(q, k, v, causal=False, kv_mask=None, dropout_p=0.0,
     applied in-kernel with deterministic counter-based bits (`seed`).
     Sequence length and head_dim are padded to kernel-friendly shapes
     internally and sliced back.  v's head size may differ from q/k's; the
-    scale is 1/sqrt(q/k head size).
+    scale is 1/sqrt(q/k head size).  k and v may have fewer heads than q
+    (grouped-query attention): query head h reads KV head h // (H // Hkv)
+    inside the kernels, and dk / dv come back at the KV heads' count.
     """
     B, S, H, D = q.shape
-    Dv = v.shape[-1]
+    Dv, Hkv = v.shape[-1], k.shape[2]
+    if H % Hkv or v.shape[2] != Hkv:
+        raise ValueError(f"{H} query heads over {Hkv} / {v.shape[2]} KV "
+                         f"heads: the KV heads must divide the query heads")
     scale = 1.0 / math.sqrt(D)
 
     Sp = -(-S // _LANE) * _LANE
@@ -557,7 +589,9 @@ def flash_attention_bshd(q, k, v, causal=False, kv_mask=None, dropout_p=0.0,
 
     spec = getattr(_partition, "spec", None)
     if spec is not None:
-        core = _shard_over(core, spec, B, H, per_shard_seed=dropout_p > 0.0)
+        # a head axis must divide the KV heads (which divide the query's)
+        core = _shard_over(core, spec, B, Hkv,
+                           per_shard_seed=dropout_p > 0.0)
     out = jnp.swapaxes(core(qt, kt, vt, mask, seed), 1, 2)
     if Sp != S or Dvp != Dv:
         out = out[:, :S, :, :Dv]

@@ -12,4 +12,5 @@ from .models import (  # noqa: F401
     BertModel,
     GPTModel,
     KimiLinearModel,
+    Lfm2MoeModel,
 )

@@ -213,71 +213,62 @@ class HybridDecoderLayer(nn.Layer):
         return x + h
 
 
-class KimiLinearModel(nn.Layer):
-    """Decoder-only LM of the Kimi-Linear family: layers of different
-    kinds by a per-layer table — `layer_kinds[i]` is "kda" (gated
-    delta-rule linear attention) or "mla" (NoPE latent attention) — a
+class _HybridDecoderLM(nn.Layer):
+    """What the hybrid decoder LMs share: token embedding, a stack of
+    HybridDecoderLayers — a mixer by the per-layer table `layer_kinds`, a
     dense SwiGLU FFN in the first `first_dense` layers and one chip's share
-    of a sparse-expert layer in the rest (`experts_held` = (start, count)
-    of `num_experts_published`; nn.SparseExpertShare), RMSNorm, an untied
-    head.  `vocab_size` is the rows held here: a slice of the vocabulary is
-    a smaller vocabulary.  `recompute=True` rematerialises each layer in
-    the backward pass (fleet.recompute) while training.
+    of a sparse-expert layer in the rest — each rematerialised in the
+    backward pass while training where `recompute` (fleet.recompute), a
+    final RMSNorm and a head: its own Linear, or the embedding matrix
+    transposed.  `vocab_size` is the rows held here: a slice of the
+    vocabulary is a smaller vocabulary.
 
     The buffer `moe_routed_tokens` [sparse layers, count + 1] adds up, step
     by step inside the step's own buffers, the assignments routed to each
     held expert and (last column) to absent ones; hapi publishes it as the
     counter `moe.routed_tokens` (framework.monitor) without a host sync.
     It is float32 and never reset: exact to 2**24 a column and in
-    proportion beyond, where an int32 would wrap within a long run (the
-    last column takes ~63,500 a step of 8,192 tokens: 33,800 steps)."""
+    proportion beyond, where an int32 would wrap within a long run."""
 
     step_counters = {"moe.routed_tokens": "moe_routed_tokens"}
+    _family = ""        # the spans are text/<_family>/build and /forward
+    _kinds = {}         # layer kind -> its count's name in the build span
 
-    def __init__(self, vocab_size, hidden_size, layer_kinds, num_heads,
-                 kda_head_dim, kv_lora_rank, qk_nope_head_dim,
-                 qk_rope_head_dim, v_head_dim, intermediate_size,
-                 moe_intermediate_size, num_experts_published, experts_held,
-                 experts_per_token, routed_scale=1.0, renormalize=True,
-                 first_dense=1, conv_size=4, gate_rank=None, epsilon=1e-5,
-                 recompute=False):
-        super().__init__()
+    def _build(self, vocab_size, hidden_size, layer_kinds, make_mixer,
+               intermediate_size, first_dense, experts, epsilon, recompute,
+               tie_head):
+        """`make_mixer(kind)` builds one layer's mixer; `experts` are
+        nn.SparseExpertShare's arguments after the hidden size."""
         from ..utils.profiler import RecordEvent
 
         kinds = list(layer_kinds)
-        with RecordEvent("text/kimi_linear/build", layers=len(kinds),
-                         kda_layers=kinds.count("kda"),
-                         mla_layers=kinds.count("mla"),
-                         experts_held=int(experts_held[1]),
-                         experts_published=int(num_experts_published)):
+        for kind in kinds:
+            if kind not in self._kinds:
+                raise ValueError(f"layer kind {kind!r}: "
+                                 f"{' or '.join(self._kinds)}")
+        held = int(experts["experts_held"][1])
+
+        def layer(i):
+            mixer = make_mixer(kinds[i])
+            ffn = nn.SwiGLU(hidden_size, intermediate_size) \
+                if i < first_dense \
+                else nn.SparseExpertShare(hidden_size, **experts)
+            return HybridDecoderLayer(hidden_size, mixer, ffn, epsilon)
+
+        with RecordEvent(
+                f"text/{self._family}/build", layers=len(kinds),
+                **{name: kinds.count(kind)
+                   for kind, name in self._kinds.items()},
+                experts_held=held,
+                experts_published=int(experts["num_experts_published"])):
             self.recompute = recompute
             self.embed_tokens = nn.Embedding(vocab_size, hidden_size)
-            layers = []
-            for i, kind in enumerate(kinds):
-                if kind == "kda":
-                    mixer = nn.KimiDeltaAttention(
-                        hidden_size, num_heads, kda_head_dim, conv_size,
-                        gate_rank, epsilon)
-                elif kind == "mla":
-                    mixer = nn.LatentAttention(
-                        hidden_size, num_heads, kv_lora_rank,
-                        qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
-                        epsilon)
-                else:
-                    raise ValueError(f"layer kind {kind!r}: kda or mla")
-                ffn = nn.SwiGLU(hidden_size, intermediate_size) \
-                    if i < first_dense else nn.SparseExpertShare(
-                        hidden_size, moe_intermediate_size,
-                        num_experts_published, experts_held,
-                        experts_per_token, routed_scale, renormalize)
-                layers.append(HybridDecoderLayer(hidden_size, mixer, ffn,
-                                                 epsilon))
-            self.layers = nn.LayerList(layers)
+            self.layers = nn.LayerList([layer(i) for i in range(len(kinds))])
             self.norm = nn.RMSNorm(hidden_size, epsilon)
-            self.lm_head = nn.Linear(hidden_size, vocab_size, bias_attr=False)
-            sparse = max(0, len(kinds) - first_dense)
+            self.lm_head = None if tie_head else nn.Linear(
+                hidden_size, vocab_size, bias_attr=False)
             self.register_buffer("moe_routed_tokens", Tensor(np.zeros(
-                (sparse, int(experts_held[1]) + 1), np.float32)),
+                (max(0, len(kinds) - first_dense), held + 1), np.float32)),
                 persistable=False)
 
     def forward(self, input_ids):
@@ -287,8 +278,8 @@ class KimiLinearModel(nn.Layer):
 
         B, T = input_ids.shape
         # under jit this runs once, where the step is traced
-        with RecordEvent("text/kimi_linear/forward", tokens=int(B) * int(T),
-                         layers=len(self.layers)):
+        with RecordEvent(f"text/{self._family}/forward",
+                         tokens=int(B) * int(T), layers=len(self.layers)):
             x = self.embed_tokens(input_ids)
             counts = []
             for layer in self.layers:
@@ -304,4 +295,92 @@ class KimiLinearModel(nn.Layer):
                 self.moe_routed_tokens._value = (
                     self.moe_routed_tokens._value
                     + stack(counts)._value)
-            return self.lm_head(self.norm(x))
+            x = self.norm(x)
+            if self.lm_head is not None:
+                return self.lm_head(x)
+            return F.linear(x, self.embed_tokens.weight.t())
+
+
+class KimiLinearModel(_HybridDecoderLM):
+    """Decoder-only LM of the Kimi-Linear family: layers of different
+    kinds by a per-layer table — `layer_kinds[i]` is "kda" (gated
+    delta-rule linear attention) or "mla" (NoPE latent attention) — a
+    dense SwiGLU FFN in the first `first_dense` layers and one chip's share
+    of a sparse-expert layer with its shared expert in the rest
+    (`experts_held` = (start, count) of `num_experts_published`;
+    nn.SparseExpertShare), RMSNorm, an untied head; the loop, recomputation
+    and the routing counter `moe_routed_tokens` are the base class's (the
+    counter's last column takes ~63,500 a step of 8,192 tokens: exact for
+    33,800 steps)."""
+
+    _family = "kimi_linear"
+    _kinds = {"kda": "kda_layers", "mla": "mla_layers"}
+
+    def __init__(self, vocab_size, hidden_size, layer_kinds, num_heads,
+                 kda_head_dim, kv_lora_rank, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, intermediate_size,
+                 moe_intermediate_size, num_experts_published, experts_held,
+                 experts_per_token, routed_scale=1.0, renormalize=True,
+                 first_dense=1, conv_size=4, gate_rank=None, epsilon=1e-5,
+                 recompute=False):
+        super().__init__()
+
+        def make_mixer(kind):
+            if kind == "kda":
+                return nn.KimiDeltaAttention(
+                    hidden_size, num_heads, kda_head_dim, conv_size,
+                    gate_rank, epsilon)
+            return nn.LatentAttention(
+                hidden_size, num_heads, kv_lora_rank, qk_nope_head_dim,
+                qk_rope_head_dim, v_head_dim, epsilon)
+
+        self._build(
+            vocab_size, hidden_size, layer_kinds, make_mixer,
+            intermediate_size, first_dense, dict(
+                expert_size=moe_intermediate_size,
+                num_experts_published=num_experts_published,
+                experts_held=experts_held,
+                experts_per_token=experts_per_token,
+                routed_scale=routed_scale, renormalize=renormalize),
+            epsilon, recompute, tie_head=False)
+
+
+class Lfm2MoeModel(_HybridDecoderLM):
+    """Decoder-only LM of the LFM2 sparse-expert family: `layer_kinds[i]`
+    is "conv" (the gated short-convolution mixer, nn.GatedShortConv) or
+    "full_attention" (grouped-query attention with per-head QK-norm and
+    rotary positions, nn.GroupedQueryAttention); a dense SwiGLU FFN in the
+    first `first_dense` layers and one chip's share of a sparse-expert
+    layer WITHOUT a shared expert in the rest (`experts_held` = (start,
+    count) of `num_experts_published`); RMSNorm; the head is the embedding
+    matrix transposed (one leaf, its gradient the sum of both uses).  The
+    loop, recomputation and the routing counter `moe_routed_tokens` are the
+    base class's."""
+
+    _family = "lfm2_moe"
+    _kinds = {"conv": "conv_layers", "full_attention": "attn_layers"}
+
+    def __init__(self, vocab_size, hidden_size, layer_kinds, num_heads,
+                 num_kv_heads, intermediate_size, moe_intermediate_size,
+                 num_experts_published, experts_held, experts_per_token,
+                 routed_scale=1.0, renormalize=True, first_dense=2,
+                 conv_size=3, rope_theta=1000000.0, epsilon=1e-5,
+                 recompute=False):
+        super().__init__()
+
+        def make_mixer(kind):
+            if kind == "conv":
+                return nn.GatedShortConv(hidden_size, conv_size)
+            return nn.GroupedQueryAttention(
+                hidden_size, num_heads, num_kv_heads, rope_theta, epsilon)
+
+        self._build(
+            vocab_size, hidden_size, layer_kinds, make_mixer,
+            intermediate_size, first_dense, dict(
+                expert_size=moe_intermediate_size,
+                num_experts_published=num_experts_published,
+                experts_held=experts_held,
+                experts_per_token=experts_per_token,
+                routed_scale=routed_scale, renormalize=renormalize,
+                shared_expert=False),
+            epsilon, recompute, tie_head=True)

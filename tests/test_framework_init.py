@@ -176,3 +176,99 @@ class TestLazyGeneratorKey:
         np.testing.assert_array_equal(gen.state_dict()["key_data"],
                                       data(jax.random.key(1234)))
         assert gen.state_dict()["seed"] == 1234
+
+
+class TestSplitAhead:
+    """split_ahead() computes the next split early and is otherwise
+    invisible: same stream, same state, dropped when the key changes."""
+
+    @staticmethod
+    def data(k):
+        import jax
+        import numpy as np
+
+        return np.asarray(jax.random.key_data(k))
+
+    def stream(self, seed, n):
+        import jax
+
+        k, out = jax.random.key(seed), []
+        for _ in range(n):
+            k, sub = jax.random.split(k)
+            out.append(self.data(sub))
+        return out
+
+    @pytest.mark.parametrize("ahead_before", [(), (0,), (0, 1, 2), (1,)])
+    def test_the_stream_is_the_same_wherever_it_is_called(self, ahead_before):
+        import numpy as np
+
+        from paddle_tpu.framework.random import Generator
+
+        g = Generator(7)
+        for i, want in enumerate(self.stream(7, 3)):
+            if i in ahead_before:
+                before = g.state_dict()["key_data"]
+                g.split_ahead()
+                g.split_ahead()                      # twice is once
+                np.testing.assert_array_equal(
+                    g.state_dict()["key_data"], before)
+            np.testing.assert_array_equal(self.data(g.split_key()), want)
+
+    @pytest.mark.parametrize("change", ["manual_seed", "set_state",
+                                        "set_state_dict"])
+    def test_a_new_key_in_between_drops_what_was_computed(self, change):
+        import jax
+        import numpy as np
+
+        from paddle_tpu.framework.random import Generator
+
+        g = Generator(7)
+        g.split_key()
+        g.split_ahead()
+        if change == "manual_seed":
+            g.manual_seed(11)
+        elif change == "set_state":
+            g.set_state(jax.random.key(11))
+        else:
+            g.set_state_dict(Generator(11).state_dict())
+        np.testing.assert_array_equal(self.data(g.split_key()),
+                                      self.stream(11, 1)[0])
+
+    def test_train_batch_takes_host_arrays_and_keeps_the_key_stream(self):
+        """Two steps from numpy batches: the generator ends where two
+        split_key() calls end, and the losses are those of device-array
+        batches (the jitted step moves host arrays itself)."""
+        import numpy as np
+
+        import paddle_tpu as paddle
+        from paddle_tpu import nn, optimizer
+        from paddle_tpu.framework.random import default_generator
+
+        def run(as_device):
+            paddle.seed(21)
+            net = nn.Sequential(nn.Linear(4, 8), nn.Dropout(0.5),
+                                nn.Linear(8, 3))
+            m = paddle.Model(net)
+            m.prepare(optimizer.SGD(0.1, parameters=net.parameters()),
+                      nn.CrossEntropyLoss())
+            rng = np.random.default_rng(0)
+            out = []
+            for _ in range(2):
+                x = rng.normal(size=(6, 4)).astype(np.float32)
+                y = rng.integers(0, 3, size=(6,))          # int64 labels
+                if as_device:
+                    x, y = paddle.to_tensor(x), paddle.to_tensor(y)
+                out.append(m.train_batch([x], [y])[0])
+            return out, default_generator.state_dict()["key_data"]
+
+        host, key_host = run(False)
+        dev, key_dev = run(True)
+        assert host == dev
+        np.testing.assert_array_equal(key_host, key_dev)
+        # ... and where the same draws plus one split_key() a step end
+        paddle.seed(21)
+        nn.Sequential(nn.Linear(4, 8), nn.Dropout(0.5), nn.Linear(8, 3))
+        default_generator.split_key()
+        default_generator.split_key()
+        np.testing.assert_array_equal(
+            default_generator.state_dict()["key_data"], key_host)

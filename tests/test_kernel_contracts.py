@@ -245,3 +245,33 @@ class TestKernelParityAfterRefactor:
         ref = paged_attention_xla(q, kp, vp, pt, sl)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("kernel", ["flash_attention_fwd",
+                                        "flash_attention_bwd_dkv",
+                                        "flash_attention_bwd_dq"])
+    def test_grouped_flash_runs_under_the_declared_blocks(self, kernel):
+        """Fewer KV heads than query heads (ISSUE 33) changes no block of
+        the three flash contracts: at the default block sizes the grouped
+        case table's kernel (interpret mode, 8 query heads over 2 KV
+        heads of 64 at 2,048 tokens in 512-row blocks) matches its XLA twin, dk/dv at the KV heads' count; the
+        dk/dv contract's grid says what its axes count."""
+        import jax
+
+        from paddle_tpu.ops.pallas_ops.cases import grouped_cases
+
+        c = CONTRACTS[kernel]
+        assert c.validate() == []
+        assert CONTRACTS["flash_attention_bwd_dkv"].grid \
+            == ("batch_kv_heads", "k_blocks", "group_q_blocks")
+        case = next(k for k in grouped_cases(
+            heads=8, kv_heads=2, head_dim=64, seq=2 * c.dim("block_k"),
+            block=c.dim("block_q")) if k.contract == kernel)
+        with jax.default_matmul_precision("highest"):
+            want = jax.tree_util.tree_leaves(case.twin(*case.args))
+        got = jax.tree_util.tree_leaves(case.kernel(*case.args))
+        assert [g.shape for g in got] == [w.shape for w in want]
+        if kernel == "flash_attention_bwd_dkv":
+            assert [g.shape[1] for g in got] == [2, 2]
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-4, atol=2e-4)

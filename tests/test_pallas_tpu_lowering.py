@@ -215,3 +215,34 @@ def test_the_hybrid_mixers_compile_for_v5e(case, v5e_topology):
              for i, a in enumerate(args)]
     text = jax.jit(fn).lower(*specs).compile().as_text()
     assert ("tpu_custom_call" in text) == label.startswith("flash"), label
+
+
+@pytest.mark.parametrize("case", range(3), ids=[
+    "flash-fwd-32-8x64", "flash-dkv-32-8x64", "flash-dq-32-8x64"])
+def test_the_grouped_flash_kernels_compile_for_v5e(case, v5e_topology):
+    """PR 33's grouped-query forms, bf16 as the train step runs them: 32
+    query heads over 8 KV heads of 64 — the forward and dq kernels read
+    KV head h // 4 through their index maps, the dk/dv kernel walks the
+    four heads of a group inside one grid axis (the case table's
+    `grouped_cases`, which chip_smoke.py runs on the chip against the XLA
+    twins).  dk and dv come back at the KV heads' count: no 32-head copy
+    of K or V is in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_ops.cases import grouped_cases
+
+    on_chip = SingleDeviceSharding(v5e_topology.devices[0])
+    contract, label, fn, _, args = grouped_cases()[case]
+    assert contract in CONTRACTS
+    specs = [jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=on_chip)
+             for a in args]
+    assert [s.shape[1] for s in specs] == [32, 8, 8, 32]
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text, label
+    out = jax.tree_util.tree_leaves(jax.eval_shape(fn, *specs))
+    assert [o.shape[1] for o in out] == ([8, 8] if case == 1 else [32])
+    # the kernel's own K and V operands are the 8-head arrays
+    calls = [e for e in jax.make_jaxpr(fn)(*specs).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    k_op, v_op = calls[-1].invars[2:4]
+    assert k_op.aval.shape == v_op.aval.shape == (8, specs[1].shape[2], 64)
