@@ -21,9 +21,9 @@ from a seed), and checks what comes out by the repo's own means:
   kernels  every entry of contracts.CONTRACTS compiled on the chip (never
            interpreted) at (H=12, D=64) and (H=16, D=128), page 16,
            against its XLA twin; then the hybrid models' mixers (PR 28):
-           flash forward and backward at q/k 192, v 128 and the chunked
-           gated delta rule, forward and gradients, against the
-           token-by-token recurrence.
+           flash forward and backward at q/k 192, v 128 and the gated
+           delta rule's two kernels (PR 34), forward and gradients,
+           against the token-by-token recurrence.
   mesh     only with >= 4 devices visible: the same model and requests
            on ServingEngine(mesh_axes={"tp": 2, "sp": 2}), and the train
            phase's own job (same batch, s2048 b4 bf16, three steps)
@@ -534,7 +534,7 @@ def kernels_phase():
     lines += 1
     line("H=12 D=64 ", label, kernel, twin, args)
     # the hybrid models' mixers at their own head sizes: flash at q/k
-    # 192, v 128 and the chunked delta rule at 128
+    # 192, v 128 and the delta rule's kernels at 128
     for _, label, kernel, twin, args in mixer_cases():
         lines += 1
         line("mixers    ", label, kernel, twin, args)

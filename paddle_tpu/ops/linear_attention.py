@@ -29,10 +29,17 @@ chunk is cut into sub-blocks of SUB tokens; a pair of different sub-blocks
 factors around the later block's first position (both factors decay away
 from it), and inside one sub-block the difference is formed directly.
 
-Everything the op does sits inside its `lax.scan` over groups of chunks,
-whose body is rematerialised: autodiff keeps one state per group, not
-every chunk's products, and a device trace shows the whole op, forward and
-backward, as `while` operations and what runs under them.
+Two executions of that one algorithm, told apart by what the code can see
+(`gated_delta_rule`, as ops/attention.py routes flash; `ROUTE_STATS` counts
+them where the op is traced).  On a TPU with head sizes of one lane tile
+(128), in a step not traced for a mesh, it is two Pallas kernels, forward
+and backward, under a `jax.custom_vjp` (pallas_ops/delta_rule.py): a
+device trace shows the op as Mosaic `custom-call`s — a layer's forward,
+its forward again under recomputation, its backward — and no loop.
+Anywhere else it is `gated_delta_rule_chunked`, the kernels' XLA twin:
+everything inside a `lax.scan` over groups of chunks whose body is
+rematerialised, so autodiff keeps one state per group, not every chunk's
+products, and a trace shows `while` operations and what runs under them.
 """
 from __future__ import annotations
 
@@ -48,6 +55,10 @@ CHUNK = 64          # tokens whose interactions are one set of matrix products
 SUB = 16            # sub-block inside which exp(G_i - G_j) is formed directly
 GROUP = 4           # chunks a scan step handles (its intra-chunk work batched)
 _HI = jax.lax.Precision.HIGHEST
+
+# trace-time routing telemetry, as ops/attention.py ROUTE_STATS: which
+# execution each traced call of `gated_delta_rule` was built with
+ROUTE_STATS = {"pallas": 0, "xla": 0}
 
 
 def gated_delta_rule_recurrent(q, k, v, g, beta):
@@ -209,12 +220,20 @@ def gated_delta_rule(q, k, v, g, beta, name=None):
     `amp.auto_cast` the large matrix products take bfloat16 operands like
     every matmul; the decay and the state stay float32."""
     from .dispatch import _amp_should_cast
+    from .pallas_ops import delta_rule, flash_attention
 
     mm_dtype = _amp_should_cast("matmul_v2")
+    q, k, v, g, beta = (to_tensor_like(a) for a in (q, k, v, g, beta))
+    # a step traced for a mesh (`partitioned_over`) keeps the XLA form,
+    # which GSPMD partitions: a Mosaic call would need a shard_map
+    pallas = jax.default_backend() == "tpu" \
+        and delta_rule.fits(q.shape[-1], v.shape[-1]) \
+        and getattr(flash_attention._partition, "spec", None) is None
+    ROUTE_STATS["pallas" if pallas else "xla"] += 1
+    form = delta_rule.gated_delta_rule_kernel if pallas \
+        else gated_delta_rule_chunked
 
     def f(q, k, v, g, beta):
-        return gated_delta_rule_chunked(q, k, v, g, beta,
-                                        mm_dtype=mm_dtype or q.dtype)
+        return form(q, k, v, g, beta, mm_dtype=mm_dtype or q.dtype)
 
-    return apply("gated_delta_rule", f, *(to_tensor_like(a)
-                                          for a in (q, k, v, g, beta)))
+    return apply("gated_delta_rule", f, q, k, v, g, beta)

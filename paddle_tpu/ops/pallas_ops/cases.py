@@ -6,7 +6,8 @@ the ONE table of them.
 and the tune runners (``tune/runners.py``) draw their inputs from the
 same builders at their own bucket sizes.  A new kernel form is added
 here, once; ``kernel_cases`` refuses to return while a contract has no
-case.
+case (``MIXER_CONTRACTS`` have theirs in ``mixer_cases``: forms that exist
+at one head size, whatever ``kernel_cases`` is asked for).
 """
 from __future__ import annotations
 
@@ -17,11 +18,13 @@ import numpy as np
 from .contracts import CONTRACTS
 
 __all__ = ["KernelCase", "kernel_cases", "mixer_cases", "grouped_cases",
-           "serve_cell_case",
+           "serve_cell_case", "MIXER_CONTRACTS",
            "flash_inputs", "paged_inputs", "qmm_inputs"]
 
 
 PAGE_SIZE = 16                      # the serving default
+# contracts whose only head size is 128: their cases are `mixer_cases`'
+MIXER_CONTRACTS = ("delta_rule_fwd", "delta_rule_bwd")
 FLASH_SEQ, FLASH_BLOCK = 512, 256   # 2 x 2 blocks: the causal skip runs
 
 
@@ -242,7 +245,8 @@ def kernel_cases(heads, head_dim):
         lambda *a: qm.quantized_matmul_kernel(*a, interpret=False),
         qm.quantized_matmul_xla, qmm_inputs(64, H * D, 3 * H * D)))
 
-    missing = set(CONTRACTS) - {c.contract for c in cases}
+    missing = set(CONTRACTS) - {c.contract for c in cases} \
+        - set(MIXER_CONTRACTS)
     if missing:
         raise AssertionError(f"contracts without a kernel case: {missing}")
     return cases
@@ -252,15 +256,16 @@ def mixer_cases(heads=4, seq=FLASH_SEQ + 72):
     """The sequence mixers of the hybrid linear-attention models, each
     against its XLA twin, forward and gradients: flash attention whose q/k
     head size (192) is not its v head size (128), through the public
-    wrapper (padding and all), and the chunked gated delta rule at head
-    size 128 against the token-by-token recurrence, at a length that is no
-    multiple of a chunk and decays from 0.999 down to 0.2 a token.  The
-    delta rule is XLA loops, not a Pallas kernel: no contract governs it."""
+    wrapper (padding and all), and the gated delta rule's two Pallas
+    kernels (forward; backward through the `custom_vjp`) at head size 128
+    against the token-by-token recurrence, at a length that is no
+    multiple of a chunk pair and decays from 0.999 down to 0.2 a token."""
     import jax
     import jax.numpy as jnp
 
     from .. import linear_attention as la
     from ..attention import _sdpa_core
+    from . import delta_rule as dr
     from . import flash_attention as fa
 
     rng = np.random.RandomState(11)
@@ -300,10 +305,11 @@ def mixer_cases(heads=4, seq=FLASH_SEQ + 72):
                    xla_attn, (q, k, v, g)),
         KernelCase("flash_attention_bwd_dkv", "flash bwd q/k 192, v 128",
                    grads_of(flash), grads_of(xla_attn), (q, k, v, g)),
-        KernelCase("", "delta rule chunked fwd", la.gated_delta_rule_chunked,
+        KernelCase("delta_rule_fwd", "delta rule fwd",
+                   dr.gated_delta_rule_kernel,
                    la.gated_delta_rule_recurrent, delta),
-        KernelCase("", "delta rule chunked bwd",
-                   delta_grads(la.gated_delta_rule_chunked),
+        KernelCase("delta_rule_bwd", "delta rule bwd",
+                   delta_grads(dr.gated_delta_rule_kernel),
                    delta_grads(la.gated_delta_rule_recurrent), delta),
     ]
 

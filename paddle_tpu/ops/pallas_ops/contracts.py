@@ -539,10 +539,102 @@ QUANTIZED_MATMUL = KernelContract(
            "block_k": (128, 256, 512)},
 )
 
+# ===========================================================================
+# delta_rule.py — the gated delta rule of ops/linear_attention.py (ISSUE
+# 34), forward and backward.  Grid (batch, head_blocks, pairs, heads):
+# one step = one PAIR of 64-token chunks of one head — 128 tokens, so a
+# step's q, k, g tile is [128, 128], one lane tile square, and the pair's
+# A, B and triangular inverse are block-diagonal [128, 128] matrices.
+# q, k, v, g are read in place from [B, T, H, head_dim]: a block is the
+# pair of ``heads`` = 8 heads (the sublanes of the array's own tiles; all
+# H where 8 does not divide it), fetched once and held while the last
+# grid axis walks its heads.  The pairs of a head run in sequence (the
+# backward's in reverse) with the block's states [heads, head_dim,
+# head_dim] float32 in scratch; beta comes twice, as a column and as a
+# row of the pair.  Structural, no sweep:
+# ``sub`` and the chunk (pair / 2) are the algorithm's, head_dim is one
+# lane tile.  Padded: T, to a whole pair, with k = 0, beta = 0, g = 0.
+# The declared blocks are not all a step holds: the backward keeps ~60
+# live [128, 128] float32 temporaries a head (~4 MiB; the sixteen
+# diagonals' exp(G_i - G_j) among them, shared by the recomputation and
+# the gradient), ``together`` heads at once, so with float32 operands at
+# eight heads a block it passes the default 16 MiB scoped limit and the
+# kernels ask for ``vmem_limit_mib`` (the chip has 128).
+# ===========================================================================
+DELTA_RULE_FWD = KernelContract(
+    name="delta_rule_fwd",
+    module="paddle_tpu/ops/pallas_ops/delta_rule.py",
+    grid=("batch", "head_blocks", "pairs", "heads"),
+    dims={"pair": 128, "sub": 16, "heads": 8, "together": 2,
+          "head_dim": 128, "lane": 128, "vmem_limit_mib": 48},
+    blocks=(
+        BlockDecl("q", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("k", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("v", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("g", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("beta_col", "in", (1, "together", "pair", 1), "float32",
+                  lanes_full=True),
+        BlockDecl("beta_row", "in", (1, "together", 1, "pair"), "float32",
+                  sublane_full=True),
+        BlockDecl("o", "out", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        # the state at the pair's start: the backward's residual
+        BlockDecl("state_at", "out",
+                  (1, "together", 1, "head_dim", "head_dim"), "float32"),
+        BlockDecl("state", "scratch", ("heads", "head_dim", "head_dim"),
+                  "float32"),
+    ),
+    shape_buckets={"pair": (1024, 2048, 4096, 8192)},
+)
+
+DELTA_RULE_BWD = KernelContract(
+    name="delta_rule_bwd",
+    module="paddle_tpu/ops/pallas_ops/delta_rule.py",
+    grid=("batch", "head_blocks", "pairs", "heads"),
+    dims={"pair": 128, "sub": 16, "heads": 8, "together": 2,
+          "head_dim": 128, "lane": 128, "vmem_limit_mib": 48},
+    blocks=(
+        BlockDecl("q", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("k", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("v", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("g", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("beta_col", "in", (1, "together", "pair", 1), "float32",
+                  lanes_full=True),
+        BlockDecl("beta_row", "in", (1, "together", 1, "pair"), "float32",
+                  sublane_full=True),
+        BlockDecl("state_at", "in",
+                  (1, "together", 1, "head_dim", "head_dim"), "float32"),
+        BlockDecl("do", "in", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("dq", "out", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("dk", "out", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("dv", "out", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("dg", "out", (1, "pair", "heads", "head_dim"),
+                  "float32"),
+        BlockDecl("dbeta", "out", (1, "together", "pair", 1), "float32",
+                  lanes_full=True),
+        BlockDecl("dstate", "scratch", ("heads", "head_dim", "head_dim"),
+                  "float32"),
+    ),
+    shape_buckets={"pair": (1024, 2048, 4096, 8192)},
+)
+
 # name -> contract, the registry the lint, the tests and (next) the
 # autotuner iterate
 CONTRACTS: Dict[str, KernelContract] = {
     c.name: c for c in (FLASH_FWD, FLASH_BWD_DKV, FLASH_BWD_DQ,
                         PAGED_RAGGED, PAGED_RAGGED_INT8,
-                        PAGED_RAGGED_STATS, QUANTIZED_MATMUL)
+                        PAGED_RAGGED_STATS, QUANTIZED_MATMUL,
+                        DELTA_RULE_FWD, DELTA_RULE_BWD)
 }

@@ -152,6 +152,56 @@ def test_chunked_delta_rule_is_the_recurrence(T, low, high):
             < 1e-4 * max(1.0, float(jnp.max(jnp.abs(want))))
 
 
+@pytest.mark.parametrize("case", [
+    (300, 0.2, 0.999), (64, 0.2, 0.3), (257, 0.95, 0.999), (100, 0.5, 0.5),
+    "bf16", "route"], ids=["T300", "T64-fast-decay", "T257-slow-decay",
+                           "T100", "bf16-products", "route-counter"])
+def test_the_delta_rule_kernels_are_the_recurrence(case):
+    """The Pallas route (ops/pallas_ops/delta_rule.py), interpreted on the
+    CPU at head size 128: forward and every gradient against the
+    token-by-token recurrence at the tolerances the XLA form is held to;
+    with bfloat16 operands in the large products, against the XLA form
+    given the same; and the route `gated_delta_rule` takes here."""
+    from paddle_tpu.ops import linear_attention as la
+    from paddle_tpu.ops.pallas_ops.delta_rule import gated_delta_rule_kernel
+
+    kernel = lambda *a, **kw: gated_delta_rule_kernel(*a, interpret=True,
+                                                      **kw)
+    grads_of = lambda fn, w: jax.grad(
+        lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))
+    if case == "route":
+        before = dict(la.ROUTE_STATS)
+        for dk, dv in ((128, 128), (32, 16)):
+            out = la.gated_delta_rule(*_delta_inputs(
+                70, 0.5, 0.9, B=1, H=1, Dk=dk, Dv=dv))
+            assert out.shape == [1, 70, 1, dv]
+        assert la.ROUTE_STATS == {"pallas": before["pallas"],
+                                  "xla": before["xla"] + 2}
+        return
+    if case == "bf16":
+        args = _delta_inputs(200, 0.3, 0.999, B=1, H=2, Dk=128, Dv=128)
+        twin = lambda *a: la.gated_delta_rule_chunked(
+            *a, mm_dtype=jnp.bfloat16)
+        mine = lambda *a: kernel(*a, mm_dtype=jnp.bfloat16)
+        # the forward rounds the same operands; the backward's cotangents
+        # are rounded too, where autodiff of the twin keeps them float32
+        want, tol, grad_tol = twin(*args), 1e-4, 1e-2
+    else:
+        args = _delta_inputs(*case, B=1, H=2, Dk=128, Dv=128)
+        twin = mine = None
+        want, tol, grad_tol = la.gated_delta_rule_recurrent(*args), 1e-5, 1e-4
+    out = (mine or kernel)(*args)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    scale = lambda a: max(1.0, float(jnp.max(jnp.abs(a))))
+    assert float(jnp.max(jnp.abs(out - want))) < tol
+    w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    grads = grads_of(mine or kernel, w)(*args)
+    wants = grads_of(twin or la.gated_delta_rule_recurrent, w)(*args)
+    for got, ref in zip(grads, wants):
+        assert float(jnp.max(jnp.abs(got - ref))) \
+            < grad_tol * scale(ref)
+
+
 def test_the_delta_rule_never_steps_over_single_tokens():
     """The normal path's loops run over groups of chunks, forward and
     backward: no loop in the lowered program has as many steps as

@@ -27,7 +27,7 @@ import jax.export
 import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas_ops import flash_attention as fa
-from paddle_tpu.ops.pallas_ops.cases import kernel_cases
+from paddle_tpu.ops.pallas_ops.cases import MIXER_CONTRACTS, kernel_cases
 from paddle_tpu.ops.pallas_ops.contracts import CONTRACTS
 
 SHAPES = ((12, 64), (16, 128))       # GPT-2-small's, and the R1+ width
@@ -69,7 +69,7 @@ def test_tpu_lowering_accepts():
     Mosaic custom call for the TPU platform (one shape — the Mosaic
     tests below repeat this half at both)."""
     by_contract = _cases(*SHAPES[0])
-    assert set(by_contract) == set(CONTRACTS)
+    assert set(by_contract) == set(CONTRACTS) - set(MIXER_CONTRACTS)
     for forms in by_contract.values():
         for label, fn, specs in forms:
             exported = jax.export.export(jax.jit(fn),
@@ -78,7 +78,8 @@ def test_tpu_lowering_accepts():
 
 
 @pytest.mark.parametrize("H,D", SHAPES)
-@pytest.mark.parametrize("name", sorted(CONTRACTS))
+@pytest.mark.parametrize("name", sorted(set(CONTRACTS)
+                                         - set(MIXER_CONTRACTS)))
 def test_mosaic_compiles_for_v5e(name, H, D, v5e_topology):
     from jax.sharding import SingleDeviceSharding
 
@@ -199,22 +200,48 @@ def test_the_serve_cells_kernel_call_compiles_for_v5e(v5e_topology):
 def test_the_hybrid_mixers_compile_for_v5e(case, v5e_topology):
     """PR 28's mixers at their own head sizes, bf16 as the train step
     runs them: flash with q/k 192 and v 128 (q/k padded to 256 lanes, v
-    not), and the chunked gated delta rule with its ragged sub-blocks and
-    triangular inverse — forward and gradients, compiled for one v5e
-    chip (the case table's `mixer_cases`, which chip_smoke.py runs on the
-    chip against the XLA twins)."""
+    not), and the gated delta rule's two kernels (PR 34: the chunk pair's
+    rolls, transposes and fp32-contract products, forward and through the
+    `custom_vjp`) — compiled for one v5e chip with no loop left in the
+    program (the case table's `mixer_cases`, which chip_smoke.py runs on
+    the chip against the XLA twins)."""
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.ops.pallas_ops.cases import mixer_cases
 
     on_chip = SingleDeviceSharding(v5e_topology.devices[0])
-    label, fn, args = [(c.label, c.kernel, c.args)
-                       for c in mixer_cases()][case]
+    contract, label, fn, _, args = mixer_cases()[case]
+    assert contract in CONTRACTS
     specs = [jax.ShapeDtypeStruct(a.shape, jnp.bfloat16 if i < 3
                                   else a.dtype, sharding=on_chip)
              for i, a in enumerate(args)]
     text = jax.jit(fn).lower(*specs).compile().as_text()
-    assert ("tpu_custom_call" in text) == label.startswith("flash"), label
+    assert "tpu_custom_call" in text and " while(" not in text, label
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_the_delta_rule_kernels_hold_eight_float32_heads(backward,
+                                                         v5e_topology):
+    """A block of the delta rule's kernels is a chunk pair of EIGHT heads
+    as the array stores them, two heads a grid step: with float32 q, k, v
+    the backward's blocks and temporaries pass the 16 MiB a kernel gets by
+    default — the kernels ask for what the contract states."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_ops.delta_rule import gated_delta_rule_kernel
+
+    on_chip = SingleDeviceSharding(v5e_topology.devices[0])
+    B, T, H, D = 1, 1024, 8, 128
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                               sharding=on_chip)
+    args = [spec(B, T, H, D)] * 4 + [spec(B, T, H)]
+    fn = gated_delta_rule_kernel
+    if backward:
+        fn = jax.grad(lambda *a: jnp.sum(gated_delta_rule_kernel(*a)),
+                      argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 + backward
+    assert CONTRACTS["delta_rule_bwd"].dim("vmem_limit_mib") > 16
 
 
 @pytest.mark.parametrize("case", range(3), ids=[
