@@ -47,18 +47,26 @@ def require_tpu(chips):
     return devices[:chips]
 
 
-def describe(devices):
-    """The device as jax reports it.  The peak on the fullest chip is the
-    allocator's peak in use plus its peak reserved: the TPU runtime books a
-    running program's temporaries as reserved, not as in use (the train
-    step: 4.95 GB of state in use, 5.92 GB of temporaries reserved)."""
-    peaks = []
-    for d in devices:
-        stats = d.memory_stats() or {}
-        peaks.append(int(stats.get("peak_bytes_in_use", 0))
-                     + int(stats.get("peak_bytes_reserved", 0)))
+def peak_bytes(devices):
+    """The peak on the fullest chip so far: the allocator's peak in use
+    plus its peak reserved — the TPU runtime books a running program's
+    temporaries as reserved, not as in use (the train step: 4.95 GB of
+    state in use, 5.92 GB of temporaries reserved).  A process's peak
+    never falls, so WHEN it is read decides what it covers."""
+    return max(int(s.get("peak_bytes_in_use", 0))
+               + int(s.get("peak_bytes_reserved", 0))
+               for s in (d.memory_stats() or {} for d in devices))
+
+
+def describe(devices, window_peak_bytes):
+    """The device as jax reports it.  `memory_peak_bytes` is the peak the
+    runner read as its window closed — the served or trained state's,
+    before the reference ran; the peak as the process ends (the check's
+    memory included) stays beside it for the record."""
     return {"platform": devices[0].platform, "kind": devices[0].device_kind,
-            "count": len(devices), "memory_peak_bytes": max(peaks),
+            "count": len(devices),
+            "memory_peak_bytes": int(window_peak_bytes),
+            "memory_peak_bytes_at_exit": peak_bytes(devices),
             "memory_stats": devices[0].memory_stats() or {}}
 
 

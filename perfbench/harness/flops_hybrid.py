@@ -6,21 +6,27 @@ a peak cannot pass 100%."""
 from __future__ import annotations
 
 
-def attn_fwd(batch, seq, heads, dk, dv, dtype_bytes=2):
+def attn_fwd(batch, seq, heads, dk, dv, dtype_bytes=2, kv_heads=None):
     """Causal attention whose q/k head size differs from its v head size:
-    QK^T at 2*S*S*dk and PV at 2*S*S*dv per head, halved by the mask.
-    Bytes: q, k (dk) and v (dv) read, o (dv) written, once."""
+    QK^T at 2*S*S*dk and PV at 2*S*S*dv per query head, halved by the
+    mask.  Bytes: q (dk) read and o (dv) written at the query heads'
+    count, k (dk) and v (dv) read at the KV heads' (the query heads' where
+    none is given: every head has its own), once."""
+    kv_heads = heads if kv_heads is None else kv_heads
     flops = 2 * seq * seq * (dk + dv) * batch * heads * 0.5
-    nbytes = 2 * (dk + dv) * batch * heads * seq * dtype_bytes
+    nbytes = (dk + dv) * batch * (heads + kv_heads) * seq * dtype_bytes
     return flops, nbytes
 
 
-def attn_bwd(batch, seq, heads, dk, dv, dtype_bytes=2):
+def attn_bwd(batch, seq, heads, dk, dv, dtype_bytes=2, kv_heads=None):
     """Backward: S again, dQ and dK at the q/k head size, dP and dV at
     the v head size — 2.5 x the forward by the same rule as
-    flops.flash_bwd.  Bytes: q, k, v, o, dO read; dq, dk, dv written."""
+    flops.flash_bwd.  Bytes: q, o, dO read and dq written at the query
+    heads' count; k, v read and dk, dv written at the KV heads'."""
+    kv_heads = heads if kv_heads is None else kv_heads
     flops = 2 * seq * seq * (3 * dk + 2 * dv) * batch * heads * 0.5
-    nbytes = (4 * dk + 4 * dv) * batch * heads * seq * dtype_bytes
+    nbytes = (2 * dk + 2 * dv) * batch * (heads + kv_heads) * seq \
+        * dtype_bytes
     return flops, nbytes
 
 

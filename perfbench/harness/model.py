@@ -6,7 +6,7 @@ from __future__ import annotations
 
 import sys
 
-from .weights import make_weights
+from .weights import weight_groups, weights_dtype
 
 
 def weight_seed(config, seed):
@@ -16,23 +16,38 @@ def weight_seed(config, seed):
     return int(seed) if fixed is None else int(fixed)
 
 
+def _refuse(what, odd):
+    print(f"perfbench: the program's model is not the configured "
+          f"architecture ({what}): {sorted(odd)[:6]}", file=sys.stderr)
+    raise SystemExit(4)
+
+
 def build(manifest, config, seed):
-    """(model, weights): the program's model at the configuration's sizes,
-    its parameters replaced by make_weights(seed) in the shapes the
-    reference states."""
+    """(model, weights): the program's model at the configuration's sizes
+    and in its `weights_dtype`, every parameter replaced in place by the
+    seeded array of the shape the reference states.  The weights land a
+    group at a time and each leaf is installed as its group lands, which
+    releases the constructor's leaf: the peak is the model plus one group,
+    never two copies.  `weights` names the installed buffers themselves."""
+    import jax.numpy as jnp
     import paddle_tpu as paddle
 
-    shapes = manifest.reference(config).param_shapes(config)
+    dtype = weights_dtype(config)
+    shapes = {n: tuple(s) for n, s in
+              manifest.reference(config).param_shapes(config).items()}
     paddle.seed(int(seed) % (2 ** 31 - 1))
     model = manifest.model(config).construct(config)
-    have = {n: tuple(p.shape) for n, p in model.named_parameters()}
-    if have != {n: tuple(s) for n, s in shapes.items()}:
-        odd = sorted(set(have.items()) ^ set(
-            (n, tuple(s)) for n, s in shapes.items()))[:6]
-        print(f"perfbench: the program's model is not the configured "
-              f"architecture: {odd}", file=sys.stderr)
-        raise SystemExit(4)
-    weights = make_weights(shapes, weight_seed(config, seed))
-    for n, p in model.named_parameters():
-        p._value = weights[n]
+    params = dict(model.named_parameters())
+    have = {n: tuple(p.shape) for n, p in params.items()}
+    if have != shapes:
+        _refuse("shapes", set(have.items()) ^ set(shapes.items()))
+    held = {n: jnp.dtype(p._value.dtype).name for n, p in params.items()}
+    odd = {(n, d) for n, d in held.items() if d != dtype}
+    if odd:
+        _refuse(f"weights_dtype is {dtype}", odd)
+    weights = {}
+    for group in weight_groups(shapes, weight_seed(config, seed), dtype):
+        for n, leaf in group.items():
+            params[n]._value = leaf
+        weights.update(group)
     return model, weights

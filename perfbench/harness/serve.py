@@ -1,6 +1,6 @@
 """What the two serving runners share: server bring-up through the
 program's public entry points, warm-up, the load generator's process, the
-traced slice, and the logit check.
+traced slice, the server's release, and the logit check on the freed chip.
 
 Bring-up is chip_smoke.py's: Config.enable_serving ->
 create_serving_frontend -> start_http_server.  The load generator is
@@ -20,6 +20,7 @@ import time
 
 import numpy as np
 
+from . import device
 from . import trace as trace_mod
 from .manifest import BENCH_DIR
 from .model import build
@@ -112,8 +113,16 @@ class Server:
         return out
 
     def close(self):
+        """Stop the server and let go of everything that holds device
+        memory but the weights (the model's parameters ARE `weights`):
+        the engine with its KV pools and the step programs, which close
+        over a copy of the weights each."""
+        import gc
+
         self.http.stop()
         self.frontend.close()
+        self.http = self.frontend = self.engine = self.model = None
+        gc.collect()
 
 
 def run_load(job, server, schedule, window, label):
@@ -156,6 +165,7 @@ def run_load(job, server, schedule, window, label):
             obs["trace_path"] = stop_trace()
         _sleep_until(w1)
         obs["compiles_in_window"] = job.counter.since(compiles)
+        obs["memory_peak_bytes"] = device.peak_bytes(job.devices)
         if sampler is not None:
             sampler.stop()
             obs["samples"] = sampler.between(w0, w1)
@@ -183,38 +193,77 @@ def _sleep_until(t):
         time.sleep(wait)
 
 
-def check_logits(job, server, records, prompts, sample=4):
-    """A seeded sample of completed requests, teacher-forced through the
+def measure(job, build_schedule):
+    """The part of a run that needs the server, the same for both serving
+    runners: bring-up, warm-up, the schedule `build_schedule(traffic, seed,
+    seconds, vocab)` makes, the load, the server's health — and then its
+    release, so that the check which follows has the chip to itself beside
+    the weights.  Returns (records, obs, window, prompts, faults,
+    weights)."""
+    traffic = job.traffic
+    server = Server(job)
+    try:
+        server.warm_up(traffic["warmup_prompts"],
+                       traffic["warmup_new_tokens"])
+        schedule, window, prompts = build_schedule(
+            traffic, job.seed, job.seconds, job.config["vocab_size"])
+        records, obs = run_load(job, server, schedule, window, "load")
+        faults = server.faults()
+        weights = server.weights
+    finally:
+        server.close()
+    return records, obs, window, prompts, faults, weights
+
+
+def _request_of(record):
+    """The schedule's request a record answers (a closed loop sends one
+    request many times: `c3.0`, `c3.1`, ...)."""
+    return record["id"].split(".")[0]
+
+
+def sample_requests(records, prompts, seed, sample=4):
+    """The completed requests the check follows: the longest (prompt plus
+    served tokens — the positions where a long context's machinery alone
+    differs from a short one's) and `sample` - 1 more drawn by --seed."""
+    done = sorted((r for r in records
+                   if r.get("status") == "completed" and r["tokens"]
+                   and _request_of(r) in prompts),
+                  key=lambda r: r["id"])
+    if not done:
+        return []
+    longest = max(done, key=lambda r: len(prompts[_request_of(r)])
+                  + len(r["tokens"]))
+    rest = [r for r in done if r is not longest]
+    rng = np.random.default_rng([int(seed), 77])
+    return [longest] + [rest[i] for i in rng.choice(
+        len(rest), size=min(sample - 1, len(rest)), replace=False)]
+
+
+def check_logits(job, weights, records, prompts):
+    """The sampled requests (sample_requests), teacher-forced through the
     plain reference: every token the engine streamed (prefill, then
     decode through the paged cache) must have a reference logit within
-    the stated margin of the reference's maximum at its position.
-    Returns (ok, {name: value beside limit}, detail)."""
+    the stated margin of the reference's maximum at its position.  All
+    are padded to the reference's longest sequence, so one program
+    serves them.  Returns (ok, {name: value beside limit}, detail)."""
     import jax
     import jax.numpy as jnp
 
     cfg = job.config
     ref = job.manifest.reference(cfg)
     margin = job.manifest.tolerance(cfg)["serve_logit_margin_rel"]
-    done = sorted((r for r in records
-                   if r.get("status") == "completed" and r["tokens"]
-                   and r["id"].split(".")[0] in prompts),
-                  key=lambda r: r["id"])
-    if not done:
+    picks = sample_requests(records, prompts, job.seed)
+    if not picks:
         return False, {}, {"error": "no completed request to check"}
-    rng = np.random.default_rng([int(job.seed), 77])
-    picks = [done[i] for i in rng.choice(len(done),
-                                         size=min(sample, len(done)),
-                                         replace=False)]
     pad_to = ref.max_positions(cfg)
     fwd = jax.jit(lambda w, ids: ref.forward(w, ids, cfg))
-    worst, flips, positions = 0.0, 0, 0
+    worst, flips, positions, last = 0.0, 0, 0, 0
     for r in picks:
-        prompt = prompts[r["id"].split(".")[0]]
+        prompt = prompts[_request_of(r)]
         seq = list(prompt) + [int(t) for t in r["tokens"]]
         ids = np.zeros((pad_to,), np.int32)
         ids[:len(seq) - 1] = seq[:-1]
-        logits = np.asarray(fwd(server.weights, jnp.asarray(ids)),
-                            np.float32)
+        logits = np.asarray(fwd(weights, jnp.asarray(ids)), np.float32)
         if not np.all(np.isfinite(logits[:len(seq) - 1])):
             return False, {}, {"error": f"reference logits not finite "
                                         f"({r['id']})"}
@@ -227,8 +276,12 @@ def check_logits(job, server, records, prompts, sample=4):
             worst = max(worst, short)
             flips += int(np.argmax(row) != int(tok))
             positions += 1
+        last = max(last, len(seq) - 1)
+    # `sampled_max_position`, the last position a sampled token was served
+    # at (0-based), says how far into a context the comparison reached
     detail = {"worst_shortfall_rel": worst, "margin_rel": margin,
               "argmax_differs": flips, "positions": positions,
+              "sampled_max_position": last,
               "requests": [r["id"] for r in picks]}
     checks = {"logit_shortfall_rel": {"value": worst, "limit": margin}}
     return worst <= margin, checks, detail

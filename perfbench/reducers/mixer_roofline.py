@@ -7,8 +7,9 @@ matches `pattern`.  `calls_per_unit` matched operations make one unit of
 `shape_fn` (a forward kernel: 1; a backward made of two kernels: 2; an op
 that shows as a forward loop, the forward loop again under
 rematerialisation and a backward loop, counted as one forward and one
-backward: 3).  A reference without `mixer_shapes` (another architecture,
-an older commit) reads nothing."""
+backward: 3).  A mixer that states `kv_heads` (fewer KV heads than query
+heads) has its K and V counted at that many.  A reference without
+`mixer_shapes` (another architecture, an older commit) reads nothing."""
 from perfbench.harness import flops as F
 from perfbench.harness import flops_hybrid as H
 from perfbench.harness import trace as T
@@ -23,8 +24,9 @@ def reduce(ctx, pattern, shape_fn, mixer, calls_per_unit=1):
     secs, calls = T.op_calls(ctx["trace"], pattern)
     if not shape or not calls or "sequences_per_chip" not in values:
         return None
+    grouped = {"kv_heads": shape["kv_heads"]} if "kv_heads" in shape else {}
     flops, nbytes = getattr(H, shape_fn)(
         values["sequences_per_chip"], values["seq_len"], shape["heads"],
-        shape["dk"], shape["dv"])
+        shape["dk"], shape["dv"], **grouped)
     least, _ = F.roofline_seconds(flops, nbytes, ctx["peaks"]())
     return 100.0 * least * (calls / calls_per_unit) / secs

@@ -121,9 +121,8 @@ def attention_shape(cfg, mesh=None):
 
 def mixer_shapes(cfg):
     """What the attention layers' kernels see on this chip, for the shape
-    functions of harness/flops_hybrid.py (which count K and V at the query
-    heads' count: bytes overstated, operations exact), and how many layers
-    are of each kind."""
+    functions of harness/flops_hybrid.py (which count K and V at
+    `kv_heads`), and how many layers are of each kind."""
     kinds = layer_kinds(cfg)
     return {"gqa": {"heads": cfg["num_attention_heads"],
                     "kv_heads": cfg["num_key_value_heads"],

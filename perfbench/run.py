@@ -64,10 +64,10 @@ def run_cell(manifest, name, seed, seconds, trace, devices, t0=None):
     result = manifest.runner(traffic["kind"]).run(job)
     obs = result["obs"]
     setup_s = obs["window_start_perf"] - t0
-    dev = device.describe(devices)
+    dev = device.describe(devices, obs["memory_peak_bytes"])
     result["values"].update(
         compiles_in_window=obs["compiles_in_window"],
-        hbm_peak_gb=dev["memory_peak_bytes"] / 1e9, chips=len(devices))
+        hbm_peak_gb=obs["memory_peak_bytes"] / 1e9, chips=len(devices))
     line = {"correct": result["correct"], "attempted": result["attempted"],
             "failed": result["failed"], "device": dev}
     if not trace:

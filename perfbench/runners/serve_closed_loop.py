@@ -24,20 +24,10 @@ def build_schedule(traffic, seed, seconds, vocab):
 
 
 def run(job):
-    traffic = job.traffic
-    server = serve.Server(job)
-    try:
-        server.warm_up(traffic["warmup_prompts"],
-                       traffic["warmup_new_tokens"])
-        schedule, window, prompts = build_schedule(
-            traffic, job.seed, job.seconds, job.config["vocab_size"])
-        records, obs = serve.run_load(job, server, schedule, window, "load")
-        faults = server.faults()
-        result = score.score_closed_loop(records, *window)
-        ok, checks, detail = serve.check_logits(job, server, records,
-                                                prompts)
-    finally:
-        server.close()
+    records, obs, window, prompts, faults, weights = serve.measure(
+        job, build_schedule)
+    result = score.score_closed_loop(records, *window)
+    ok, checks, detail = serve.check_logits(job, weights, records, prompts)
     return {
         "correct": bool(ok and not faults),
         "attempted": result["attempted"], "failed": result["failed"],

@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 
+from perfbench.harness import device
 from perfbench.harness import trace as trace_mod
 from perfbench.harness.model import build
 
@@ -211,6 +212,7 @@ def run(job):
         if tracing:
             obs["trace_path"] = stop_trace()
         obs["compiles_in_window"] = job.counter.since(compiles)
+        obs["memory_peak_bytes"] = device.peak_bytes(job.devices)
         obs["window_start_perf"] = t_start
     finally:
         source.close()

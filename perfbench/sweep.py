@@ -114,7 +114,8 @@ def main(argv=None):
     with open(args.out, "w") as f:
         json.dump({"cell": cell["name"], "seconds": args.seconds,
                    "knee_rps": knee, "rows": rows,
-                   "device": device.describe(devices)}, f, indent=1)
+                   "device": device.describe(
+                       devices, device.peak_bytes(devices))}, f, indent=1)
     print(json.dumps({"knee_rps": knee}), flush=True)
     return 0
 

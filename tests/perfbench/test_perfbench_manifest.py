@@ -253,10 +253,10 @@ def test_no_architecture_is_named_outside_its_own_files(sub):
 def test_an_unknown_model_type_names_the_files_to_add():
     for lookup in (M.model, M.reference, M.tolerance):
         with pytest.raises(FileNotFoundError) as e:
-            lookup({"model_type": "kimi_linear"})
-        for path in ("perfbench/models/kimi_linear.py",
-                     "perfbench/reference/kimi_linear.py",
-                     "perfbench/reference/kimi_linear.tolerance.json"):
+            lookup({"model_type": "no_such_arch"})
+        for path in ("perfbench/models/no_such_arch.py",
+                     "perfbench/reference/no_such_arch.py",
+                     "perfbench/reference/no_such_arch.tolerance.json"):
             assert path in str(e.value)
     with pytest.raises(FileNotFoundError):
         M.reference({"vocab_size": 8})

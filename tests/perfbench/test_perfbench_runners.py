@@ -203,8 +203,7 @@ def test_a_token_altered_where_it_is_produced_fails_the_serve_check(tree,
     if fault == "token_altered":
         tokens[-1] = worst_token
     records = [{"id": "c0", "status": "completed", "tokens": tokens}]
-    server = types.SimpleNamespace(weights=weights)
-    ok, checks, detail = serve.check_logits(job, server, records,
+    ok, checks, detail = serve.check_logits(job, weights, records,
                                             {"c0": prompt})
     limit = tree.tolerance(cfg)["serve_logit_margin_rel"]
     assert checks["logit_shortfall_rel"]["limit"] == limit
