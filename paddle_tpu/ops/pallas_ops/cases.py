@@ -19,7 +19,8 @@ from .contracts import CONTRACTS
 
 __all__ = ["KernelCase", "kernel_cases", "mixer_cases", "grouped_cases",
            "serve_cell_case", "MIXER_CONTRACTS",
-           "flash_inputs", "paged_inputs", "qmm_inputs"]
+           "flash_inputs", "paged_inputs", "qmm_inputs", "kv_write_inputs",
+           "kv_write_scatter"]
 
 
 PAGE_SIZE = 16                      # the serving default
@@ -131,6 +132,56 @@ def serve_cell_case(pages=513):
             q, kp, vp, pt, rl, interpret=False), twin, (kp, vp))
 
 
+# (row-0 position, live rows) of each lane kind the ragged step writes,
+# as the engine plans them, at rows 16 and pages of 16: a decode lane, an
+# aligned full chunk, a chunk from mid-page to mid-page, a prompt's short
+# final chunk, spec-verify rows across a page boundary, an idle lane
+KV_WRITE_LANES = ((37, 1), (32, 16), (21, 16), (48, 5), (62, 4), (0, 0))
+
+
+def kv_write_inputs(H, D, page_size, *, lanes=KV_WRITE_LANES, rows=16,
+                    pages=64, table=8, seed=7):
+    """The operands of the paged KV write for ``lanes`` ((first, live)
+    pairs): ``(k_rows, v_rows [lanes * rows, H*D], k_pool, v_pool [pages,
+    page_size, H*D], page_tables [lanes, table] — distinct pages, none of
+    them the trash page — first [lanes], live [lanes])``."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    G, HD = len(lanes), H * D
+    pool = (pages, page_size, HD)
+    pt = (1 + rng.permutation(pages - 1)[:G * table]).reshape(G, table)
+    first = np.array([f for f, _ in lanes], np.int32)
+    live = np.array([n for _, n in lanes], np.int32)
+    arr = lambda *shape: jnp.asarray(
+        rng.standard_normal(shape).astype(np.float32))
+    return (arr(G * rows, HD), arr(G * rows, HD), arr(*pool), arr(*pool),
+            jnp.asarray(pt.astype(np.int32)), jnp.asarray(first),
+            jnp.asarray(live))
+
+
+def kv_write_scatter(k_rows, v_rows, k_pool, v_pool, page_tables, first,
+                     live):
+    """The paged KV write's reference: the ragged step's row scatter —
+    every row into page ``table[min(pos // P, M - 1)]`` slot ``pos % P``,
+    rows past a lane's live ones into the trash page 0 — with page 0 then
+    put back as it was (the kernel leaves it alone where no live row maps
+    to it, and what it holds is junk either way)."""
+    import jax.numpy as jnp
+
+    G, M = page_tables.shape
+    Q = k_rows.shape[0] // G
+    P = k_pool.shape[1]
+    r = jnp.arange(Q, dtype=jnp.int32)
+    pos = (first[:, None] + r[None, :]).reshape(-1)
+    page = jnp.take_along_axis(jnp.repeat(page_tables, Q, axis=0),
+                               jnp.minimum(pos // P, M - 1)[:, None],
+                               axis=1)[:, 0]
+    page = jnp.where((r[None, :] < live[:, None]).reshape(-1), page, 0)
+    return tuple(pool.at[page, pos % P].set(new).at[0].set(pool[0])
+                 for pool, new in ((k_pool, k_rows), (v_pool, v_rows)))
+
+
 def qmm_inputs(M, K, N):
     """(x [M, K] f32, w_q [K, N] int8, w_scale [N] f32) for the
     weight-only int8 matmul."""
@@ -238,6 +289,14 @@ def kernel_cases(heads, head_dim):
         ]
 
     cases += paged(False) + paged(True)
+
+    # --- the ragged step's K/V write: one lane of each kind --------------
+    from . import paged_kv_write as kw
+
+    cases.append(KernelCase(
+        "paged_kv_write", "kv write native, lane mix",
+        lambda *a: kw.paged_kv_write(*a, interpret=False),
+        kv_write_scatter, kv_write_inputs(H, D, PAGE_SIZE)))
 
     # --- weight-only int8 matmul at the model's own projection shape -----
     cases.append(KernelCase(

@@ -83,7 +83,7 @@ class BlockDecl:
     kind: str                      # "in" | "out" | "scratch"
     shape: Tuple[Dim, ...]
     dtype: str
-    memory: str = "vmem"           # "vmem" | "smem"
+    memory: str = "vmem"           # "vmem" | "smem" | "hbm"
     lanes_full: bool = False
     sublane_full: bool = False
     waivers: Tuple[str, ...] = ()
@@ -511,6 +511,63 @@ PAGED_RAGGED_STATS = KernelContract(
 )
 
 # ===========================================================================
+# paged_kv_write.py — a ragged step's new K/V rows into the page pools,
+# live rows only, a page the lane covers whole as one copy.  Grid (G,):
+# one lane a step, K and V together.  The lane's rows arrive through the
+# pipeline — its first `tile` of them where they hold every live row (a
+# decode lane), else the whole lane (`rows` rounded up to `tile`) — with
+# its first page where the live rows cover it in part; the image the rows
+# are rolled into and the last page, where read, are scratch.  The pools
+# stay in HBM (aliased to the outputs) and every DMA slice is whole
+# tiles, so `page_size` must be a multiple of `tile`.
+# ===========================================================================
+PAGED_KV_WRITE = KernelContract(
+    name="paged_kv_write",
+    module="paddle_tpu/ops/pallas_ops/paged_kv_write.py",
+    grid=("groups",),
+    dims={"page_size": 16, "tile": 8, "rows": 64, "image_rows": 80,
+          "kv_width": 768},
+    blocks=(
+        BlockDecl("page_tables", "in", ("groups", "pages_per_seq"),
+                  "int32", memory="smem"),
+        BlockDecl("first", "in", ("groups",), "int32", memory="smem"),
+        BlockDecl("live", "in", ("groups",), "int32", memory="smem"),
+        BlockDecl("tile_lane", "in", ("groups",), "int32",
+                  memory="smem"),
+        BlockDecl("rows_lane", "in", ("groups",), "int32",
+                  memory="smem"),
+        BlockDecl("head_page", "in", ("groups",), "int32",
+                  memory="smem"),
+        BlockDecl("k_tile", "in", (1, "tile", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("v_tile", "in", (1, "tile", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("k_rows", "in", (1, "rows", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("v_rows", "in", (1, "rows", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("k_head", "in", (1, "page_size", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("v_head", "in", (1, "page_size", "kv_width"), "float32",
+                  lanes_full=True),
+        BlockDecl("k_pool", "out", ("pages", "page_size", "kv_width"),
+                  "float32", memory="hbm"),
+        BlockDecl("v_pool", "out", ("pages", "page_size", "kv_width"),
+                  "float32", memory="hbm"),
+        # the pages a lane's rows can reach: its rows rolled page-aligned
+        BlockDecl("k_image", "scratch", ("image_rows", "kv_width"),
+                  "float32", lanes_full=True),
+        BlockDecl("v_image", "scratch", ("image_rows", "kv_width"),
+                  "float32", lanes_full=True),
+        BlockDecl("k_tail", "scratch", ("page_size", "kv_width"),
+                  "float32", lanes_full=True),
+        BlockDecl("v_tail", "scratch", ("page_size", "kv_width"),
+                  "float32", lanes_full=True),
+    ),
+    # no sweep: every dim is the data's (the tile is the dtype's)
+)
+
+# ===========================================================================
 # quantized_matmul.py — weight-only int8 matmul.  Grid (M/bm, N/bn,
 # K/bk), K innermost; int8 weight blocks satisfy the (32, 128) floor at
 # the default 128x128x128 tiling.
@@ -635,6 +692,6 @@ DELTA_RULE_BWD = KernelContract(
 CONTRACTS: Dict[str, KernelContract] = {
     c.name: c for c in (FLASH_FWD, FLASH_BWD_DKV, FLASH_BWD_DQ,
                         PAGED_RAGGED, PAGED_RAGGED_INT8,
-                        PAGED_RAGGED_STATS, QUANTIZED_MATMUL,
-                        DELTA_RULE_FWD, DELTA_RULE_BWD)
+                        PAGED_RAGGED_STATS, PAGED_KV_WRITE,
+                        QUANTIZED_MATMUL, DELTA_RULE_FWD, DELTA_RULE_BWD)
 }

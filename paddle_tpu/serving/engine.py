@@ -848,9 +848,12 @@ class ServingEngine:
         self._ragged_steady: Dict[int, tuple] = {}
         from ..text.generation import RAGGED_NO_LIMIT
         self._ragged_no_limit = RAGGED_NO_LIMIT
-        # the kernel's own row-block rule, for the attn_rows_skipped count
+        # the kernels' own rules, for the attn_rows_skipped count and the
+        # pool write's kv_rows_written / kv_page_copies
         from ..ops.pallas_ops.paged_attention import ragged_rows_skipped
+        from ..ops.pallas_ops.paged_kv_write import kv_write_counts
         self._rows_skipped = ragged_rows_skipped
+        self._kv_write_counts = kv_write_counts
 
     def _dput(self, x):
         """Host→device upload for engine state.  In mesh mode every
@@ -1758,6 +1761,19 @@ class ServingEngine:
                 + len(idle) * self._rows_skipped(0, Q)
                 + sum(self._rows_skipped(c[0].size, Q)
                       for c in chunks.values()))
+            # the pool write's work: every lane but a chunk or idle one
+            # writes its row 0 (whether one row is a whole page does not
+            # depend on where it sits), a chunk lane the rows under its
+            # prompt's length, an idle lane nothing
+            rows1, pages1 = self._kv_write_counts(0, 1, self.page_size)
+            kv_rows = (B - len(chunks) - len(idle)) * rows1
+            kv_pages = (B - len(chunks) - len(idle)) * pages1
+            for ctok, cpos, n in chunks.values():
+                first = int(cpos[0])
+                r, p = self._kv_write_counts(
+                    first, min(ctok.size, n - first), self.page_size)
+                kv_rows += r
+                kv_pages += p
             ev.set(chunks=len(chunks), idle=len(idle))
         if self._mesh_layout is not None:
             # chaos site ``serving.shard_sync``: the last host boundary
@@ -1772,7 +1788,8 @@ class ServingEngine:
         with RecordEvent("serving/ragged_step", bucket=B, rows=Q,
                          decode_rows=decode_rows, prefill_rows=prefill_rows,
                          ctx_tokens=ctx_tokens, attn_pairs=attn_pairs,
-                         attn_rows_skipped=attn_rows_skipped):
+                         attn_rows_skipped=attn_rows_skipped,
+                         kv_rows_written=kv_rows, kv_page_copies=kv_pages):
             (_out_rows, out_dec, self._tokens, self._pos,
              self._kv) = self._ragged_jit(
                 self._tokens, self._pos, self._tables, rows_tok,
@@ -1791,7 +1808,8 @@ class ServingEngine:
         self.metrics.on_ragged(
             decode_rows=decode_rows, prefill_rows=prefill_rows, q_bucket=Q,
             rows_computed=B * Q, ctx_tokens=ctx_tokens,
-            attn_pairs=attn_pairs, attn_rows_skipped=attn_rows_skipped)
+            attn_pairs=attn_pairs, attn_rows_skipped=attn_rows_skipped,
+            kv_rows_written=kv_rows, kv_page_copies=kv_pages)
         for sid, plan in done_plans:
             if not plan["count"]:
                 # barrier-only plan (fully-covered prefix hit): the
@@ -2156,7 +2174,12 @@ class ServingEngine:
                     rows_computed=bucket * K, ctx_tokens=ctx,
                     attn_pairs=K * ctx - len(active) * K * (K - 1) // 2,
                     attn_rows_skipped=(bucket - len(active))
-                    * self._rows_skipped(0, K))
+                    * self._rows_skipped(0, K),
+                    kv_rows_written=K * len(active),
+                    kv_page_copies=sum(
+                        self._kv_write_counts(seq.pos, K,
+                                              self.page_size)[1]
+                        for _, seq in active))
                 t0 = time.perf_counter()
                 toks = np.ascontiguousarray(              # [K, bucket]
                     np.asarray(jax.device_get(out_rows)).T)

@@ -135,6 +135,11 @@ class ServingMetrics:
                 # bucket beyond each lane's last live row, rounded up to
                 # the kernel's row block (ISSUE 29)
                 "serving.ragged.attn_rows_skipped",
+                # the pool write's work by the write kernel's rule: the
+                # live rows it writes, and the pages they cover whole
+                # (each one page copy with nothing read first)
+                "serving.ragged.kv_rows_written",
+                "serving.ragged.kv_page_copies",
                 # mesh-sharded serving (ISSUE 19): ragged dispatches that
                 # ran as one mesh program (every step crosses the
                 # tp/sp collectives), and maintenance traffic that had to
@@ -349,7 +354,8 @@ class ServingMetrics:
     def on_ragged(self, *, decode_rows: int = 0, prefill_rows: int = 0,
                   spec_rows: int = 0, q_bucket: int = 0,
                   rows_computed: int = 0, ctx_tokens: int = 0,
-                  attn_pairs: int = 0, attn_rows_skipped: int = 0):
+                  attn_pairs: int = 0, attn_rows_skipped: int = 0,
+                  kv_rows_written: int = 0, kv_page_copies: int = 0):
         """One ``serving.ragged_step`` dispatch's row mix: ``decode_rows``
         lanes advanced one position, ``prefill_rows`` prompt positions
         rode along as chunk rows (instead of serializing ahead of the
@@ -360,7 +366,10 @@ class ServingMetrics:
         positions read over the lanes with a row that carries a token,
         ``attn_pairs`` = position + 1 over every such row,
         ``attn_rows_skipped`` = the rows of ``rows_computed`` the ragged
-        kernel's row blocks leave out (past a lane's last live row)."""
+        kernel's row blocks leave out (past a lane's last live row),
+        ``kv_rows_written`` / ``kv_page_copies`` = the live rows the
+        pool write puts into the pages and the pages they cover whole
+        (``pallas_ops.paged_kv_write.kv_write_counts``)."""
         stat_registry.get("serving.ragged.steps").add(1)
         stat_registry.get("serving.ragged.rows_computed").add(
             int(rows_computed))
@@ -368,6 +377,10 @@ class ServingMetrics:
         stat_registry.get("serving.ragged.attn_pairs").add(int(attn_pairs))
         stat_registry.get("serving.ragged.attn_rows_skipped").add(
             int(attn_rows_skipped))
+        stat_registry.get("serving.ragged.kv_rows_written").add(
+            int(kv_rows_written))
+        stat_registry.get("serving.ragged.kv_page_copies").add(
+            int(kv_page_copies))
         if decode_rows:
             stat_registry.get("serving.ragged.decode_rows").add(
                 int(decode_rows))
@@ -537,7 +550,8 @@ class ServingMetrics:
             short: stat_registry.get(f"serving.ragged.{short}").get()
             for short in ("steps", "decode_rows", "prefill_rows",
                           "spec_rows", "row_bucket", "rows_computed",
-                          "ctx_tokens", "attn_pairs", "attn_rows_skipped")}
+                          "ctx_tokens", "attn_pairs", "attn_rows_skipped",
+                          "kv_rows_written", "kv_page_copies")}
         snap["disagg"] = {"shipped_pages": stat_registry.get(
             "serving.disagg.shipped_pages").get()}
         snap["shard"] = {

@@ -413,6 +413,12 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
     ``valid_len`` (scalar, traced) masks bucket padding: lanes with
     ``pos >= valid_len`` scatter into the reserved trash page 0 and clamp
     their attention span, so padded lanes can never touch live pages.
+    In the ragged layout with native pools, on a TPU and without ``sp``,
+    the write is the paged KV write kernel instead
+    (``ops/pallas_ops/paged_kv_write.py``): each lane's live rows — a
+    prefix of its Q rows at consecutive positions, as the engine plans
+    them — go into their pages, whole pages as one copy, and padded rows
+    go nowhere.
     ``with_head=False`` skips the [N, V] logits matmul (prefill discards
     logits — the first decode step consumes the last prompt token).
 
@@ -446,6 +452,7 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
     projection/MLP matmuls through the weight-only int8 kernel.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from ..ops.pallas_ops import paged_kv_write as kv_write
     from ..ops.pallas_ops.paged_attention import (
         paged_attention as paged_attn,
         ragged_paged_attention as ragged_paged_attn,
@@ -565,11 +572,26 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
                                  page_idx % pages_local, 0)
             pt_owner = (page_tables // pages_local) == sp_i
             page_tables = jnp.where(pt_owner, page_tables % pages_local, 0)
+        # the ragged layout's live rows are a prefix of each lane's Q rows
+        # at consecutive positions (the engine plans them so): one Pallas
+        # call a layer writes them as whole pages and live rows.  The row
+        # scatter stays where that does not hold or the kernel cannot
+        # run: the split programs, int8 pools (scales grow per page), sp
+        # (non-owned rows go to the trash page mid-range), the CPU
+        write_pages = (Q is not None and not quant_kv and sp == 1
+                       and kv_write.routes(kv["k"][0]))
+        if write_pages:
+            live = pos < vlen if vlen is not None else pos >= 0
+            w_first = pos.reshape(N // Q, Q)[:, 0]
+            w_live = jnp.sum(live.reshape(N // Q, Q), axis=1,
+                             dtype=jnp.int32)
         kv_out = {key: [] for key in kv}
 
         def attend(i, q, k1, v1):
             # k1/v1 stay [N, H_loc*D]: the projection's rows ARE the
-            # pool's rows, scattered in place on the donated buffer
+            # pool's rows, written in place on the donated buffer
+            kv_write.WRITE_ROUTE_STATS[
+                "pallas" if write_pages else "scatter"] += 1
             if quant_kv:
                 kc, ks_ = _quant_write_page(
                     kv["k"][i], kv["k_scale"][i], page_idx, slot,
@@ -580,6 +602,11 @@ def _make_gpt_paged_core(model, page_size: int, pages_per_seq: int, *,
                 kv_out["k_scale"].append(ks_)
                 kv_out["v_scale"].append(vs_)
                 scales = (ks_, vs_)
+            elif write_pages:
+                kc, vc = kv_write.paged_kv_write(
+                    k1, v1, kv["k"][i], kv["v"][i], page_tables, w_first,
+                    w_live)
+                scales = ()
             else:
                 kc = kv["k"][i].at[page_idx, slot].set(k1)
                 vc = kv["v"][i].at[page_idx, slot].set(v1)

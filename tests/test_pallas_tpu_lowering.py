@@ -140,14 +140,18 @@ def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype, rows,
 
     import paddle_tpu
     from paddle_tpu.ops.pallas_ops import paged_attention as pa
+    from paddle_tpu.ops.pallas_ops import paged_kv_write as kw
     from paddle_tpu.serving.engine import (aliased_arguments,
                                            whole_pool_relayouts)
     from paddle_tpu.text.generation import make_gpt_paged_ragged_step
     from paddle_tpu.text.models import GPTModel
 
-    # the kernel route, compiled and not interpreted, as on the chip
+    # the kernel routes, compiled and not interpreted, as on the chip:
+    # the ragged kernel reads the pools, the write kernel (native pools)
+    # writes them
     monkeypatch.setenv("PADDLE_TPU_FORCE_PAGED", "1")
     monkeypatch.setattr(pa, "_interpret_mode", lambda: False)
+    monkeypatch.setattr(kw, "_interpret_mode", lambda: False)
     paddle_tpu.seed(0)
     model = GPTModel(vocab_size=256, hidden_size=H * D, num_layers=1,
                      num_heads=H, ffn_size=256, max_seq_len=1024,
@@ -170,7 +174,10 @@ def test_serve_step_never_relayouts_a_pool_on_v5e(H, D, kv_dtype, rows,
         spec((lanes, rows)), spec((lanes, rows)), spec((lanes, rows)),
         spec((lanes,)), kv).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    # the layer's ragged kernel, and its write kernel where the pools are
+    # native (int8 pools keep the row scatter: their scales grow per page)
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (1 if kv_dtype else 2)
     assert whole_pool_relayouts(text, pages, page_size) == []
     assert aliased_arguments(text) == len(pools)
     pool_bytes = pages * page_size * H * D * pools[0].dtype.itemsize
@@ -192,6 +199,44 @@ def test_the_serve_cells_kernel_call_compiles_for_v5e(v5e_topology):
              for a in case.args]
     text = jax.jit(case.kernel).lower(*specs).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_the_kv_write_kernel_compiles_per_tp_shard_on_a_v5e_mesh(
+        v5e_topology):
+    """The mesh engine runs the paged KV write per tp shard, under the
+    core's ``shard_map``: at the serve cell's shape split over tp
+    2 of a 2x2 host, each shard writes its 384-wide half of every row into
+    its half of the pools, in place — one Mosaic call, no copy of a pool.
+    (No chip has run this route: ``chip_smoke.py``'s four-chip phase.)"""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.pallas_ops import paged_kv_write as kw
+
+    mesh = Mesh(np.array(v5e_topology.devices).reshape(2, 2), ("data", "tp"))
+    lanes, rows, width, pages, page_size, table = 48, 64, 768, 3073, 16, 64
+    row_spec, pool_spec = P(None, "tp"), P(None, None, "tp")
+
+    def write(*args):
+        return kw.paged_kv_write(*args, interpret=False)
+
+    fn = jax.shard_map(write, mesh=mesh,
+                       in_specs=(row_spec, row_spec, pool_spec, pool_spec,
+                                 P(), P(), P()),
+                       out_specs=(pool_spec, pool_spec), check_vma=False)
+
+    def spec(shape, dtype, part):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, part))
+
+    new = spec((lanes * rows, width), jnp.float32, row_spec)
+    pool = spec((pages, page_size, width), jnp.float32, pool_spec)
+    text = jax.jit(fn, donate_argnums=(2, 3)).lower(
+        new, new, pool, pool, spec((lanes, table), jnp.int32, P()),
+        spec((lanes,), jnp.int32, P()), spec((lanes,), jnp.int32, P())
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"[{pages}," in line]
 
 
 @pytest.mark.parametrize("case", range(4), ids=[

@@ -282,3 +282,192 @@ class TestPoolLayout:
         # nothing of the chip: tests/test_pallas_tpu_lowering.py asks the
         # v5e's compiler for its optimised program.)
         assert aliased_arguments(compiled.as_text()) == len(pools)
+
+
+# =============================================================================
+# the pool write: live rows as whole pages, where the kernel can run
+# =============================================================================
+def _fresh_gpt():
+    """The shared model's twin (same seed, same weights) as a NEW object:
+    the engine caches step programs per model object, and a route is
+    chosen when a program is traced."""
+    import paddle_tpu
+    from paddle_tpu.text.models import GPTModel
+
+    paddle_tpu.seed(11)
+    m = GPTModel(vocab_size=VOCAB, hidden_size=32, num_layers=2,
+                 num_heads=2, ffn_size=64, max_seq_len=64, dropout=0.0)
+    m.eval()
+    return m
+
+
+def _spy_rows(eng):
+    """Record (state pos, rows_pos, row_valid, advance) of every ragged
+    dispatch, as the device was handed them."""
+    seen = []
+    real = eng._ragged_jit
+
+    def spy(tokens, pos, tables, rows_tok, rows_pos, row_valid, advance,
+            kv):
+        seen.append(tuple(np.asarray(jax.device_get(a)) for a in
+                          (pos, rows_pos, row_valid, advance)))
+        return real(tokens, pos, tables, rows_tok, rows_pos, row_valid,
+                    advance, kv)
+
+    eng._ragged_jit = spy
+    return seen
+
+
+def _lane_rows(state_pos, rows_pos, row_valid, advance):
+    """Per lane, (effective position, live) of each row — as the step
+    computes them: an advancing lane's row 0 sits at its device position,
+    a row is live where its position is under its valid length."""
+    eff = rows_pos.copy()
+    eff[:, 0] = np.where(advance > 0, state_pos, rows_pos[:, 0])
+    return eff, eff < row_valid
+
+
+class TestPoolWrite:
+    """The ragged step hands each layer's K/V rows to the paged KV write
+    kernel (``ops/pallas_ops/paged_kv_write.py``) where it can run and the
+    engine's rows have the shape it needs; the row scatter stays
+    everywhere else."""
+
+    @pytest.mark.parametrize("drive", ["chunks_decode_idle", "spec_verify"])
+    def test_planned_live_rows_are_a_prefix_at_consecutive_positions(
+            self, gpt, drive):
+        """The kernel describes a lane by its row-0 position and its live
+        rows, which holds only while the planner lays every lane's live
+        rows out as a prefix of its Q rows at consecutive positions: chunk
+        rows (short final chunks padded with junk), decode lanes (row 0),
+        barrier-held idle lanes (none), steady decode (Q = 1) and
+        spec-verify rows.  The counters the engine computes at dispatch
+        by the kernel's rule equal that rule applied to those arrays."""
+        from paddle_tpu.ops.pallas_ops.paged_kv_write import kv_write_counts
+
+        rng = np.random.RandomState(38)
+        page = 4
+        if drive == "spec_verify":
+            eng = ServingEngine(gpt, page_size=page, max_batch_size=4,
+                                prefill_chunk=4, eos_id=-1, spec_decode=4)
+        else:
+            eng = ServingEngine(gpt, page_size=page, max_batch_size=4,
+                                prefill_chunk=4, eos_id=-1,
+                                prefix_cache=True)
+        seen = _spy_rows(eng)
+        r0 = stat_registry.get("serving.ragged.kv_rows_written").get()
+        p0 = stat_registry.get("serving.ragged.kv_page_copies").get()
+        if drive == "spec_verify":
+            for p in (2, 3):
+                eng.add_request(np.tile(rng.randint(1, VOCAB, (p,))
+                                        .astype(np.int32), 4),
+                                max_new_tokens=16)
+        else:
+            shared = rng.randint(1, VOCAB, (8,)).astype(np.int32)
+            eng.add_request(rng.randint(1, VOCAB, (3,)).astype(np.int32),
+                            max_new_tokens=12)
+            eng.step()
+            eng.step()
+            for tail in (5, 3):
+                eng.add_request(np.concatenate(
+                    [shared, rng.randint(1, VOCAB, (tail,))
+                     .astype(np.int32)]), max_new_tokens=4)
+            eng.add_request(rng.randint(1, VOCAB, (14,)).astype(np.int32),
+                            max_new_tokens=3)
+        eng.drain()
+        kinds = set()
+        rows = pages = 0
+        for state_pos, rows_pos, row_valid, advance in seen:
+            eff, live = _lane_rows(state_pos, rows_pos, row_valid, advance)
+            Q = live.shape[1]
+            for b in range(live.shape[0]):
+                n = int(live[b].sum())
+                # a prefix of the lane's rows, at consecutive positions
+                assert live[b, :n].all() and not live[b, n:].any(), b
+                assert (eff[b, :n] == eff[b, 0] + np.arange(n)).all(), b
+                r, p = kv_write_counts(int(eff[b, 0]), n, page)
+                rows += r
+                pages += p
+                kinds.add("steady" if Q == 1 else "decode" if advance[b]
+                          else "idle" if n == 0
+                          else "spec" if (row_valid[b] > 1 << 20).all()
+                          else "short_chunk" if n < Q else "chunk")
+        want = ({"steady", "spec"} if drive == "spec_verify" else
+                {"steady", "decode", "idle", "chunk", "short_chunk"})
+        assert want <= kinds, kinds
+        assert stat_registry.get(
+            "serving.ragged.kv_rows_written").get() - r0 == rows
+        assert stat_registry.get(
+            "serving.ragged.kv_page_copies").get() - p0 == pages
+        if drive != "spec_verify":
+            assert pages > 0          # 4-row chunks from a page boundary
+        assert eng.cache.pages_in_use == 0
+
+    @pytest.mark.parametrize("where", [
+        "cpu", "forced", "int8", "split", "page4", "sp2", "tp2"])
+    def test_kernel_routes_only_where_it_applies(self, monkeypatch, where):
+        """Interpret mode stands in for the TPU (``PADDLE_TPU_FORCE_PAGED``,
+        as for the paged-attention kernels).  The kernel takes the pool
+        write of a ragged step with native 32-bit pools, pages of whole
+        tiles and no sequence sharding; int8 pools (scales grow per
+        page), the split programs (no lane layout), sp (non-owned rows go
+        to the trash page mid-range), 4-row pages and the CPU keep the
+        row scatter.  Under tp the kernel runs per shard."""
+        from paddle_tpu.ops.pallas_ops.paged_kv_write import (
+            WRITE_ROUTE_STATS)
+
+        if where != "cpu":
+            monkeypatch.setenv("PADDLE_TPU_FORCE_PAGED", "1")
+        kw = dict(page_size=4 if where == "page4" else 8, max_batch_size=2,
+                  prefill_chunk=8, eos_id=-1)
+        if where == "int8":
+            kw["kv_cache_dtype"] = "int8"
+        if where in ("sp2", "tp2"):
+            kw["mesh_axes"] = {where[:2]: 2}
+        before = dict(WRITE_ROUTE_STATS)
+        if where == "split":
+            eng = ServingEngine(_fresh_gpt(), ragged=False, **kw)
+            eng.add_request(np.arange(1, 6, dtype=np.int32),
+                            max_new_tokens=2)
+            eng.drain()
+        else:
+            eng = ServingEngine(_fresh_gpt(), **kw)
+            eng.lower_ragged_step(8)
+        grew = {k: WRITE_ROUTE_STATS[k] - before[k] for k in before}
+        if where in ("forced", "tp2"):
+            assert grew == {"pallas": 2, "scatter": 0}   # two layers
+        else:
+            assert grew["pallas"] == 0 and grew["scatter"] >= 2
+
+    @pytest.mark.parametrize("mode", ["mixed", "prefix_cache", "spec"])
+    def test_kernel_route_serves_the_same_tokens(self, monkeypatch, mode):
+        """An engine whose pool writes go through the kernel (interpret
+        mode) streams the same tokens as one on the row scatter: mixed
+        chunk / decode / padded-chunk steps, prefix hits with barrier-held
+        lanes, and spec-verify rows."""
+        from paddle_tpu.ops.pallas_ops.paged_kv_write import (
+            WRITE_ROUTE_STATS)
+
+        rng = np.random.RandomState(39)
+        shared = rng.randint(1, VOCAB, (16,)).astype(np.int32)
+        if mode == "spec":
+            prompts = [np.tile(rng.randint(1, VOCAB, (p,)).astype(np.int32),
+                               5) for p in (2, 3, 4)]
+        elif mode == "prefix_cache":
+            prompts = [np.concatenate([shared, rng.randint(
+                1, VOCAB, (n,)).astype(np.int32)]) for n in (5, 2, 11)]
+        else:
+            prompts = _mixed_prompts(rng, lens=(3, 19, 11, 2, 27))
+        kw = dict(page_size=8, max_batch_size=4, prefill_chunk=8,
+                  eos_id=-1, prefix_cache=mode == "prefix_cache",
+                  spec_decode=4 if mode == "spec" else False)
+        monkeypatch.delenv("PADDLE_TPU_FORCE_PAGED", raising=False)
+        ref = _drive(ServingEngine(_fresh_gpt(), **kw), prompts, budget=9)
+        monkeypatch.setenv("PADDLE_TPU_FORCE_PAGED", "1")
+        before = WRITE_ROUTE_STATS["pallas"]
+        eng = ServingEngine(_fresh_gpt(), **kw)
+        got = _drive(eng, prompts, budget=9)
+        assert WRITE_ROUTE_STATS["pallas"] > before
+        for a, b in zip(ref, got):
+            np.testing.assert_array_equal(a, b)
+        assert eng.cache.pages_in_use == 0
