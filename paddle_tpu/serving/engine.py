@@ -1494,7 +1494,7 @@ class ServingEngine:
                           for i in range(desired)]
 
     # --- prefill ----------------------------------------------------------
-    def _prefill_seq(self, seq: Sequence):
+    def _prefill_seq(self, seq: Sequence) -> int:
         """Teacher-force prompt[:-1] through the paged cache in parallel
         chunks of up to ``prefill_chunk`` positions — O(P/C) dispatches.
         Padded tail positions scatter into the trash page (valid_len
@@ -1505,12 +1505,13 @@ class ServingEngine:
         at the first uncached token (the ``valid_len`` machinery handles
         the ragged start; positions are absolute, so the chunk queries
         attend over the shared pages like any previously-written ones).
-        A fully-covered prompt dispatches NOTHING."""
+        A fully-covered prompt dispatches NOTHING.  Returns the chunks
+        dispatched."""
         prompt = seq.request.prompt
         n = prompt.size - 1
         start = min(seq.cached_tokens, n)
         if n - start == 0:
-            return
+            return 0
         spans = chunk_schedule(n - start, self.prefill_chunk)
         row = self._dput(self.cache.page_table_row(seq.seq_id))
         n_dev = self._dput(np.int32(n))
@@ -1536,9 +1537,10 @@ class ServingEngine:
         dt = time.perf_counter() - t0
         self.metrics.on_prefill(dt)
         self.metrics.on_prefill_chunks(len(spans), n - start, dt)
+        return len(spans)
 
     # --- unified ragged dispatch (ISSUE 18) -------------------------------
-    def _plan_prefill(self, seq: Sequence, awaits=()):
+    def _plan_prefill(self, seq: Sequence, awaits=()) -> int:
         """Ragged-mode admission: BUILD the chunk plan (host arrays
         only, no dispatch) — each following engine step pops one chunk
         into the mixed ragged dispatch, interleaved with every other
@@ -1557,14 +1559,15 @@ class ServingEngine:
         copy) until every awaited page's write has been dispatched, so
         device program order commits the payload before any read.  A
         fully-covered prompt with a non-empty barrier gets a chunkless
-        plan that exists only to hold the lane idle."""
+        plan that exists only to hold the lane idle.  Returns the chunks
+        planned."""
         prompt = seq.request.prompt
         n = prompt.size - 1
         start = min(seq.cached_tokens, n)
         awaits = set(awaits)
         cow = seq.cow_pair is not None and bool(awaits)
         if n - start == 0 and not awaits:
-            return
+            return 0
         chunks: Deque[Tuple[np.ndarray, np.ndarray, int]] = deque()
         for off, size in chunk_schedule(n - start, self.prefill_chunk) \
                 if n - start else ():
@@ -1591,6 +1594,7 @@ class ServingEngine:
             "chunks": chunks, "t0": time.perf_counter(),
             "count": len(chunks), "tokens": n - start,
             "await": awaits, "cow": cow, "pending": pend}
+        return len(chunks)
 
     def _drop_plan(self, seq_id: str) -> set:
         """Remove a sequence's prefill plan (preemption / abort /
@@ -1787,9 +1791,7 @@ class ServingEngine:
             self.metrics.on_shard_step()
         with RecordEvent("serving/ragged_step", bucket=B, rows=Q,
                          decode_rows=decode_rows, prefill_rows=prefill_rows,
-                         ctx_tokens=ctx_tokens, attn_pairs=attn_pairs,
-                         attn_rows_skipped=attn_rows_skipped,
-                         kv_rows_written=kv_rows, kv_page_copies=kv_pages):
+                         ctx_tokens=ctx_tokens, attn_pairs=attn_pairs):
             (_out_rows, out_dec, self._tokens, self._pos,
              self._kv) = self._ragged_jit(
                 self._tokens, self._pos, self._tables, rows_tok,
@@ -2006,10 +2008,16 @@ class ServingEngine:
             self._clear_lane(lane)
 
     def _sync_pending(self) -> int:
-        """Collapse the pipeline: consume every in-flight step."""
+        """Collapse the pipeline: consume every in-flight step.  Every
+        caller collapses through here, so the span and the counter see
+        each collapse that drained a step, whoever asked for it."""
+        if not self._pending:
+            return 0
         emitted = 0
-        while self._pending:
-            emitted += self._consume_one()
+        with RecordEvent("serving/collapse", steps=len(self._pending)):
+            while self._pending:
+                emitted += self._consume_one()
+        self.metrics.on_pipeline_collapse()
         return emitted
 
     # --- speculative decoding (docs/SERVING.md "Speculative decoding") ----
@@ -2244,88 +2252,98 @@ class ServingEngine:
     def _admit_waiting(self) -> List[Sequence]:
         """Admit what fits from the waiting queue (pipeline already
         collapsed) and run each admission's device half: page-scale
-        reset, snapshot upload or COW copy + prefill plan, lane bind."""
+        reset, snapshot upload or COW copy + prefill plan, lane bind.
+        The scheduler's choice is one span (``serving/schedule``), each
+        admitted sequence's set-up another (``serving/admit_request``)."""
         sched = self.scheduler
-        if self.kv_transport is not None:
-            # admission boundary (ISSUE 16): promote tier hits for
-            # the waiting prompts, and open the ONLY window where
-            # evictions demote (admission-pressure reclaims gather
-            # D2H here; decode-time pressure keeps discarding, so
-            # steady decode never pays a transfer)
-            self.kv_transport.chaos_key = self.chaos_key
-            self.kv_transport.demote_window = True
-            try:
-                for req in sched.waiting:
-                    if req.resume is None and req.use_prefix_cache:
-                        self.prefix_cache.promote_for(req.prompt)
+        with RecordEvent("serving/schedule") as ev:
+            if self.kv_transport is not None:
+                # admission boundary (ISSUE 16): promote tier hits for
+                # the waiting prompts, and open the ONLY window where
+                # evictions demote (admission-pressure reclaims gather
+                # D2H here; decode-time pressure keeps discarding, so
+                # steady decode never pays a transfer)
+                self.kv_transport.chaos_key = self.chaos_key
+                self.kv_transport.demote_window = True
+                try:
+                    for req in sched.waiting:
+                        if req.resume is None and req.use_prefix_cache:
+                            self.prefix_cache.promote_for(req.prompt)
+                    admitted = sched.admit()
+                finally:
+                    self.kv_transport.demote_window = False
+            else:
                 admitted = sched.admit()
-            finally:
-                self.kv_transport.demote_window = False
-        else:
-            admitted = sched.admit()
+            ev.set(admitted=len(admitted))
         now = time.monotonic()
         self.metrics.on_admission(
             len(admitted),
             queue_waits=[now - s.request.arrival_time for s in admitted])
         for seq in admitted:
+            resume = seq.request.resume is not None
             flight.request_event(seq.seq_id, EV_ADMITTED,
-                                 replica=self.chaos_key,
-                                 resume=seq.request.resume is not None)
-            if seq.request.resume is None and seq.cached_tokens:
+                                 replica=self.chaos_key, resume=resume)
+            if not resume and seq.cached_tokens:
                 flight.request_event(seq.seq_id, EV_PREFIX_HIT,
                                      replica=self.chaos_key,
                                      tokens=int(seq.cached_tokens))
-            # freshly allocated pages must quantize from scratch
-            # (dynamic int8 mode; no-op otherwise — and dynamic
-            # mode bypasses the prefix cache, so no shared page can
-            # ever be scale-reset here)
-            self._reset_page_scales(self.cache.seq_page_ids(seq.seq_id))
-            if seq.request.resume is not None:
-                # warm-failover resume: upload checkpoint pages
-                # instead of prefilling — decode continues mid-stream
-                self._upload_snapshot(seq)
-            else:
-                # hit/miss accounting and the sealing of prompt
-                # pages happened inside Scheduler.admit (host-side,
-                # so intra-batch sharing works); the device halves
-                # — the COW page copy and the suffix prefill — run
-                # here in admission order
-                deps = ()
-                if self.ragged and seq.cached_tokens \
-                        and self.prefix_cache is not None:
-                    # shared pages this sequence READS whose writer
-                    # is itself still mid-plan: the lane must idle
-                    # until their writes are issued (and the COW
-                    # copy below must wait with it — it would
-                    # duplicate an empty page)
-                    ids = self.cache.seq_page_ids(seq.seq_id)
-                    unw = self.prefix_cache.unwritten
-                    deps = {int(p) for p in
-                            ids[:seq.cached_tokens // self.page_size]
-                            if int(p) in unw}
-                    if seq.cow_pair is not None \
-                            and int(seq.cow_pair[0]) in unw:
-                        # the COW SOURCE is no longer in this
-                        # sequence's table (the host already
-                        # swapped in the copy) but the copy's
-                        # payload comes from it
-                        deps.add(int(seq.cow_pair[0]))
-                if seq.cow_pair is not None and not deps:
-                    self._apply_cow(seq)
-                if self.ragged:
-                    # unified dispatch: plan now, chunks ride the
-                    # mixed ragged steps (no dedicated prefill
-                    # program, no serialization ahead of decode)
-                    self._plan_prefill(seq, awaits=deps)
-                else:
-                    self._prefill_seq(seq)
-            self._bind_lane(seq)
-            if self.spec is not None:
-                # seed the drafter with the lane's full history
-                # (prompt, plus generated for a snapshot resume —
-                # which also restores the drafter's adaptive state)
-                self.spec.on_admit(seq)
+            with RecordEvent("serving/admit_request", resume=int(resume),
+                             cached_tokens=int(seq.cached_tokens)) as ev:
+                ev.set(chunks=self._admit_one(seq, resume))
         return admitted
+
+    def _admit_one(self, seq: Sequence, resume: bool) -> int:
+        """One admitted sequence's device half; returns the prefill
+        chunks planned (or dispatched, split mode) — 0 for a resume or
+        a prompt the prefix cache covers whole."""
+        chunks = 0
+        # freshly allocated pages must quantize from scratch (dynamic
+        # int8 mode; no-op otherwise — and dynamic mode bypasses the
+        # prefix cache, so no shared page can ever be scale-reset here)
+        self._reset_page_scales(self.cache.seq_page_ids(seq.seq_id))
+        if resume:
+            # warm-failover resume: upload checkpoint pages instead of
+            # prefilling — decode continues mid-stream
+            self._upload_snapshot(seq)
+        else:
+            # hit/miss accounting and the sealing of prompt pages
+            # happened inside Scheduler.admit (host-side, so intra-batch
+            # sharing works); the device halves — the COW page copy and
+            # the suffix prefill — run here in admission order
+            deps = ()
+            if self.ragged and seq.cached_tokens \
+                    and self.prefix_cache is not None:
+                # shared pages this sequence READS whose writer is
+                # itself still mid-plan: the lane must idle until their
+                # writes are issued (and the COW copy below must wait
+                # with it — it would duplicate an empty page)
+                ids = self.cache.seq_page_ids(seq.seq_id)
+                unw = self.prefix_cache.unwritten
+                deps = {int(p) for p in
+                        ids[:seq.cached_tokens // self.page_size]
+                        if int(p) in unw}
+                if seq.cow_pair is not None \
+                        and int(seq.cow_pair[0]) in unw:
+                    # the COW SOURCE is no longer in this sequence's
+                    # table (the host already swapped in the copy) but
+                    # the copy's payload comes from it
+                    deps.add(int(seq.cow_pair[0]))
+            if seq.cow_pair is not None and not deps:
+                self._apply_cow(seq)
+            if self.ragged:
+                # unified dispatch: plan now, chunks ride the mixed
+                # ragged steps (no dedicated prefill program, no
+                # serialization ahead of decode)
+                chunks = self._plan_prefill(seq, awaits=deps)
+            else:
+                chunks = self._prefill_seq(seq)
+        self._bind_lane(seq)
+        if self.spec is not None:
+            # seed the drafter with the lane's full history (prompt,
+            # plus generated for a snapshot resume — which also
+            # restores the drafter's adaptive state)
+            self.spec.on_admit(seq)
+        return chunks
 
     # --- one scheduler iteration -----------------------------------------
     def step(self) -> dict:

@@ -1412,7 +1412,10 @@ class ServingFrontend:
             pending, rep.captures = rep.captures, []
         for entry, cap in pending:
             rid = entry.handle.request_id
-            with RecordEvent("serving/snapshot"):
+            # the landing's own child tells it from a capture's
+            # ``serving/snapshot``, which has none
+            with RecordEvent("serving/snapshot"), \
+                    RecordEvent("serving/snapshot_land"):
                 if cap.stale:
                     self.engine_metrics.on_snapshot_dropped()
                     continue

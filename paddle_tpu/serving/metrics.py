@@ -147,7 +147,12 @@ class ServingMetrics:
                 # KV pages through the host — snapshots, tier demotions,
                 # scrubs and restores
                 "serving.shard.steps", "serving.shard.page_gathers",
-                "serving.shard.page_scatters")
+                "serving.shard.page_scatters",
+                # collapses of the dispatch-ahead pipeline that drained
+                # at least one in-flight step (admission, abort,
+                # quarantine, speculation): the device idles through
+                # each until the next dispatch
+                "serving.pipeline_collapses")
     HISTOGRAMS = ("serving.step_latency_ms", "serving.prefill_latency_ms",
                   "serving.decode_latency_ms", "serving.ttft_ms",
                   "serving.dispatch_gap_ms",
@@ -224,6 +229,10 @@ class ServingMetrics:
         with self._lock:
             self._completed += n
         stat_registry.get("serving.requests_completed").add(n)
+
+    def on_pipeline_collapse(self):
+        """The engine consumed every in-flight step before going on."""
+        stat_registry.get("serving.pipeline_collapses").add(1)
 
     def on_preemption(self, n: int = 1):
         stat_registry.get("serving.preemptions").add(n)
